@@ -8,7 +8,7 @@ BASELINE := tests/lint_baseline.json
 .PHONY: lint verify protocheck shardcheck detcheck pallas-check check test native \
     trace-demo \
     zero-demo multislice-demo adapt-demo overlap-demo serve-demo pp-demo \
-    persist-demo xray-gate sentinel-gate benchdiff help
+    persist-demo xray-gate sentinel-gate help
 
 ## lint: all eighteen kf-lint rules — the Python suite (env-contract,
 ## jit-sync, blocking-io, retry-discipline, handle-discipline,
@@ -77,7 +77,7 @@ native:
 ## 30 ms link delay — offline `kftrace --critical-path` and the online
 ## aggregator verdict must be identical and name the planted edge, and
 ## the per-phase medians must sit inside tests/xray_budget.json
-## (docs/xray.md; the recorded row is BENCH_extra.json xray_cpu_mesh).
+## (docs/xray.md).
 xray-gate:
 	$(PY) bench.py --xray --quick > /tmp/_kf_xray_gate.json
 	grep -q '"vs_baseline": 1.0' /tmp/_kf_xray_gate.json
@@ -90,20 +90,11 @@ xray-gate:
 ## regress:step_time_s changepoint alert must fire online within K=2
 ## windows, the incident flight record's xray verdict must name the
 ## planted rank/edge, and `kfhist --verdict` over the durable history
-## must reproduce the identical verdicts offline (docs/sentinel.md;
-## the recorded row is BENCH_extra.json sentinel_cpu_mesh).
+## must reproduce the identical verdicts offline (docs/sentinel.md).
 sentinel-gate:
 	$(PY) bench.py --sentinel --quick > /tmp/_kf_sentinel_gate.json
 	grep -q '"vs_baseline": 1.0' /tmp/_kf_sentinel_gate.json
 	@echo "sentinel-gate: all checks green"
-
-## benchdiff: compare the live BENCH_extra.json against the checked-in
-## per-gate scalar baseline (tests/bench_baseline.json) with tolerance
-## bands — nonzero exit on any regressed or vanished gate.  Regenerate
-## the baseline after recording new rows:
-##   scripts/kfbench-diff --snapshot BENCH_extra.json > tests/bench_baseline.json
-benchdiff:
-	$(PY) scripts/kfbench-diff tests/bench_baseline.json BENCH_extra.json
 
 ## trace-demo: 4-peer local run with an injected 400 ms straggler on
 ## rank 2 (every 9th matching send, so most collectives stay clean and
@@ -150,7 +141,7 @@ multislice-demo:
 ## consensus-fenced lockstep swap onto the measured-latency MST — the
 ## script asserts the swap fires on EVERY rank and the step time
 ## recovers (docs/adaptation.md; the full A/B vs every fixed strategy
-## is `python bench.py --adapt`, recorded in BENCH_extra.json).
+## is `python bench.py --adapt`).
 adapt-demo:
 	$(PY) examples/adapt_interference.py
 
@@ -161,8 +152,7 @@ adapt-demo:
 ## requests from their committed positions on the survivors.  Asserts
 ## zero lost accepted requests, >=1 replay, replayed tokens equal to
 ## the greedy reference, and measured prefix reuse (docs/serving.md;
-## the full SLO A/B incl. a slice kill is `python bench.py --serve`,
-## recorded in BENCH_extra.json).
+## the full SLO A/B incl. a slice kill is `python bench.py --serve`).
 serve-demo:
 	$(PY) examples/serve_demo.py
 
@@ -173,7 +163,7 @@ serve-demo:
 ## script asserts measured overlap > 0, BITWISE-identical final params,
 ## and the kf_overlap_inflight gauge back at 0 (docs/overlap.md; the
 ## full A/B incl. zero-3 and the bare shard_map+psum row is
-## `python bench.py --overlap`, recorded in BENCH_extra.json).
+## `python bench.py --overlap`).
 overlap-demo:
 	$(PY) examples/overlap_pipeline.py
 
@@ -184,8 +174,7 @@ overlap-demo:
 ## params between the schedules, a measured 1F1B win, and a planned
 ## 2->1 elastic stage merge restored bitwise from the ring-mirrored
 ## StageBoundary (docs/pipeline.md; the full A/B with the xray bubble
-## decomposition is `python bench.py --pp`, recorded in
-## BENCH_extra.json).
+## decomposition is `python bench.py --pp`).
 pp-demo:
 	$(PY) examples/pp_demo.py
 
@@ -197,7 +186,7 @@ pp-demo:
 ## the 4-rank manifest re-carves onto the halved world and the final
 ## params are asserted BITWISE against a fixed-world numpy replay
 ## (docs/persistence.md; the overhead/goodput A/B is `python bench.py
-## --persist`, recorded in BENCH_extra.json).
+## --persist`).
 persist-demo:
 	$(PY) examples/preempt_restore.py
 

@@ -38,9 +38,7 @@ def main(argv=None) -> dict:
     import jax
 
     if args.cpu_mesh:
-        from kungfu_tpu.utils.jaxcompat import set_cpu_device_count
-
-        set_cpu_device_count(args.cpu_mesh)
+        jax.config.update("jax_num_cpu_devices", int(args.cpu_mesh))
         jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np

@@ -137,9 +137,7 @@ def main(argv=None) -> dict:
         import jax
 
         # before any backend init; env vars are too late when jax is preloaded
-        from kungfu_tpu.utils.jaxcompat import set_cpu_device_count
-
-        set_cpu_device_count(args.cpu_mesh)
+        jax.config.update("jax_num_cpu_devices", int(args.cpu_mesh))
         jax.config.update("jax_platforms", "cpu")
 
     if args.backend == "device":

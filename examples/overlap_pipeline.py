@@ -17,7 +17,7 @@ optimizer math runs).  The script asserts:
 
 Wired into ``make overlap-demo`` and ``scripts/check.sh``; the full A/B
 with the zero-3 rows and the bare ``shard_map``+``psum`` reference is
-``python bench.py --overlap`` (recorded in BENCH_extra.json).  See
+``python bench.py --overlap``.  See
 docs/overlap.md for the design.
 """
 

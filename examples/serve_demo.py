@@ -19,8 +19,7 @@ last committed decode position on the survivors.  The script asserts:
 
 Wired into ``make serve-demo`` and ``scripts/check.sh``; the measured
 SLO A/B (p50/p99 before/during/after worker AND slice kills at fixed
-offered load) is ``python bench.py --serve``, recorded in
-BENCH_extra.json.  See docs/serving.md.
+offered load) is ``python bench.py --serve``.  See docs/serving.md.
 """
 
 from __future__ import annotations

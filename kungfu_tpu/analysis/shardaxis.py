@@ -12,9 +12,8 @@ ZeRO arc; cf. arXiv:2004.13336, arXiv:1909.09756).  Built on the
   resolving through the project constant table: ``AXIS_TP``,
   ``GLOBAL_AXES``, ...) passed to any collective — the ``jax.lax``
   primitives AND the project wrappers in :mod:`kungfu_tpu.ops` /
-  :mod:`kungfu_tpu.comm.device` / :mod:`kungfu_tpu.utils.jaxcompat` —
-  must be an axis some ``Mesh``/``pmap`` in the tree declares.  A
-  one-token typo (``"tq"`` for ``"tp"``) is caught anywhere, even in a
+  :mod:`kungfu_tpu.comm.device` — must be an axis some ``Mesh``/``pmap``
+  in the tree declares.  A one-token typo (``"tq"`` for ``"tp"``) is caught anywhere, even in a
   helper whose calling context is unknown.
 * **environment** — where the function's axis environment is statically
   known (it is a ``shard_map``/``pmap`` body with a resolved mesh, or
@@ -49,7 +48,7 @@ CHECKER = "shard-axis"
 
 #: collective terminal name -> (positional axis-arg index, kwarg names).
 #: Covers the jax.lax primitives and the project wrappers (ops/,
-#: comm/device.py, utils/jaxcompat.py).  Values that evaluate to ints
+#: comm/device.py).  Values that evaluate to ints
 #: (lax.all_gather's ``axis=0`` DIMENSION kwarg, Communicator.broadcast's
 #: ``root``) are ignored — only string-valued arguments are axis names.
 AXIS_ARGS: Dict[str, Tuple[int, Tuple[str, ...]]] = {
@@ -65,9 +64,9 @@ AXIS_ARGS: Dict[str, Tuple[int, Tuple[str, ...]]] = {
     "all_to_all": (1, ("axis_name",)),
     "axis_index": (0, ("axis_name",)),
     "axis_size": (0, ("axis_name",)),
+    "pcast": (1, ("axis_name",)),
     "all_gather": (1, ("axis_name", "axis")),
-    # project wrappers (kungfu_tpu.ops.collective / .schedules,
-    # utils/jaxcompat)
+    # project wrappers (kungfu_tpu.ops.collective / .schedules)
     "all_reduce": (1, ("axis",)),
     "group_all_reduce": (1, ("axis",)),
     "all_reduce_scheduled": (1, ("axis",)),
@@ -75,7 +74,6 @@ AXIS_ARGS: Dict[str, Tuple[int, Tuple[str, ...]]] = {
     "barrier_value": (0, ("axis",)),
     "peer_rank": (0, ("axis",)),
     "peer_size": (0, ("axis",)),
-    "pcast_varying": (1, ("axes",)),
     # the Pallas ICI ring collectives (ops/pallas/collectives.py): the
     # axis name threads through pallas_call kernels under shard_map —
     # a typo'd literal here fails at trace time on the pod exactly like

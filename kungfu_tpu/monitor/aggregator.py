@@ -654,7 +654,7 @@ class ClusterAggregator:
                 lines += [
                     "# HELP kf_cluster_step_phase_seconds per-phase step-"
                     "time decomposition, mean over reporting ranks "
-                    "(kf-xray taxonomy)",
+                    "(kf-xray phases)",
                     "# TYPE kf_cluster_step_phase_seconds gauge",
                 ]
                 for ph in sorted(xr["phase_seconds"]):

@@ -246,3 +246,36 @@ def publish_device_memory() -> bool:
     if limit:
         REGISTRY.gauge("kf_device_memory_bytes", kind="limit").set(limit)
     return True
+
+
+#: one-shot guard for install_compile_metrics (a second install would
+#: double-count every compile)
+_COMPILE_METRICS_INSTALLED = False
+
+#: the jax monitoring event that marks one XLA backend compile — the
+#: recompile signal kf-sentinel's recompile-steady watermark judges
+_BACKEND_COMPILE_EVENT = "backend_compile_duration"
+
+
+def install_compile_metrics() -> None:
+    """Mirror XLA compiles into the unified registry:
+    ``kf_jit_compiles_total`` (counter) and ``kf_jit_compile_seconds``
+    (histogram) tick on every ``/jax/core/compile/
+    backend_compile_duration`` monitoring event — so the cluster
+    snapshots carry them, kftop can show them, and the sentinel's
+    recompile-steady watermark can alert on compiles after warmup
+    (a steady-state recompile means a shape leak / cache bust).
+    Idempotent — peers and tests may both call it."""
+    global _COMPILE_METRICS_INSTALLED
+    if _COMPILE_METRICS_INSTALLED:
+        return
+    import jax
+
+    def _on_duration(name: str, duration: float, **_kw) -> None:
+        if name.endswith(_BACKEND_COMPILE_EVENT):
+            REGISTRY.counter("kf_jit_compiles_total").inc()
+            REGISTRY.histogram("kf_jit_compile_seconds").observe(
+                float(duration))
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _COMPILE_METRICS_INSTALLED = True

@@ -18,7 +18,7 @@ comparison, so the offline and online verdicts cannot diverge):
   over the event windows ranks push with their snapshots
   (:mod:`~kungfu_tpu.monitor.aggregator`), rendered by ``kftop``.
 
-Attribution taxonomy (:data:`PHASES`, per step, decomposing the
+Attribution phases (:data:`PHASES`, per step, decomposing the
 *critical rank's* wall):
 
 * ``compute``        — wall not covered by any recorded span (the
@@ -54,7 +54,7 @@ from typing import Dict, List, Optional, Tuple
 
 from kungfu_tpu.monitor import skew as skewlib
 
-#: the attribution taxonomy, in render order.  ``pp_bubble`` is the
+#: the attribution phases, in render order.  ``pp_bubble`` is the
 #: pipeline-parallel fill/drain wait (kf-pipeline "bubble" spans): time
 #: a stage spent blocked on a cross-DCN activation/gradient dependency
 #: — distinct from comm_exposed (the wire itself) because a prefetched

@@ -1,6 +1,6 @@
 // TSan stress driver for the native transport (transport.cpp).
 //
-// Exercises the paths the CHANGELOG fixed after the fact — teardown
+// Exercises the paths that were fixed after the fact — teardown
 // use-after-free (close_all racing in-flight send/recv) and the racing
 // send hang — as a standalone, fully TSan-instrumented binary.
 // (Instrumenting only the dlopen'd .so under an uninstrumented python

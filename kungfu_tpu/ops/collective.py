@@ -13,8 +13,7 @@ from typing import Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-
-from kungfu_tpu.utils.jaxcompat import axis_size
+from jax.lax import axis_size
 
 Axis = Union[str, Tuple[str, ...]]
 

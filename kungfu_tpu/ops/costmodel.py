@@ -6,8 +6,8 @@ count, so recompute/fusion choices cannot inflate it) and the chip's
 peak FLOP/s (detected from the TPU device kind, or pinned by the
 ``KF_XRAY_PEAK_FLOPS`` launch env).  On the CPU mesh there is no
 meaningful peak, so :func:`chip_peak_flops` returns ``None`` and every
-consumer reports the **model-FLOPs rate** row instead of an MFU — the
-same tunnel-proof discipline as every other CPU-mesh bench row.
+consumer reports the **model-FLOPs rate** row instead of an MFU: a
+CPU-mesh row is a count, never a device metric.
 
 Three model surfaces (docs/xray.md derives each):
 

@@ -1,4 +1,4 @@
-"""Varying-manual-axes (vma) helpers for jax>=0.9 shard_map typing.
+"""Varying-manual-axes (vma) helpers for shard_map typing.
 
 Under ``shard_map`` every value carries the set of mesh axes it varies
 over; pallas ``out_shape`` structs must declare it, and scan carries /
@@ -9,38 +9,27 @@ evolves.
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-from kungfu_tpu.utils.jaxcompat import pcast_varying, typeof
-
-#: whether this jax's ShapeDtypeStruct takes the ``vma`` kwarg (0.4.x
-#: predates vma typing entirely)
-_SDS_HAS_VMA = "vma" in inspect.signature(jax.ShapeDtypeStruct.__init__).parameters
 
 
 def sds(shape, dtype, vma=frozenset()):
-    """``jax.ShapeDtypeStruct`` declaring varying manual axes where the
-    running jax supports them; the plain struct otherwise (pre-vma jax
-    has no varying types for the out_shape to disagree with)."""
-    if _SDS_HAS_VMA and vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    """``jax.ShapeDtypeStruct`` declaring its varying manual axes."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
 
 
 def vma_of(*operands) -> frozenset:
     """Union of the operands' varying manual axes (empty outside
-    ``shard_map``, and always empty on pre-vma jax)."""
+    ``shard_map``)."""
     vs = set()
     for o in operands:
-        vs |= set(getattr(typeof(o), "vma", ()) or ())
+        vs |= set(jax.typeof(o).vma)
     return frozenset(vs)
 
 
 def match_vma(t, vma: frozenset):
     """Mark ``t`` varying over any axes in ``vma`` it doesn't carry yet
-    (no-op for axes already varying — pcast rejects varying→varying)."""
-    cur = set(getattr(typeof(t), "vma", ()) or ())
-    missing = tuple(a for a in vma if a not in cur)
-    return pcast_varying(t, missing)
+    (pcast rejects varying→varying, and an empty cast)."""
+    missing = tuple(a for a in vma if a not in jax.typeof(t).vma)
+    if not missing:
+        return t
+    return jax.lax.pcast(t, missing, to="varying")

@@ -66,7 +66,6 @@ _LANES = 128
 from kungfu_tpu.ops.pallas._sharding import match_vma as _match_vma
 from kungfu_tpu.ops.pallas._sharding import vma_of as _vma
 from kungfu_tpu.ops.pallas._sharding import sds as _sds
-from kungfu_tpu.utils.jaxcompat import tpu_compiler_params
 
 
 def _causal_hi(qi, block_q, block_k):
@@ -183,10 +182,11 @@ def _fwd_call(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out[:, :s], lse[:, :s, 0]
 
@@ -390,10 +390,11 @@ def _bwd_pallas(q, k, v, out, lse, dout, causal, block_q, block_k, interpret,
         out_shape=[_sds((bh, s_pad, d), q.dtype,
                                         vma=_vma(q, k, v, dout))],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, dout, lse, delta)[0]
 
     # dk/dv grid: (bh, kv-block, q-block); clamp the q index upward for
@@ -433,10 +434,11 @@ def _bwd_pallas(q, k, v, out, lse, dout, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, dout, lse, delta)
     return dq[:, :s], dk[:, :s], dv[:, :s]
 

@@ -72,12 +72,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.lax import axis_size
 
 from kungfu_tpu.ops.pallas._sharding import match_vma as _match_vma
 from kungfu_tpu.ops.pallas._sharding import sds as _sds
 from kungfu_tpu.ops.pallas._sharding import vma_of as _vma
 from kungfu_tpu.utils.envs import LaunchKnobs
-from kungfu_tpu.utils.jaxcompat import axis_size, tpu_compiler_params
 
 _LANE = 128
 
@@ -421,11 +421,29 @@ def _ag_kernel(x_ref, o_ref, buf_ref, send_sem, recv_sem, copy_sem,
                     device_id=nbr[-sign], device_id_type=_LOGICAL)
 
 
-def _any_space():
-    space = getattr(pltpu, "ANY", None)
-    if space is None:
-        space = pltpu.TPUMemorySpace.ANY
-    return space
+#: VMEM a compiled ring kernel's scratch may take: Mosaic's default
+#: scoped limit on a TPU v5e (the kernels pass no ``vmem_limit_bytes``).
+#: Found by compiling for a described v5e:2x2 with libtpu 0.0.34: 15 MiB
+#: of scratch compiles and 18 MiB is refused, in every kernel form.
+_VMEM_SCRATCH_BUDGET = 16 << 20
+
+
+def _check_vmem(name: str, slots: int, rows: int, dtype) -> None:
+    """The kernels hold whole chunks in VMEM (``slots`` buffers of
+    ``rows x 128``), so a chunk past the budget cannot compile.  Say so
+    at trace time, with the numbers, instead of leaving the caller a
+    compiler dump from the middle of a training step."""
+    chunk = rows * _LANE * jnp.dtype(dtype).itemsize
+    if slots * chunk > _VMEM_SCRATCH_BUDGET:
+        mib = 1 << 20
+        raise ValueError(
+            f"{name}(impl='pallas'): a {chunk / mib:.1f} MiB chunk per "
+            f"device needs {slots * chunk / mib:.1f} MiB of VMEM scratch "
+            f"({slots} whole-chunk buffers) and the kernel's budget is "
+            f"{_VMEM_SCRATCH_BUDGET / mib:.0f} MiB — send chunks of at "
+            f"most {_VMEM_SCRATCH_BUDGET // slots / mib:.2f} MiB "
+            f"(bucket the payload, as the ZeRO schedules do) or use the "
+            f"lax schedule")
 
 
 def _rs_pallas(parts, axis: str, n: int, bidirectional: bool,
@@ -433,6 +451,8 @@ def _rs_pallas(parts, axis: str, n: int, bidirectional: bool,
     rows = parts.shape[1]
     band = _band_rows(rows, parts.dtype) if bidirectional else 0
     ndir = 2 if band else 1
+    if not interpret:
+        _check_vmem("ring_reduce_scatter", 3 * ndir * 2, rows, parts.dtype)
     kernel = functools.partial(
         _rs_kernel, axis=axis, n=n, band=band, rows=rows,
         interpret=interpret)
@@ -440,7 +460,7 @@ def _rs_pallas(parts, axis: str, n: int, bidirectional: bool,
         kernel,
         out_shape=_sds((rows, _LANE), parts.dtype,
                        vma=_vma(parts) | frozenset({axis})),
-        in_specs=[pl.BlockSpec(memory_space=_any_space())],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((ndir, 2, rows, _LANE), parts.dtype),  # acc
@@ -451,8 +471,9 @@ def _rs_pallas(parts, axis: str, n: int, bidirectional: bool,
             pltpu.SemaphoreType.DMA((ndir, 2)),               # copies
             pltpu.SemaphoreType.REGULAR((ndir,)),             # acks
         ],
-        compiler_params=tpu_compiler_params(collective_id=1),
+        compiler_params=pltpu.CompilerParams(collective_id=1),
         interpret=interpret,
+        name="ring_reduce_scatter",
     )(parts)
 
 
@@ -461,6 +482,8 @@ def _ag_pallas(tile, axis: str, n: int, bidirectional: bool,
     rows = tile.shape[0]
     band = _band_rows(rows, tile.dtype) if bidirectional else 0
     ndir = 2 if band else 1
+    if not interpret:
+        _check_vmem("ring_all_gather", ndir * 2, rows, tile.dtype)
     kernel = functools.partial(
         _ag_kernel, axis=axis, n=n, band=band, rows=rows,
         interpret=interpret)
@@ -468,8 +491,8 @@ def _ag_pallas(tile, axis: str, n: int, bidirectional: bool,
         kernel,
         out_shape=_sds((n, rows, _LANE), tile.dtype,
                        vma=_vma(tile) | frozenset({axis})),
-        in_specs=[pl.BlockSpec(memory_space=_any_space())],
-        out_specs=pl.BlockSpec(memory_space=_any_space()),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((ndir, 2, rows, _LANE), tile.dtype),   # slots
             pltpu.SemaphoreType.DMA((ndir, 2)),               # send
@@ -477,8 +500,9 @@ def _ag_pallas(tile, axis: str, n: int, bidirectional: bool,
             pltpu.SemaphoreType.DMA((ndir, 2)),               # copies
             pltpu.SemaphoreType.REGULAR((ndir,)),             # acks
         ],
-        compiler_params=tpu_compiler_params(collective_id=2),
+        compiler_params=pltpu.CompilerParams(collective_id=2),
         interpret=interpret,
+        name="ring_all_gather",
     )(tile)
 
 

@@ -43,7 +43,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kungfu_tpu.ops.pallas._sharding import vma_of as _vma
 from kungfu_tpu.ops.pallas._sharding import sds as _sds
-from kungfu_tpu.utils.jaxcompat import tpu_compiler_params
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_V = 1024
@@ -206,10 +205,11 @@ def _fwd_call(h, w, targets, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 1), jnp.float32),
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="lm_head_fwd",
     )(h, w, jnp.broadcast_to(targets[:, None], (n_pad, _LANES)))
     return loss[:n, 0], lse[:n, 0]
 
@@ -240,10 +240,11 @@ def _bwd_call(h, w, targets, lse, g, block_n, block_v, interpret):
         out_shape=_sds((n_pad, d_pad), h.dtype,
                                        vma=_vma(h, w, targets, lse, g)),
         scratch_shapes=[pltpu.VMEM((block_n, d_pad), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="lm_head_bwd_dh",
     )(h, w, lanes(targets), lanes(lse), lanes(g))
 
     row_dw = pl.BlockSpec((block_n, _LANES), lambda j, i: (i, 0))
@@ -260,10 +261,11 @@ def _bwd_call(h, w, targets, lse, g, block_n, block_v, interpret):
         out_shape=_sds((d_pad, v_pad), w.dtype,
                                        vma=_vma(h, w, targets, lse, g)),
         scratch_shapes=[pltpu.VMEM((d_pad, block_v), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="lm_head_bwd_dw",
     )(h, w, lanes(targets), lanes(lse), lanes(g))
     return dh[:n, :d], dw[:d, :v]
 

@@ -32,7 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 from kungfu_tpu.ops.pallas._sharding import vma_of as _vma
 from kungfu_tpu.ops.pallas._sharding import sds as _sds
 from kungfu_tpu.utils.envs import LaunchKnobs
-from kungfu_tpu.utils.jaxcompat import tpu_compiler_params
 
 #: measured on TPU v5e (docs/perf.md): (256, 2048) tiles run the fwd+bwd
 #: sweep ~1.5x faster than the round-3 (128, 512) defaults — big enough
@@ -125,10 +124,11 @@ def _fwd_call(logits, targets, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 1), jnp.float32),
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="xent_fwd",
     )(logits, jnp.broadcast_to(targets[:, None], (n_pad, _LANES)))
     return loss[:n, 0], lse[:n, 0]
 
@@ -202,11 +202,12 @@ def _bwd_pallas(logits, targets, lse, g, block_n, block_v, interpret):
         out_specs=pl.BlockSpec((block_n, block_v), lambda i, j: (i, j)),
         out_shape=_sds((n_pad, v_pad), logits.dtype,
                                        vma=_vma(logits, targets, lse, g)),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # stateless per tile: both grid dims are parallel
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name="xent_bwd",
     )(logits, lanes(targets), lanes(lse), lanes(g))
     return dlogits[:n, :v]
 
@@ -241,8 +242,9 @@ def _xent_bwd(block_n, block_v, interpret, res, g):
 _xent.defvjp(_xent_fwd, _xent_bwd)
 
 
-#: Per-shape kernel-vs-XLA routing thresholds, seeded from the settled
-#: v5e measurements (BENCH_extra.json tpu_kernels; docs/perf.md):
+#: Per-shape kernel-vs-XLA routing thresholds, seeded from v5e
+#: measurements taken before PR 1 (docs/perf.md) and not repeated on
+#: today's code:
 #:
 #: * fwd-only: the kernel streams the logits once and beats XLA's
 #:   materialized log-softmax ~2x at HBM scale (2.49 vs 5.00 ms at

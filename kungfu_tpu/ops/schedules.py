@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.lax import axis_size
 
-from kungfu_tpu.utils.jaxcompat import axis_size
 
 Axis = Union[str, Tuple[str, ...]]
 
@@ -90,8 +90,6 @@ def _pad_identity(op: str, dtype):
         return op == "min"  # True is min's identity, False is max's
     info = jnp.iinfo(dtype)
     return info.max if op == "min" else info.min
-
-
 
 
 def _flatten_pad(a, n: int, op: str):
@@ -201,34 +199,11 @@ def _dep_fence(pair):
     XLA can hold in flight (and therefore how much gathered live range
     it can accumulate) without changing a single output bit.
 
-    ``lax.optimization_barrier`` has no differentiation rule on current
-    jax, so the fence is a custom_vjp identity whose backward applies
-    the same barrier to the cotangents — the ZeRO-3 gradient path (an
-    all-gather whose transpose IS the reduce-scatter) gets the same
-    window on the backward collectives for free.  Falls back to a plain
-    identity where the primitive is unavailable (older jax): the values
-    are identical either way, only the scheduling hint is lost."""
-    bar = getattr(lax, "optimization_barrier", None)
-    if bar is None:
-        return pair
-    return _dep_fence_vjp(pair)
-
-
-@jax.custom_vjp
-def _dep_fence_vjp(pair):
+    ``lax.optimization_barrier`` differentiates to the same barrier on
+    the cotangents, so the ZeRO-3 gradient path (an all-gather whose
+    transpose IS the reduce-scatter) gets the same window on the
+    backward collectives for free."""
     return lax.optimization_barrier(pair)
-
-
-def _dep_fence_fwd(pair):
-    return lax.optimization_barrier(pair), None
-
-
-def _dep_fence_bwd(_, ct):
-    return (lax.optimization_barrier(ct),)
-
-
-if hasattr(lax, "optimization_barrier"):
-    _dep_fence_vjp.defvjp(_dep_fence_fwd, _dep_fence_bwd)
 
 
 def bucket_widths(chunk: int, n: int, itemsize: int,
@@ -414,7 +389,9 @@ def traced_collective_bytes(fn, *args, axis_sizes: Dict[str, int]):
 
     def walk(jp):
         for eqn in jp.eqns:
-            prim = eqn.primitive.name
+            # under shard_map's vma typing a psum of a varying value
+            # traces as `psum_invariant`: the same all-reduce
+            prim = eqn.primitive.name.removesuffix("_invariant")
             cost = _COLLECTIVE_COST.get(prim)
             if cost is not None:
                 k = axis_total(eqn.params.get("axes")

@@ -22,9 +22,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
 from kungfu_tpu.models import nn
-from kungfu_tpu.utils.jaxcompat import axis_size
 
 
 def moe_init(key, n_experts_local: int, d_model: int, d_ff: int, n_experts_global: int):

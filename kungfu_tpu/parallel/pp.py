@@ -574,7 +574,7 @@ class StageModule:
         from kungfu_tpu.models import nn
         from kungfu_tpu.models.transformer import _rope, default_attention
         from kungfu_tpu.parallel import tp as tpmod
-        from kungfu_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
 
         cfg = self.cfg
         dt = cfg.compute_dtype

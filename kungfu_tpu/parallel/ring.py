@@ -27,8 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from kungfu_tpu.utils.jaxcompat import axis_size
+from jax.lax import axis_size
 
 
 def ring_attention(q, k, v, causal: bool = True, axis: str = "sp",
@@ -231,11 +230,8 @@ def _ring_einsum(q, k, v, causal: bool, axis: str):
 
     def vary(x):
         # mark the accumulators as varying over the ring axis so the scan
-        # carry type matches (jax>=0.9 varying-manual-axes typing;
-        # identity on 0.4.x, which has no vma types to match)
-        from kungfu_tpu.utils.jaxcompat import pcast_varying
-
-        return pcast_varying(x, (axis,))
+        # carry type matches (varying-manual-axes typing)
+        return jax.lax.pcast(x, (axis,), to="varying")
 
     m0 = vary(jnp.full((B, H, S), -jnp.inf, jnp.float32))
     l0 = vary(jnp.zeros((B, H, S), jnp.float32))

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from kungfu_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kungfu_tpu.models import nn
@@ -858,6 +858,11 @@ def dp_train_step(
         return base(params, opt_state, batch)
 
     stepped.pulse = mon  # introspection hook for tests/tools
+    # the two jitted programs behind the wrapper, for callers that need
+    # what only a jit object has (.lower, cost analysis, tracing the step
+    # inside another compiled program)
+    stepped.base = base
+    stepped.pulse_step = pulse_jit
     return stepped
 
 

@@ -43,9 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
-
-from kungfu_tpu.utils.jaxcompat import axis_size, shard_map
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kungfu_tpu.ops.fuse import defuse, fuse
@@ -613,6 +612,13 @@ class ZeroStep:
                    group_norms={
                        "flat": math.sqrt(max(0.0, g_global_sq))})
 
+    def jitted(self, params):
+        """The jit program :meth:`step` runs for this parameter tree, for
+        callers that need what only a jit object has (``.lower``, cost
+        analysis)."""
+        built = self._require_g3() if self.stage == 3 else self._get(params)
+        return built["step"]
+
     def init_opt(self, params):
         out = self._get(params)["init_opt"](params)
         record_opt_state_gauge(out)
@@ -752,6 +758,15 @@ class ZeroStep:
                     p_shard = optax.apply_updates(p_shard, updates)
                     loss = lax.pmean(loss, axes)
                     if with_pulse:
+                        # the pair was reduced over scatter_axes only; a
+                        # size-1 mesh axis (one host: kf_host) moves no
+                        # data but still types it as varying, which the
+                        # replicated out_spec rejects — the identity
+                        # pmean over those axes clears it
+                        for ax in geo.axes_t:
+                            if ax not in geo.scatter_axes:
+                                gl_sq = lax.pmean(gl_sq, ax)
+                                gg_sq = lax.pmean(gg_sq, ax)
                         return p_shard, opt_shard, loss, gl_sq, gg_sq
                     return p_shard, opt_shard, loss
                 return step_body

@@ -104,10 +104,14 @@ class Peer:
             if platform:
                 import jax
 
-                try:
-                    jax.config.update("jax_platforms", platform)
-                except Exception as e:  # backend may already be initialized
-                    _log.warning("cannot set jax platform %s: %s", platform, e)
+                jax.config.update("jax_platforms", platform)
+                if platform != "cpu":
+                    # the chip path: compiles of whole-step programs are
+                    # worth keeping across processes and runs
+                    from kungfu_tpu.utils.compile_cache import \
+                        enable_compile_cache
+
+                    enable_compile_cache()
             monitor = None
             if envs.parse_bool_env(envs.ENABLE_MONITORING):
                 from kungfu_tpu.monitor.metrics import (
@@ -140,6 +144,16 @@ class Peer:
                     n_peers=self.size())
             if self.config.coordinator and self.config.num_processes > 1:
                 self._init_jax_distributed()
+            if platform and platform != "cpu":
+                # an accelerator was asked for: bring the backend up now
+                # (after the distributed world, which must come first)
+                # and refuse to train on anything else — a backend that
+                # was initialized before start() ignores the config above
+                got = jax.devices()[0].platform
+                if got != platform:
+                    raise RuntimeError(
+                        f"the launcher asked for the {platform!r} backend "
+                        f"but JAX came up on {got!r}")
             from kungfu_tpu.utils.affinity import bind_local_rank
 
             world = self.config.world_peers
@@ -180,15 +194,12 @@ class Peer:
                     rank = self.rank()
                 if rank is not None:
                     from kungfu_tpu.monitor.aggregator import RankReporter
-                    from kungfu_tpu.monitor.metrics import \
-                        publish_device_memory
-                    from kungfu_tpu.utils.jaxcompat import \
-                        install_compile_metrics
+                    from kungfu_tpu.monitor.metrics import (
+                        install_compile_metrics, publish_device_memory)
 
                     # XLA compiles become registry series the snapshot
                     # carries (kf_jit_compiles_total — the sentinel's
-                    # recompile-steady feedstock); no-op on jax
-                    # versions without the monitoring hook
+                    # recompile-steady feedstock)
                     install_compile_metrics()
                     # slice identity rides the same stable bootstrap
                     # frame as the rank: kftop's per-slice grouping
@@ -262,16 +273,10 @@ class Peer:
             # CPU-backend multi-process clusters (the fake-cluster test
             # trick, SURVEY §4) need an explicit cross-process collectives
             # impl; TPU uses ICI/DCN natively
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception as e:  # older jaxlib without gloo
-                _log.warning("cannot enable gloo cpu collectives: %s", e)
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
             ndev = os.environ.get(envs.NUM_DEVICES)
             if ndev:
-                try:
-                    jax.config.update("jax_num_cpu_devices", int(ndev))
-                except Exception as e:
-                    _log.warning("cannot set cpu device count: %s", e)
+                jax.config.update("jax_num_cpu_devices", int(ndev))
         with stall_detector("jax.distributed.initialize"):
             jax.distributed.initialize(
                 coordinator_address=self.config.coordinator,

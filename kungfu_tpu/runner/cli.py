@@ -76,8 +76,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("-q", dest="quiet", action="store_true", help="suppress worker output")
     p.add_argument("-timeout", type=float, default=0.0, help="job timeout seconds (0 = none)")
     p.add_argument("-backend", default=None, choices=["cpu", "tpu"],
-                   help="worker device backend (default cpu = multi-process "
-                        "test cluster; a detected cloud platform may set tpu)")
+                   help="worker device backend.  The default, cpu, is the "
+                        "multi-process test cluster — on a TPU host too, so "
+                        "pass tpu to train on the chips (a detected cloud "
+                        "platform sets it).  tpu pins the platform (a worker "
+                        "that finds no TPU fails at kf.init()) and runs one "
+                        "worker process per host over all of its chips")
     p.add_argument("-platform", default="auto", choices=["auto", "none", "tpu-pod"],
                    help="cloud platform adapter: derive -H/-self/-backend from "
                         "the scheduler's env (TPU_WORKER_HOSTNAMES et al.); "
@@ -360,6 +364,21 @@ def main(argv: Optional[List[str]] = None) -> int:
     if ns.device_world:
         hl = build_hostlist(ns)
         world = hl.gen_peer_list(hl.cap(), parse_port_range(ns.port_range))
+
+    if ns.backend == "tpu":
+        # a chip belongs to one process at a time, and a worker is handed
+        # nothing that says which chip is its own: two workers on a host
+        # would each reach for all of its chips, and the second fails or
+        # hangs at backend start-up.  Refuse before anything starts.
+        spawn = world if world is not None else cluster.workers
+        for host, ranks in spawn.partition_by_host().items():
+            if len(ranks) > 1:
+                raise SystemExit(
+                    f"kfrun: -backend tpu runs ONE worker process per "
+                    f"host, driving all of that host's chips, but this "
+                    f"launch puts {len(ranks)} workers on {host}.  Use one "
+                    f"slot per host (-np 1 on a single host; "
+                    f"-H a:1,b:1 -np 2 across hosts)")
 
     if ns.num_slices and ns.num_slices > 1:
         spawn_total = len(world) if world is not None else cluster.size()

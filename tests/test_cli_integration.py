@@ -246,7 +246,7 @@ class TestTpuBackendEnvContract:
         from kungfu_tpu.runner.job import COORDINATOR_PORT_OFFSET, Job
         from kungfu_tpu.utils import envs as E
 
-        hl = HostList.parse("10.0.0.1:2,10.0.0.2:2")
+        hl = HostList.parse("10.0.0.1:1,10.0.0.2:1,10.0.0.3:1,10.0.0.4:1")
         cluster = Cluster(hl.gen_runner_list(), hl.gen_peer_list(4))
         job = Job(prog="python3", args=["t.py"], backend="tpu")
         procs = [job.new_proc(w, cluster) for w in cluster.workers]
@@ -255,7 +255,10 @@ class TestTpuBackendEnvContract:
             assert p.envs[E.COORDINATOR] == f"10.0.0.1:{cluster.workers[0].port + COORDINATOR_PORT_OFFSET}"
             assert p.envs[E.NUM_PROCESSES] == "4"
             assert p.envs[E.PROCESS_ID] == str(i)
-            assert "JAX_PLATFORMS" not in p.envs
+            # pinned, so a worker that finds no TPU fails instead of
+            # training on the host's CPU
+            assert p.envs["JAX_PLATFORMS"] == "tpu"
+            assert p.envs["KF_JAX_PLATFORM"] == "tpu"
 
     def test_single_worker_no_distributed(self):
         from kungfu_tpu.plan import Cluster, HostList

@@ -12,7 +12,7 @@ import pytest
 
 from kungfu_tpu.comm import Communicator
 from kungfu_tpu.plan import Cluster, HostList
-from kungfu_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 
 def make_comm(local_size=None):

@@ -9,6 +9,8 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 from kungfu_tpu.analysis import (
     aggschema,
     blockingio,
@@ -54,10 +56,22 @@ def _tmp_tree(tmp_path, files):
     return str(tmp_path)
 
 
+@pytest.fixture(scope="module")
+def tree_run():
+    """ONE in-process run of every checker over the real tree, from a
+    cold parse cache, shared by the tree-wide tests (a full run costs
+    ~10 s): its violations and its per-file parse counts."""
+    from kungfu_tpu.analysis import core
+
+    core.clear_parse_cache()
+    violations = run_checkers(ROOT)
+    return violations, dict(core.PARSE_COUNTS)
+
+
 class TestTreeIsClean:
-    def test_all_checkers_clean_on_tree(self):
+    def test_all_checkers_clean_on_tree(self, tree_run):
         """THE tier-1 gate: every project invariant holds on every run."""
-        violations = run_checkers(ROOT)
+        violations, _ = tree_run
         assert violations == [], "\n".join(v.render() for v in violations)
 
     def test_cli_exit_zero_on_tree(self):
@@ -979,15 +993,12 @@ class TestSingleParse:
         assert len(counts) == 4, counts
         assert all(c == 1 for c in counts.values()), counts
 
-    def test_full_tree_single_parse(self):
+    def test_full_tree_single_parse(self, tree_run):
         """On the REAL tree — every checker plus the taint engine plus
         the call graph plus the axis env still cost one parse per file
         (the <10s full-run budget depends on this)."""
-        from kungfu_tpu.analysis import core
-
-        core.clear_parse_cache()
-        run_checkers(ROOT)
-        counts = {p: c for p, c in core.PARSE_COUNTS.items()
+        _, parse_counts = tree_run
+        counts = {p: c for p, c in parse_counts.items()
                   if p.startswith(os.path.join(ROOT, "kungfu_tpu"))}
         over = {p: c for p, c in counts.items() if c != 1}
         assert counts and not over, over

@@ -167,7 +167,7 @@ class TestTransformer:
         params = m.init(jax.random.PRNGKey(0))
         ids = np.random.RandomState(0).randint(0, 128, (2, 16))
         tgt = np.roll(ids, -1, axis=1)
-        loss, grads = jax.value_and_grad(m.loss)(params, (ids, tgt))
+        loss, grads = jax.jit(jax.value_and_grad(m.loss))(params, (ids, tgt))
         assert np.isfinite(float(loss))
         g = grads["layer_0"]["wq"]["w"]
         assert np.abs(np.asarray(g)).sum() > 0
@@ -181,10 +181,11 @@ class TestTransformer:
         m = Transformer(cfg)
         params = m.init(jax.random.PRNGKey(0))
         ids = np.arange(8)[None, :] % 64
-        logits1 = np.asarray(m.apply(params, ids))
+        apply = jax.jit(m.apply)
+        logits1 = np.asarray(apply(params, ids))
         ids2 = ids.copy()
         ids2[0, -1] = (ids[0, -1] + 9) % 64
-        logits2 = np.asarray(m.apply(params, ids2))
+        logits2 = np.asarray(apply(params, ids2))
         np.testing.assert_allclose(logits1[0, :-1], logits2[0, :-1], atol=1e-5)
         assert not np.allclose(logits1[0, -1], logits2[0, -1])
 

@@ -92,7 +92,7 @@ class TestHostNoiseScale:
 
         import kungfu_tpu.ops.collective as kc
         from kungfu_tpu.ops.monitor import global_noise_scale, host_noise_scale
-        from kungfu_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
 
         b_small = 8.0
         rng = np.random.RandomState(100 + n)
@@ -138,7 +138,7 @@ class TestHostNoiseScale:
 
         import kungfu_tpu.ops.collective as kc
         from kungfu_tpu.ops.monitor import global_noise_scale, host_noise_scale
-        from kungfu_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
 
         b_small = 16.0
         rng = np.random.RandomState(7)

@@ -22,7 +22,7 @@ from kungfu_tpu.optimizers import (
     synchronous_averaging,
     synchronous_sgd,
 )
-from kungfu_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 N = 8
 

@@ -5,6 +5,8 @@ Numerical cross-check against the plain-XLA attention — the same
 uses for its collectives (SURVEY §4).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -160,8 +162,9 @@ class TestTransformerIntegration:
         ids = jnp.asarray(
             np.random.default_rng(0).integers(0, 128, size=(2, 64)), jnp.int32
         )
-        ref = model.apply(params, ids)
-        got = model.apply(params, ids, attn_fn=make_flash_attn())
+        ref = jax.jit(model.apply)(params, ids)
+        got = jax.jit(functools.partial(
+            model.apply, attn_fn=make_flash_attn()))(params, ids)
         np.testing.assert_allclose(
             np.asarray(ref), np.asarray(got), atol=2e-3
         )
@@ -448,10 +451,10 @@ class TestFusedLMHead:
         rng = np.random.default_rng(4)
         ids = jnp.asarray(rng.integers(0, 128, (2, 16)), jnp.int32)
         tgt = jnp.asarray(rng.integers(0, 128, (2, 16)), jnp.int32)
-        logits = model.apply(params, ids)
+        logits = jax.jit(model.apply)(params, ids)
         lse_ref = -jnp.take_along_axis(
             jax.nn.log_softmax(logits), tgt[..., None], -1).squeeze(-1)
-        h = model.hidden(params, ids)
+        h = jax.jit(model.hidden)(params, ids)
         fused = lm_head_nll(h, params["head"]["w"], tgt, block_n=8,
                             block_v=128)
         np.testing.assert_allclose(np.asarray(fused), np.asarray(lse_ref),

@@ -37,7 +37,7 @@ from kungfu_tpu.ops.pallas.collectives import (
     ring_reduce_scatter,
     ring_wire_bytes,
 )
-from kungfu_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 
 N_DEV = 8
 

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from kungfu_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kungfu_tpu.models.transformer import Transformer, TransformerConfig, default_attention

@@ -332,9 +332,8 @@ class TestMultislice:
             "import sys, os, numpy as np\n"
             f"sys.path.insert(0, {repo!r})\n"
             "import jax\n"
-            "from kungfu_tpu.utils.jaxcompat import set_cpu_device_count\n"
             "jax.config.update('jax_platforms', 'cpu')\n"
-            "set_cpu_device_count(2)\n"
+            "jax.config.update('jax_num_cpu_devices', 2)\n"
             "jax.config.update('jax_cpu_collectives_implementation', 'gloo')\n"
             "rank, port = int(sys.argv[1]), int(sys.argv[2])\n"
             "jax.distributed.initialize(f'127.0.0.1:{port}', 2, rank)\n"

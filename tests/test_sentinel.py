@@ -534,17 +534,16 @@ class TestReporterHooks:
         assert isinstance(publish_device_memory(), bool)
 
     def test_install_compile_metrics_idempotent_and_ticks(self):
-        from kungfu_tpu.utils import jaxcompat
+        import jax
+        import numpy as np
 
-        ok = jaxcompat.install_compile_metrics()
-        assert jaxcompat.install_compile_metrics() is ok
-        if ok:
-            import jax
-            import numpy as np
+        from kungfu_tpu.monitor.metrics import install_compile_metrics
 
-            before = REGISTRY.counter("kf_jit_compiles_total").value
-            jax.jit(lambda x: x * 2 + 1)(np.arange(7, dtype="float32"))
-            assert REGISTRY.counter("kf_jit_compiles_total").value > before
+        install_compile_metrics()
+        install_compile_metrics()  # a second install must not double-count
+        before = REGISTRY.counter("kf_jit_compiles_total").value
+        jax.jit(lambda x: x * 2 + 1)(np.arange(7, dtype="float32"))
+        assert REGISTRY.counter("kf_jit_compiles_total").value == before + 1
 
 
 class TestChaosAfterStep:
@@ -613,13 +612,6 @@ class TestScripts:
     def test_kfbench_diff_self_check(self):
         r = self._run("kfbench-diff", "--self-check")
         assert r.returncode == 0, r.stderr
-
-    def test_checked_in_bench_baseline_current(self):
-        # the benchdiff gate must hold against the committed artifacts
-        r = self._run("kfbench-diff",
-                      os.path.join(ROOT, "tests", "bench_baseline.json"),
-                      os.path.join(ROOT, "BENCH_extra.json"))
-        assert r.returncode == 0, r.stdout + r.stderr
 
 
 @pytest.mark.slow

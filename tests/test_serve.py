@@ -9,6 +9,7 @@ kv-gauge/SLO flow through aggregator snapshots to the kftop serving
 view (docs/serving.md).
 """
 
+import functools
 import time
 
 import jax
@@ -49,11 +50,23 @@ def make_engine(model_and_params, pages=128, max_batch=4, page_tokens=8,
                            max_seq=CFG.max_seq, rank=rank)
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted_apply(model):
+    return jax.jit(model.apply)
+
+
 def reference_tokens(model, params, prompt, n):
+    """Greedy continuation by the training-path forward, one full-context
+    pass per token.  The context is zero-padded to ``max_seq`` so that
+    one compiled forward serves every length (an eager ``model.apply``
+    compiled every op again for each new length, ~4 s a token): the
+    model is causal, so the row that is read never sees the padding."""
     out = list(prompt)
     for _ in range(n):
-        logits = model.apply(params, np.asarray([out], np.int32))
-        out.append(int(np.argmax(np.asarray(logits)[0, -1])))
+        ids = np.zeros((1, model.cfg.max_seq), np.int32)
+        ids[0, :len(out)] = out
+        logits = _jitted_apply(model)(params, ids)
+        out.append(int(np.argmax(np.asarray(logits)[0, len(out) - 1])))
     return out[len(prompt):]
 
 

@@ -1,0 +1,148 @@
+"""The main path's kernels, compiled for the chip without the chip.
+
+The TPU compiler is installed with JAX and compiles for a topology that
+is described, not attached (``v5e:2x2``, device kind "TPU v5 lite"), so
+what it refuses — a slice off the tiling, too much VMEM, a program that
+does not fit — is found here and not on chip time.  Nothing runs: a
+compile that passes is not a chip run.  Interpret-mode tests cannot see
+any of this.
+
+Shapes are GPT-small's on one chip at batch 4 x sequence 2048
+(``chip_smoke.py``), and ZeRO's 4 MiB bucket on the four devices.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from kungfu_tpu.ops.pallas.attention import flash_attention
+from kungfu_tpu.ops.pallas.collectives import (ring_all_gather,
+                                               ring_reduce_scatter)
+from kungfu_tpu.ops.pallas.lm_head import lm_head_nll
+from kungfu_tpu.ops.pallas.xent import softmax_cross_entropy
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this jaxlib
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A described-device compile can be written to the persistent cache
+    but not read back; keep it off so these stay silent."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+N, D, V = 8192, 768, 32128          # tokens on a chip, d_model, vocab
+QKV = (4, 12, 2048, 64)             # [B, H, S, head_dim]
+BUCKET = (4 << 20) // 4             # ZeRO's 4 MiB bucket, in f32 elements
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _xent(logits, targets):
+    return softmax_cross_entropy(logits, targets, interpret=False)
+
+
+def _head(h, w, targets):
+    return lm_head_nll(h, w, targets, interpret=False)
+
+
+def _grad(f, argnums):
+    return jax.grad(lambda *a: f(*a).astype(jnp.float32).mean(), argnums)
+
+
+def _ring(fn, bidirectional):
+    return lambda x: fn(x, "d", bidirectional=bidirectional, impl="pallas",
+                        interpret=False)
+
+
+bf16, i32 = jnp.bfloat16, jnp.int32
+QKV3 = [(QKV, bf16)] * 3
+XENT = [((N, V), bf16), ((N,), i32)]
+HEAD = [((N, D), bf16), ((D, V), bf16), ((N,), i32)]
+
+#: (id, function, [(shape, dtype), ...], chips, kernels expected)
+CASES = [
+    ("flash_fwd", _flash, QKV3, 1, 1),
+    ("flash_fwd_bwd", _grad(_flash, (0, 1, 2)), QKV3, 1, 3),
+    ("xent_fwd", _xent, XENT, 1, 1),
+    ("xent_fwd_bwd", _grad(_xent, 0), XENT, 1, 2),
+    ("lm_head_fwd", _head, HEAD, 1, 1),
+    ("lm_head_fwd_bwd", _grad(_head, (0, 1)), HEAD, 1, 3),
+    ("ring_reduce_scatter", _ring(ring_reduce_scatter, False),
+     [((4 * BUCKET,), jnp.float32)], 4, 1),
+    ("ring_reduce_scatter_bidir", _ring(ring_reduce_scatter, True),
+     [((4 * BUCKET,), jnp.float32)], 4, 1),
+    ("ring_all_gather", _ring(ring_all_gather, False),
+     [((BUCKET,), jnp.float32)], 4, 1),
+    ("ring_all_gather_bidir", _ring(ring_all_gather, True),
+     [((BUCKET,), jnp.float32)], 4, 1),
+]
+
+
+def _lower(topo, fn, args, chips):
+    if chips == 1:
+        sharding = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices[:chips]), ("d",))
+        sharding = NamedSharding(mesh, P("d"))
+        fn = shard_map(fn, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
+    return jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in args])
+
+
+@pytest.mark.parametrize("fn,args,chips,kernels",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_compiles_for_v5e(topo, fn, args, chips, kernels):
+    text = _lower(topo, fn, args, chips).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+@pytest.mark.parametrize("fn,per_device_mib,needs", [
+    (ring_reduce_scatter, 128, "192.0 MiB"),  # GPT-small's embedding scale
+    (ring_all_gather, 12, "24.0 MiB"),
+], ids=["reduce_scatter", "all_gather"])
+def test_over_vmem_chunk_is_refused_at_trace_time(topo, fn, per_device_mib,
+                                                  needs):
+    """The ring kernels hold whole chunks in VMEM; past the budget the
+    compiler's answer is an allocation dump from inside the step.  The
+    kernel entry says it first, with the numbers."""
+    elems = (per_device_mib << 20) // 4
+    with pytest.raises(ValueError) as e:
+        _lower(topo, _ring(fn, False), [((4 * elems,), jnp.float32)], 4)
+    msg = str(e.value)
+    assert "VMEM" in msg and "16 MiB" in msg and needs in msg
+
+
+def test_vmem_budget_is_the_compilers(topo):
+    """Just under the budget compiles: the trace-time check does not
+    refuse what the compiler accepts (10 MiB a device: 15 MiB scratch)."""
+    elems = (10 << 20) // 4
+    _lower(topo, _ring(ring_reduce_scatter, False),
+           [((4 * elems,), jnp.float32)], 4).compile()
