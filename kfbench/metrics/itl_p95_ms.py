@@ -1,0 +1,9 @@
+"""95th percentile of the gap between successive tokens of one request,
+over every gap in the window: a gap that held another request's
+prefill."""
+
+from kfbench.lib import records, stats
+
+
+def read(facts, entry):
+    return 1e3 * stats.percentile(records.itl_gaps(facts), 95)
