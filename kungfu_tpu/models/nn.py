@@ -172,6 +172,7 @@ def layernorm_init(dim: int):
     return {"scale": jnp.ones((dim,), jnp.float32), "bias": jnp.zeros((dim,), jnp.float32)}
 
 
+@jax.named_scope("norm")
 def layernorm_apply(p, x, eps=1e-5):
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, -1, keepdims=True)

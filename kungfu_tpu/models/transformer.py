@@ -9,7 +9,12 @@ only as a gradient-size list, ``model_sizes.py``):
   in ring attention (sequence-parallel over the mesh) or the Pallas flash
   kernel for long context;
 * shapes are MXU-friendly (`d_model`, `d_ff` multiples of 128) and all
-  control flow is static — one trace, one compile.
+  control flow is static — one trace, one compile;
+* every layer's operations carry a ``jax.named_scope`` from one
+  vocabulary (``embed``, ``norm``, ``attn_proj``, ``attn_core``, ``mlp``,
+  ``head``; docs/tracing.md), so a device trace says which layer an
+  operation belongs to.  Scopes are metadata: the compiled program is
+  the same.
 """
 
 from __future__ import annotations
@@ -145,7 +150,8 @@ class Transformer:
         parallelism passes the global positions of the local shard)."""
         h = self.hidden(params, ids, train=train, rng=rng, attn_fn=attn_fn,
                         positions=positions)
-        return nn.dense_apply(params["head"], h).astype(jnp.float32)
+        with jax.named_scope("head"):
+            return nn.dense_apply(params["head"], h).astype(jnp.float32)
 
     def hidden(self, params, ids, train: bool = False, rng=None,
                attn_fn: Optional[Callable] = None, positions=None):
@@ -159,26 +165,32 @@ class Transformer:
         B, S = ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-        h = nn.embedding_apply(params["embed"], ids, dtype=dt)
-        if cfg.pos == "learned":
-            h = h + nn.embedding_apply(params["pos_embed"], positions, dtype=dt)
+        with jax.named_scope("embed"):
+            h = nn.embedding_apply(params["embed"], ids, dtype=dt)
+            if cfg.pos == "learned":
+                h = h + nn.embedding_apply(params["pos_embed"], positions,
+                                           dtype=dt)
         for i in range(cfg.n_layers):
             lp = params[f"layer_{i}"]
             x = nn.layernorm_apply(lp["ln1"], h)
-            q = self._heads(nn.dense_apply(lp["wq"], x, dtype=dt))
-            k = self._heads(nn.dense_apply(lp["wk"], x, dtype=dt))
-            v = self._heads(nn.dense_apply(lp["wv"], x, dtype=dt))
-            if cfg.pos == "rope":
-                q, k = _rope(q, k, positions)
-            o = attn(q, k, v, cfg.causal)
-            o = self._merge(o)
-            h = h + nn.dense_apply(lp["wo"], o, dtype=dt)
+            with jax.named_scope("attn_proj"):
+                q = self._heads(nn.dense_apply(lp["wq"], x, dtype=dt))
+                k = self._heads(nn.dense_apply(lp["wk"], x, dtype=dt))
+                v = self._heads(nn.dense_apply(lp["wv"], x, dtype=dt))
+                if cfg.pos == "rope":
+                    q, k = _rope(q, k, positions)
+            with jax.named_scope("attn_core"):
+                o = attn(q, k, v, cfg.causal)
+            with jax.named_scope("attn_proj"):
+                o = self._merge(o)
+                h = h + nn.dense_apply(lp["wo"], o, dtype=dt)
             x = nn.layernorm_apply(lp["ln2"], h)
-            y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
-            if train and cfg.dropout > 0 and rng is not None:
-                rng, sub = jax.random.split(rng)
-                y = nn.dropout(sub, y, cfg.dropout, train)
-            h = h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
+            with jax.named_scope("mlp"):
+                y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
+                if train and cfg.dropout > 0 and rng is not None:
+                    rng, sub = jax.random.split(rng)
+                    y = nn.dropout(sub, y, cfg.dropout, train)
+                h = h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
         return nn.layernorm_apply(params["ln_f"], h)
 
     def _heads(self, x):
@@ -219,12 +231,14 @@ class Transformer:
 
             h = self.hidden(params, ids, train=train, rng=rng,
                             attn_fn=attn_fn, positions=positions)
-            return jnp.mean(lm_head_nll(h, params["head"]["w"], targets))
+            with jax.named_scope("head"):
+                return jnp.mean(lm_head_nll(h, params["head"]["w"], targets))
         logits = self.apply(params, ids, train=train, rng=rng, attn_fn=attn_fn, positions=positions)
         # train also steers the xent router: eval-only calls take the
         # fwd-only crossover (the kernel wins much earlier without a
         # backward to fuse)
-        return token_nll(logits, targets, training=train)
+        with jax.named_scope("head"):
+            return token_nll(logits, targets, training=train)
 
 
 def bert_base() -> Transformer:

@@ -6,13 +6,12 @@ fault events here, the collective engine's spans feed per-op latency
 histograms, :class:`~kungfu_tpu.monitor.metrics.NetMonitor` mirrors its
 byte totals, and :class:`~kungfu_tpu.monitor.metrics.MetricsServer`
 renders everything through the existing ``/metrics`` endpoint.  Before
-this module each subsystem kept private aggregates (``utils/trace.py``
-(count, total) pairs, ``NetMonitor`` rate counters) that no one surface
-could render together.
+this module each subsystem kept private aggregates (``NetMonitor`` rate
+counters, per-scope (count, total) pairs) that no one surface could
+render together.
 
-Deliberately dependency-free (stdlib only): ``utils/trace.py`` borrows
-:class:`Histogram` for its percentile report and ``scripts/kftrace``
-imports the package without jax.
+Deliberately dependency-free (stdlib only): ``scripts/kftrace`` imports
+the package without jax.
 
 Histograms use **fixed** bucket boundaries (seconds, latency-shaped by
 default): observation is O(#buckets) worst case with no allocation, and

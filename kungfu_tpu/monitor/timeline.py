@@ -1,9 +1,9 @@
-"""Flight recorder: bounded in-process ring of structured events.
+"""Flight recorder: bounded in-process ring of structured events, and the
+program's spans on the profiler's clock.
 
-``utils/trace.py`` answers "how much, on average"; this module answers
-"*which rank* stalled *which collective* at *which step*, and was a
-chaos fault or a shrink in flight at the time".  Every event is
-``(ts, rank, step, kind, name, dur, attrs)``:
+The ring answers "*which rank* stalled *which collective* at *which
+step*, and was a chaos fault or a shrink in flight at the time".  Every
+event is ``(ts, rank, step, kind, name, dur, attrs)``:
 
 * ``ts`` — wall-clock start time (``time.time()``, so cross-rank merges
   align without a clock-sync protocol; NTP-level skew is visible but the
@@ -20,12 +20,23 @@ chaos fault or a shrink in flight at the time".  Every event is
 * ``dur`` — seconds for :func:`span` regions, ``0`` for one-shot
   :func:`event` marks.
 
-Cost contract: gated by the same ``KF_CONFIG_ENABLE_TRACE`` switch as
-``trace_scope``.  Disabled, :func:`span` returns a shared no-op context
-manager (zero allocation) and :func:`event` returns after one env check
-— except for the rare *counted* kinds (retry/deadline/chaos/down/
-shrink), whose registry counters tick regardless so ``/metrics`` stays
-truthful without paying for the ring on the hot path.
+Every :func:`span` is also a ``jax.profiler.TraceAnnotation`` named
+``kf:<kind>.<name>`` with its scalar attrs as the event's stats, whether
+or not the ring records: a profiler session around a few steps
+(``jax.profiler.start_trace``) shows the program's spans beside the
+device's operations, on one clock.  JAX is never imported from here —
+the runner, the detector and the config server use this module without
+it — so the annotation exists only where ``jax.profiler`` is already in
+``sys.modules``.
+
+Cost contract: the ring is gated by ``KF_CONFIG_ENABLE_TRACE``.
+Disabled, :func:`span` returns the bare annotation (one small object;
+with no profiler session its enter/exit is an atomic check: no clock,
+no lock, no log line), or a shared no-op where JAX is not loaded, and
+:func:`event` returns after one env check — except for the rare
+*counted* kinds (retry/deadline/chaos/down/shrink), whose registry
+counters tick regardless so ``/metrics`` stays truthful without paying
+for the ring on the hot path.
 
 Dump: one JSONL file per process (= per rank under the runner) written
 by :func:`maybe_dump` (``Peer.close``) and an ``atexit`` hook when
@@ -41,13 +52,14 @@ import collections
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
 from kungfu_tpu.monitor.registry import REGISTRY
 from kungfu_tpu.utils.log import get_logger
-from kungfu_tpu.utils.trace import record_duration, trace_enabled
+from kungfu_tpu.utils.trace import trace_enabled
 
 _log = get_logger("timeline")
 
@@ -293,7 +305,7 @@ def context_attrs(trace: Optional[str],
 
 
 def enabled() -> bool:
-    """Same gate as ``trace_scope`` (``KF_CONFIG_ENABLE_TRACE``)."""
+    """Whether the ring records (``KF_CONFIG_ENABLE_TRACE``)."""
     return trace_enabled()
 
 
@@ -379,7 +391,8 @@ def event(kind: str, name: str, rank: Optional[int] = None,
 
 
 class _NoopSpan:
-    """Shared disabled-path span: no allocation, no timing."""
+    """Shared disabled-path span where JAX is not loaded: no
+    allocation, no timing."""
 
     __slots__ = ()
 
@@ -389,19 +402,49 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **attrs):
+        pass
+
 
 _NOOP_SPAN = _NoopSpan()
+
+#: the prefix of every span's name on the profiler's clock
+ANNOTATION_PREFIX = "kf:"
+_SCALARS = (int, float, str, bool)
+
+
+def _annotation(kind: str, name: str, attrs: Optional[Dict]):
+    """The span as a ``jax.profiler.TraceAnnotation`` with its scalar
+    attrs as the event's stats, or None in a process that has not
+    imported JAX (which this module never does itself)."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    if attrs:
+        attrs = {k: v for k, v in attrs.items() if isinstance(v, _SCALARS)}
+    return prof.TraceAnnotation(f"{ANNOTATION_PREFIX}{kind}.{name}",
+                                **(attrs or {}))
 
 
 class _Span:
     __slots__ = ("kind", "name", "rank", "attrs", "_t0", "_ts",
-                 "span_id", "_trace", "_parent")
+                 "span_id", "_trace", "_parent", "_note")
 
     def __init__(self, kind, name, rank, attrs):
         self.kind = kind
         self.name = name
         self.rank = rank
         self.attrs = attrs
+        self._note = _annotation(kind, name, attrs)
+
+    def set_metadata(self, **attrs):
+        """Attrs known only once the region is under way (what an
+        admission reused, what a commit fetched): the name and the
+        meaning of ``TraceAnnotation.set_metadata``, which is what a
+        call site holds when the ring is off."""
+        self.attrs = dict(self.attrs or {}, **attrs)
+        if self._note is not None:
+            self._note.set_metadata(**attrs)
 
     def __enter__(self):
         # causal triple: explicit trace= attr wins; else inherit the
@@ -417,12 +460,16 @@ class _Span:
         self.span_id = new_span_id()
         self._trace, self._parent = trace, parent
         _ctx_stack().append((trace, self.span_id))
+        if self._note is not None:
+            self._note.__enter__()
         self._ts = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb):
         dt = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(et, ev, tb)
         _ctx_stack().pop()
         attrs = dict(self.attrs or {})
         if et is not None:
@@ -433,11 +480,6 @@ class _Span:
         if self._parent is not None:
             attrs["parent"] = self._parent
         _append(self._ts, self.rank, self.kind, self.name, dt, attrs)
-        # aggregate parity: spans ARE trace scopes — trace_report() and
-        # its histogram percentiles see every span duration, and the live
-        # per-scope log line trace_scope users rely on keeps appearing
-        record_duration(self.name, dt)
-        _log.info("%s took %.3fms", self.name, dt * 1e3)
         if self.kind in ("collective", "device"):
             op = (attrs or {}).get("op") if attrs else None
             REGISTRY.histogram(
@@ -449,11 +491,12 @@ class _Span:
 
 def span(kind: str, name: str, rank: Optional[int] = None,
          force: bool = False, **attrs):
-    """Timed region: records one event with ``dur`` set, feeds the trace
-    aggregates, and (for collective/device kinds) the per-op latency
-    histogram.  Returns a shared no-op when tracing is off."""
+    """Timed region.  Always a profiler annotation ``kf:<kind>.<name>``
+    (where JAX is loaded); with tracing on (or ``force``) it also
+    records one event with ``dur`` set and, for collective/device kinds,
+    feeds the per-op latency histogram."""
     if not (force or trace_enabled()):
-        return _NOOP_SPAN
+        return _annotation(kind, name, attrs) or _NOOP_SPAN
     return _Span(kind, name, rank, attrs or None)
 
 

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import optax
 
 from kungfu_tpu import ops
@@ -40,18 +41,20 @@ def synchronous_sgd(
 
     def update(grads, state, params=None):
         op = "mean" if average else "sum"
-        if fuse_grads:
-            from kungfu_tpu.ops.fuse import defuse, fuse
+        with jax.named_scope("grad_sync"):
+            if fuse_grads:
+                from kungfu_tpu.ops.fuse import defuse, fuse
 
-            buf, spec = fuse(grads)
-            buf = ops.all_reduce_scheduled(buf, axis, op=op,
-                                           schedule=schedule)
-            grads = defuse(buf, spec)
-        else:
-            # schedule="psum" dispatches to the same all_reduce that
-            # group_all_reduce wraps — one call site for every schedule
-            grads = ops.all_reduce_scheduled(grads, axis, op=op,
-                                             schedule=schedule)
-        return inner.update(grads, state, params)
+                buf, spec = fuse(grads)
+                buf = ops.all_reduce_scheduled(buf, axis, op=op,
+                                               schedule=schedule)
+                grads = defuse(buf, spec)
+            else:
+                # schedule="psum" dispatches to the same all_reduce that
+                # group_all_reduce wraps — one call site for every schedule
+                grads = ops.all_reduce_scheduled(grads, axis, op=op,
+                                                 schedule=schedule)
+        with jax.named_scope("optimizer"):
+            return inner.update(grads, state, params)
 
     return optax.GradientTransformation(init, update)

@@ -39,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kungfu_tpu.models import nn
 from kungfu_tpu.models.transformer import TransformerConfig, _rope
+from kungfu_tpu.monitor import timeline
 from kungfu_tpu.parallel import tp as tpmod
 from kungfu_tpu.parallel.mesh import AXIS_DP, AXIS_PP, AXIS_SP, AXIS_TP, MeshPlan
 from kungfu_tpu.parallel.moe import moe_apply
@@ -620,19 +621,22 @@ class ShardedTrainer:
         return self._step_fn(state, (ids, targets))
 
     def _publish_pulse(self, mon, group_sq, gl, global_batch: int) -> None:
-        norms = {k: math.sqrt(max(0.0, float(v)))
-                 for k, v in group_sq.items()}
-        if self._pure_dp():
-            n = int(self.plan.dp)
-            # sorted fold: the replayed sum must not depend on the
-            # param-kind dict's insertion order (docs/determinism.md)
-            gg = sum(float(group_sq[k]) for k in sorted(group_sq))
-            b_small = max(1, global_batch // max(1, n))
-            mon.update(float(gl), gg, b_small, n, group_norms=norms)
-        else:
-            # sharded meshes: the GNS pair is undefined (no rank holds
-            # a full small-batch gradient) — norms are still exact
-            mon.publish_norms(norms)
+        with timeline.span("pulse", "sync"):  # the host waits here
+            group_sq = {k: float(v) for k, v in group_sq.items()}
+            gl = float(gl)
+        norms = {k: math.sqrt(max(0.0, v)) for k, v in group_sq.items()}
+        with timeline.span("pulse", "publish"):
+            if self._pure_dp():
+                n = int(self.plan.dp)
+                # sorted fold: the replayed sum must not depend on the
+                # param-kind dict's insertion order (docs/determinism.md)
+                gg = sum(group_sq[k] for k in sorted(group_sq))
+                b_small = max(1, global_batch // max(1, n))
+                mon.update(gl, gg, b_small, n, group_norms=norms)
+            else:
+                # sharded meshes: the GNS pair is undefined (no rank holds
+                # a full small-batch gradient) — norms are still exact
+                mon.publish_norms(norms)
 
     # -- losses without update (for tests) ---------------------------------
     def loss(self, state, batch) -> jnp.ndarray:
@@ -762,8 +766,10 @@ def dp_train_step(
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             new_aux = aux
-        updates, new_state = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        # (tx's own gradient collective names itself grad_sync inside)
+        with jax.named_scope("optimizer"):
+            updates, new_state = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         return new_params, new_aux, new_state, jax.lax.pmean(loss, axis)
 
     def body_stacked(params, aux, opt_state, batch):
@@ -822,10 +828,12 @@ def dp_train_step(
         # identical mean-allreduce inside update(); when the ops match
         # XLA CSEs the two psums into one, and this program only runs
         # on 1-in-`every` steps regardless
-        avg = ops.group_all_reduce(grads, axis, op="mean")
+        with jax.named_scope("grad_sync"):
+            avg = ops.group_all_reduce(grads, axis, op="mean")
         g_global_sq = _sq_norm(avg)
-        updates, new_state = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_state = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         return (new_params, new_state, jax.lax.pmean(loss, axis),
                 g_local_sq, g_global_sq)
 
@@ -846,16 +854,22 @@ def dp_train_step(
     n = int(comm.size)
 
     def stepped(params, opt_state, batch):
-        if mon.should_sample():
-            p, s, loss, gl, gg = pulse_jit(params, opt_state, batch)
-            gl, gg = float(gl), float(gg)
+        if not mon.should_sample():
+            with timeline.span("step", "train", pulse=0):
+                with timeline.span("step", "dispatch"):
+                    return base(params, opt_state, batch)
+        with timeline.span("step", "train", pulse=1):
+            with timeline.span("step", "dispatch"):
+                p, s, loss, gl, gg = pulse_jit(params, opt_state, batch)
+            with timeline.span("pulse", "sync"):
+                gl, gg = float(gl), float(gg)  # the host waits here
             leaves = jax.tree_util.tree_leaves(batch)
             b_small = (max(1, int(leaves[0].shape[0]) // n)
                        if (leaves and n) else 1)
-            mon.update(gl, gg, b_small, n,
-                       group_norms={"flat": max(0.0, gg) ** 0.5})
+            with timeline.span("pulse", "publish"):
+                mon.update(gl, gg, b_small, n,
+                           group_norms={"flat": max(0.0, gg) ** 0.5})
             return p, s, loss
-        return base(params, opt_state, batch)
 
     stepped.pulse = mon  # introspection hook for tests/tools
     # the two jitted programs behind the wrapper, for callers that need

@@ -24,11 +24,11 @@ from typing import Optional
 
 from kungfu_tpu.comm.device import Communicator
 from kungfu_tpu.comm.host import ConnType, HostChannel
+from kungfu_tpu.monitor import timeline
 from kungfu_tpu.plan.cluster import Cluster
 from kungfu_tpu.utils import envs
 from kungfu_tpu.utils.log import get_logger, log_event
 from kungfu_tpu.utils.stall import stall_detector
-from kungfu_tpu.utils.trace import trace_scope
 
 _log = get_logger("peer")
 
@@ -178,8 +178,6 @@ class Peer:
             # flight-recorder identity: events (and the dump filename)
             # default to this worker's rank; in-process multi-peer test
             # clusters pass rank= explicitly at rank-owning call sites
-            from kungfu_tpu.monitor import timeline
-
             timeline.set_rank(None if self.detached or self.standby
                               else self.rank())
             # live cluster plane: push snapshots to the aggregator
@@ -358,8 +356,6 @@ class Peer:
         # flush the flight recorder before tearing channels down (the
         # atexit hook also fires, but a long-lived driver that closes and
         # re-opens peers would otherwise only dump its last incarnation)
-        from kungfu_tpu.monitor import timeline
-
         timeline.maybe_dump()
         if self._reporter is not None:
             # final push BEFORE channels tear down: a clean shutdown
@@ -590,7 +586,8 @@ class Peer:
         """Host-level barrier across worker processes."""
         if self.size() <= 1 or self._channel is None:
             return
-        with trace_scope("peer.barrier"), stall_detector("barrier"):
+        with timeline.span("collective", "peer.barrier", op="barrier"), \
+                stall_detector("barrier"):
             self._channel.barrier(
                 self.cluster.workers, name=f"barrier.v{self.cluster_version}"
             )
@@ -672,7 +669,8 @@ class Peer:
         with self._lock:
             if new_cluster.workers == self.cluster.workers:
                 return False
-            with trace_scope("peer.propose"), stall_detector("propose"):
+            with timeline.span("mark", "peer.propose", version=version), \
+                    stall_detector("propose"):
                 self._notify_runners(new_cluster, version)
                 self.cluster = new_cluster
                 self.cluster_version = version
@@ -779,7 +777,8 @@ class Peer:
         world = self.config.world_peers
         if world is None or len(world) <= 1 or self._channel is None:
             return
-        with trace_scope("peer.world_barrier"), stall_detector("world_barrier"):
+        with timeline.span("collective", "peer.world_barrier", op="barrier"), \
+                stall_detector("world_barrier"):
             self._channel.barrier(world, name=f"wbarrier.{name}")
 
     def observe_stage(self):

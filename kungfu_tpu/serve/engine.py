@@ -144,6 +144,7 @@ class InferenceEngine:
         self._mfu = costmodel.MFUMeter(rank=rank)
 
     # -- forward passes --------------------------------------------------
+    @jax.named_scope("attn_proj")
     def _layer_qkv(self, lp, x, positions):
         cfg = self.model.cfg
         dt = cfg.compute_dtype
@@ -161,6 +162,7 @@ class InferenceEngine:
         return q, k, v
 
     @staticmethod
+    @jax.named_scope("attn_core")
     def _attend(q, keys, values, mask):
         """q [B,H,Q,D] over keys/values [B,H,S,D]; mask [B,1,Q,S] (or
         broadcastable) True = attend.  f32 logits/softmax like the
@@ -176,6 +178,17 @@ class InferenceEngine:
         b, h, s, d = x.shape
         return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
+    def _out_and_mlp(self, lp, h, o):
+        """The rest of a block after attention ``o`` [B,H,Q,D]: the
+        output projection and the MLP, each added to the stream ``h``."""
+        dt = self.model.cfg.compute_dtype
+        with jax.named_scope("attn_proj"):
+            h = h + nn.dense_apply(lp["wo"], self._merge(o), dtype=dt)
+        x = nn.layernorm_apply(lp["ln2"], h)
+        with jax.named_scope("mlp"):
+            y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
+            return h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
+
     def _prefill_fn(self, params, k_slab, v_slab, ids, n, start, slot):
         """ids [S_pad] (suffix, zero-padded past ``n``); writes K/V at
         positions ``[start, start + S_pad)`` of ``slot`` and returns the
@@ -185,10 +198,11 @@ class InferenceEngine:
         s_pad = ids.shape[0]
         s_max = k_slab.shape[3]
         positions = start + jnp.arange(s_pad)
-        h = nn.embedding_apply(params["embed"], ids[None], dtype=dt)
-        if cfg.pos == "learned":
-            h = h + nn.embedding_apply(params["pos_embed"], positions[None],
-                                       dtype=dt)
+        with jax.named_scope("embed"):
+            h = nn.embedding_apply(params["embed"], ids[None], dtype=dt)
+            if cfg.pos == "learned":
+                h = h + nn.embedding_apply(params["pos_embed"],
+                                           positions[None], dtype=dt)
         q_pos = positions
         key_pos = jnp.arange(s_max)
         mask = (key_pos[None, :] <= q_pos[:, None])[None, None]  # [1,1,Q,S]
@@ -196,23 +210,25 @@ class InferenceEngine:
             lp = params[f"layer_{li}"]
             x = nn.layernorm_apply(lp["ln1"], h)
             q, k, v = self._layer_qkv(lp, x, positions[None])
-            k_slab = jax.lax.dynamic_update_slice(
-                k_slab, k[None], (li, slot, 0, start, 0))
-            v_slab = jax.lax.dynamic_update_slice(
-                v_slab, v[None], (li, slot, 0, start, 0))
-            keys = jax.lax.dynamic_index_in_dim(k_slab[li], slot, 0,
-                                                keepdims=True)
-            values = jax.lax.dynamic_index_in_dim(v_slab[li], slot, 0,
-                                                  keepdims=True)
-            o = self._merge(self._attend(q, keys, values, mask))
-            h = h + nn.dense_apply(lp["wo"], o, dtype=dt)
-            x = nn.layernorm_apply(lp["ln2"], h)
-            y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
-            h = h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
+            with jax.named_scope("kv_write"):
+                k_slab = jax.lax.dynamic_update_slice(
+                    k_slab, k[None], (li, slot, 0, start, 0))
+                v_slab = jax.lax.dynamic_update_slice(
+                    v_slab, v[None], (li, slot, 0, start, 0))
+            with jax.named_scope("attn_core"):
+                keys = jax.lax.dynamic_index_in_dim(k_slab[li], slot, 0,
+                                                    keepdims=True)
+                values = jax.lax.dynamic_index_in_dim(v_slab[li], slot, 0,
+                                                      keepdims=True)
+                o = self._attend(q, keys, values, mask)
+            h = self._out_and_mlp(lp, h, o)
         h = nn.layernorm_apply(params["ln_f"], h)
-        last = jax.lax.dynamic_index_in_dim(h, n - 1, axis=1, keepdims=False)
-        logits = nn.dense_apply(params["head"], last).astype(jnp.float32)
-        return k_slab, v_slab, jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(h, n - 1, axis=1,
+                                                keepdims=False)
+            logits = nn.dense_apply(params["head"], last).astype(jnp.float32)
+            tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+        return k_slab, v_slab, tok
 
     def _decode_fn(self, params, k_slab, v_slab, last_ids, pos):
         """One token for every slot: ``last_ids``/``pos`` are [B]; the
@@ -222,10 +238,12 @@ class InferenceEngine:
         dt = cfg.compute_dtype
         s_max = k_slab.shape[3]
         positions = pos[:, None]                     # [B, 1]
-        h = nn.embedding_apply(params["embed"], last_ids[:, None], dtype=dt)
-        if cfg.pos == "learned":
-            h = h + nn.embedding_apply(params["pos_embed"], positions,
-                                       dtype=dt)
+        with jax.named_scope("embed"):
+            h = nn.embedding_apply(params["embed"], last_ids[:, None],
+                                   dtype=dt)
+            if cfg.pos == "learned":
+                h = h + nn.embedding_apply(params["pos_embed"], positions,
+                                           dtype=dt)
         mask = (jnp.arange(s_max)[None, :] <= positions)[:, None, None, :]
 
         def upd(slab_b, new_b, p):  # [H,S,D], [H,1,D], scalar
@@ -235,18 +253,18 @@ class InferenceEngine:
             lp = params[f"layer_{li}"]
             x = nn.layernorm_apply(lp["ln1"], h)
             q, k, v = self._layer_qkv(lp, x, positions)
-            k_l = jax.vmap(upd)(k_slab[li], k, pos)
-            v_l = jax.vmap(upd)(v_slab[li], v, pos)
-            k_slab = k_slab.at[li].set(k_l)
-            v_slab = v_slab.at[li].set(v_l)
-            o = self._merge(self._attend(q, k_l, v_l, mask))
-            h = h + nn.dense_apply(lp["wo"], o, dtype=dt)
-            x = nn.layernorm_apply(lp["ln2"], h)
-            y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
-            h = h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
+            with jax.named_scope("kv_write"):
+                k_l = jax.vmap(upd)(k_slab[li], k, pos)
+                v_l = jax.vmap(upd)(v_slab[li], v, pos)
+                k_slab = k_slab.at[li].set(k_l)
+                v_slab = v_slab.at[li].set(v_l)
+            h = self._out_and_mlp(lp, h, self._attend(q, k_l, v_l, mask))
         h = nn.layernorm_apply(params["ln_f"], h)
-        logits = nn.dense_apply(params["head"], h[:, 0]).astype(jnp.float32)
-        return k_slab, v_slab, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            logits = nn.dense_apply(params["head"], h[:, 0]
+                                    ).astype(jnp.float32)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return k_slab, v_slab, tok
 
     def _prefill_bucket(self, n: int) -> int:
         """Static prefill length: the smallest power-of-two multiple of
@@ -345,6 +363,12 @@ class InferenceEngine:
 
     # -- admission (prefill phase) ---------------------------------------
     def _try_admit(self, req: _Req) -> bool:
+        with timeline.span("serve", "admit", rank=self.rank, rid=req.rid,
+                           **timeline.context_attrs(req.trace, req.parent)
+                           ) as sp:
+            return self._admit(req, sp)
+
+    def _admit(self, req: _Req, sp) -> bool:
         T = self._page_tokens
         budget = len(req.tokens) + req.max_new
         n_pages = -(-budget // T)
@@ -394,18 +418,21 @@ class InferenceEngine:
         s_pad = self._prefill_bucket(len(suffix))
         ids = np.zeros(s_pad, np.int32)
         ids[:len(suffix)] = suffix
-        tc_attrs = timeline.context_attrs(req.trace, req.parent)
+        sp.set_metadata(tokens=len(suffix), reused=n_cached,
+                        pages=len(req.pages))
         with timeline.span("serve", "prefill", rank=self.rank,
-                           tokens=len(suffix), reused=n_cached,
-                           rid=req.rid, **tc_attrs):
+                           rid=req.rid, bucket=s_pad):
             self._k, self._v, tok = self._prefill_j(
                 self.params, self._k, self._v, jnp.asarray(ids),
                 jnp.int32(len(suffix)), jnp.int32(n_cached), jnp.int32(slot))
         req.computed = len(suffix)
         self._mfu.add_flops(costmodel.serve_prefill_flops(
             self.model.cfg, len(suffix), n_cached))
+        with timeline.span("serve", "prefill_read", rank=self.rank,
+                           rid=req.rid):
+            tok = int(tok)  # the host waits for the prefill here
         req.first_token_s = time.perf_counter()
-        req.generated.append(int(tok))
+        req.generated.append(tok)
         slo.count_prefill(computed=len(suffix), reused=n_cached)
         with self._lock:
             self._active[slot] = req
@@ -423,6 +450,12 @@ class InferenceEngine:
             req.pages = []
 
     def _complete(self, slot: int, req: _Req) -> dict:
+        with timeline.span("serve", "complete", rank=self.rank, rid=req.rid,
+                           **timeline.context_attrs(req.trace, req.parent)
+                           ) as sp:
+            return self._commit_and_retire(slot, req, sp)
+
+    def _commit_and_retire(self, slot: int, req: _Req, sp) -> dict:
         T = self._page_tokens
         # commit the full pages this request produced (beyond the reused
         # prefix) so the next shared-prefix request skips their prefill
@@ -431,6 +464,7 @@ class InferenceEngine:
         # was emitted but never ran through the stack
         full = (req.total_len - 1) // T
         first_new = req.reused // T
+        committed = fetched = 0
         if full > first_new and req.pages:
             kb = np.asarray(jax.device_get(
                 self._k[:, req.slot, :, first_new * T:full * T, :]))
@@ -442,6 +476,8 @@ class InferenceEngine:
                                         kb[:, :, lo:lo + T, :],
                                         vb[:, :, lo:lo + T, :])
             self.pool.commit_chain(seq[:full * T], req.pages[:full])
+            committed, fetched = full - first_new, kb.nbytes + vb.nbytes
+        sp.set_metadata(pages=committed, bytes=fetched)
         done_s = time.perf_counter()
         stats = {
             "rid": req.rid,
@@ -467,6 +503,13 @@ class InferenceEngine:
         """One continuous-batching iteration: admit (bounded), decode
         every active slot, retire finished requests.  Returns events:
         ``{"kind": "admit"|"token"|"done", ...}`` in occurrence order."""
+        with self._lock:
+            pending, active = len(self._pending), len(self._active)
+        with timeline.span("serve", "step", rank=self.rank,
+                           pending=pending, active=active):
+            return self._step()
+
+    def _step(self) -> List[dict]:
         events: List[dict] = []
         self._steps += 1
         t_step0 = time.perf_counter()
@@ -497,19 +540,22 @@ class InferenceEngine:
         with self._lock:
             active = dict(self._active)
         if active:
-            B = self.max_batch
-            last = np.zeros(B, np.int32)
-            pos = np.zeros(B, np.int32)
-            for slot, r in active.items():
-                last[slot] = r.generated[-1]
-                pos[slot] = r.total_len - 1
             t0 = time.perf_counter()
+            # batch: the live slots; width: the slots the decode program
+            # computes, live or not
             with timeline.span("serve", "decode", rank=self.rank,
-                               batch=len(active)):
+                               batch=len(active), width=self.max_batch):
+                B = self.max_batch
+                last = np.zeros(B, np.int32)
+                pos = np.zeros(B, np.int32)
+                for slot, r in active.items():
+                    last[slot] = r.generated[-1]
+                    pos[slot] = r.total_len - 1
                 self._k, self._v, nxt = self._decode_j(
                     self.params, self._k, self._v,
                     jnp.asarray(last), jnp.asarray(pos))
-            nxt = np.asarray(jax.device_get(nxt))
+            with timeline.span("serve", "decode_read", rank=self.rank):
+                nxt = np.asarray(jax.device_get(nxt))  # the host waits here
             slo.observe_token(time.perf_counter() - t0)
             cfg = self.model.cfg
             self._mfu.add_flops(sum(

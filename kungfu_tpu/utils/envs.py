@@ -56,10 +56,10 @@ Tuning envs (read anywhere, any time):
                                    hang/raw error — the entry point of
                                    shrink-to-survivors recovery.  Default
                                    = the engine timeout (comm/engine.py)
-``KF_CONFIG_ENABLE_TRACE``         truthy: log scope entry depth +
-                                   duration (utils/trace.py) AND record
-                                   flight-recorder timeline events
-                                   (monitor/timeline.py)
+``KF_CONFIG_ENABLE_TRACE``         truthy: record flight-recorder
+                                   timeline events (monitor/timeline.py;
+                                   its spans are profiler annotations
+                                   either way)
 ``KF_CONFIG_TRACE_DUMP``           timeline JSONL dump target: a
                                    directory (one trace-*.jsonl per
                                    process) or an exact *.jsonl path;

@@ -1,164 +1,19 @@
-"""Scoped tracing / profiling.
+"""The switch of the flight recorder's ring.
 
-Parity with the reference's optional stdtracer (``TRACE_SCOPE``,
+The reference's optional stdtracer (``TRACE_SCOPE``,
 ``include/kungfu/utils/trace.hpp:1-17``, enabled by
-``KUNGFU_ENABLE_TRACE``) plus the TPU-native upgrade: scopes can also
-drive :mod:`jax.profiler` so a traced region produces an XPlane/
-TensorBoard trace of the actual device timeline.
-
-* ``trace_scope(name)`` — context manager / decorator.  When
-  ``KF_CONFIG_ENABLE_TRACE`` is truthy, logs entry depth + duration and
-  accumulates per-name (count, total) stats; near-zero cost when off.
-* ``trace_report()`` — aggregated table of all scopes seen.
-* ``device_trace(logdir)`` — jax.profiler capture of the wrapped region
-  (the stdtracer analog for the compiled side: XLA owns the device
-  schedule, so device-side "tracing" is the profiler, not prints).
-
-The runner stamps ``KF_JOB_START_TIMESTAMP`` / ``KF_PROC_START_TIMESTAMP``
-(``runner/job.py``), and ``kungfu_tpu.utils.log.log_event`` anchors event
-lines on them — together these reproduce the reference's event-timeline
-logging (``_utils.py:44-51``).
+``KUNGFU_ENABLE_TRACE``) is :func:`kungfu_tpu.monitor.timeline.span`
+here: one tracing system, whose spans are also annotations on the
+profiler's clock.  This module keeps only the environment switch, so
+that the launcher can set it without importing the monitor package.
 """
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import os
-import threading
-import time
-from typing import Dict, Optional, Tuple
-
-from kungfu_tpu.utils.log import get_logger
-
-_log = get_logger("trace")
 
 ENABLE_TRACE = "KF_CONFIG_ENABLE_TRACE"
-
-_local = threading.local()
-_stats_lock = threading.Lock()
-_stats: Dict[str, Tuple[int, float]] = {}
-#: per-name duration histograms (monitor.registry.Histogram, imported
-#: lazily — utils must stay importable without the monitor package)
-_hists: Dict[str, object] = {}
-_Histogram = None
 
 
 def trace_enabled() -> bool:
     return os.environ.get(ENABLE_TRACE, "").lower() in ("1", "true", "yes")
-
-
-def _hist_cls():
-    global _Histogram
-    if _Histogram is None:
-        from kungfu_tpu.monitor.registry import Histogram
-
-        _Histogram = Histogram
-    return _Histogram
-
-
-def _record(name: str, dt: float) -> None:
-    # resolve the histogram class BEFORE taking the lock: the first call
-    # imports the monitor package, and running the import machinery under
-    # _stats_lock could deadlock against a module whose import-time code
-    # records a scope (import lock vs stats lock, opposite orders)
-    cls = _hist_cls()
-    with _stats_lock:
-        n, total = _stats.get(name, (0, 0.0))
-        _stats[name] = (n + 1, total + dt)
-        h = _hists.get(name)
-        if h is None:
-            h = _hists[name] = cls()
-    # observe outside _stats_lock: the histogram has its own lock and
-    # nesting the two would put an avoidable edge in the lock graph
-    h.observe(dt)
-
-
-def record_duration(name: str, dt: float) -> None:
-    """Public aggregation hook: feed one scope duration into the trace
-    stats AND its latency histogram — ``timeline.span`` regions report
-    here so ``trace_report`` covers them like any ``trace_scope``."""
-    _record(name, dt)
-
-
-@contextlib.contextmanager
-def trace_scope(name: str, force: bool = False):
-    """Time a region; nested scopes are indented by depth in the log."""
-    if not (force or trace_enabled()):
-        yield
-        return
-    depth = getattr(_local, "depth", 0)
-    _local.depth = depth + 1
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        _local.depth = depth
-        _record(name, dt)
-        _log.info("%s%s took %.3fms", "  " * depth, name, dt * 1e3)
-
-
-def traced(fn=None, *, name: Optional[str] = None):
-    """Decorator form of :func:`trace_scope`."""
-    if fn is None:
-        return functools.partial(traced, name=name)
-
-    scope = name or fn.__qualname__
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        with trace_scope(scope):
-            return fn(*args, **kwargs)
-
-    return wrapper
-
-
-def trace_report() -> Dict[str, Dict[str, float]]:
-    """Aggregated scope stats: ``{name: {count, total_s, mean_ms,
-    min_ms, max_ms, p50_ms, p95_ms}}``.  The original three keys keep
-    their exact semantics; the tail keys come from the fixed-bucket
-    histogram (``monitor.registry.Histogram``) — a mean alone hides
-    exactly the straggler tails this subsystem exists to expose."""
-    with _stats_lock:
-        snap = dict(_stats)
-        hists = dict(_hists)
-    out: Dict[str, Dict[str, float]] = {}
-    for name, (n, total) in snap.items():
-        row = {
-            "count": n,
-            "total_s": total,
-            "mean_ms": (total / n * 1e3) if n else 0.0,
-        }
-        h = hists.get(name)
-        if h is not None and h.count:
-            s = h.summary()
-            row["min_ms"] = s["min"] * 1e3
-            row["max_ms"] = s["max"] * 1e3
-            row["p50_ms"] = s["p50"] * 1e3
-            row["p95_ms"] = s["p95"] * 1e3
-        out[name] = row
-    return out
-
-
-def reset_trace_stats() -> None:
-    with _stats_lock:
-        _stats.clear()
-        _hists.clear()
-
-
-@contextlib.contextmanager
-def device_trace(logdir: str, force: bool = False):
-    """Capture a jax.profiler trace (XPlane, viewable in TensorBoard /
-    xprof) of the wrapped region.  No-op unless tracing is enabled."""
-    if not (force or trace_enabled()):
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        _log.info("device trace written to %s", logdir)

@@ -1,75 +1,81 @@
-"""Tracing subsystem tests (reference TRACE_SCOPE / event-timeline analog)."""
+"""The one tracing system (reference TRACE_SCOPE analog): what
+``utils/trace.py``'s scopes promised, held by ``timeline.span`` -- off
+by default, the env enables, durations reach the ring, nesting, an
+exception still records, attrs set on the way, the all-reduce emits a
+span."""
 
 import time
 
 import pytest
 
+from kungfu_tpu.monitor import timeline
 from kungfu_tpu.utils import trace
 
 
 @pytest.fixture(autouse=True)
-def _clean():
-    trace.reset_trace_stats()
+def _clean(monkeypatch):
+    monkeypatch.delenv(trace.ENABLE_TRACE, raising=False)
+    timeline.reset()
     yield
-    trace.reset_trace_stats()
+    timeline.reset()
 
 
-class TestTraceScope:
-    def test_disabled_by_default(self, monkeypatch, caplog):
-        monkeypatch.delenv(trace.ENABLE_TRACE, raising=False)
-        with trace.trace_scope("quiet-op"):
+class TestSpanAsScope:
+    def test_disabled_by_default(self):
+        with timeline.span("mark", "quiet-op"):
             pass
-        assert trace.trace_report() == {}
+        assert timeline.snapshot() == []
 
-    def test_records_stats(self):
-        with trace.trace_scope("op-a", force=True):
-            time.sleep(0.01)
-        with trace.trace_scope("op-a", force=True):
-            time.sleep(0.01)
-        rep = trace.trace_report()
-        assert rep["op-a"]["count"] == 2
-        assert rep["op-a"]["total_s"] >= 0.02
-        assert rep["op-a"]["mean_ms"] >= 10
+    def test_records_durations(self):
+        for _ in range(2):
+            with timeline.span("mark", "op-a", force=True):
+                time.sleep(0.01)
+        durs = [e["dur"] for e in timeline.snapshot() if e["name"] == "op-a"]
+        assert len(durs) == 2
+        assert sum(durs) >= 0.02 and min(durs) >= 0.01
 
     def test_env_enables(self, monkeypatch):
         monkeypatch.setenv(trace.ENABLE_TRACE, "true")
-        with trace.trace_scope("op-env"):
+        with timeline.span("mark", "op-env"):
             pass
-        assert trace.trace_report()["op-env"]["count"] == 1
+        assert [e["name"] for e in timeline.snapshot()] == ["op-env"]
 
-    def test_nested_scopes(self):
-        with trace.trace_scope("outer", force=True):
-            with trace.trace_scope("inner", force=True):
+    def test_nested_spans_link_parent_to_child(self):
+        with timeline.span("mark", "outer", force=True):
+            with timeline.span("mark", "inner", force=True):
                 pass
-        rep = trace.trace_report()
-        assert rep["outer"]["count"] == 1
-        assert rep["inner"]["count"] == 1
+        inner, outer = timeline.snapshot()  # inner closes first
+        assert (inner["name"], outer["name"]) == ("inner", "outer")
+        assert inner["attrs"]["parent"] == outer["attrs"]["span"]
+        assert outer["dur"] >= inner["dur"]
 
     def test_exception_still_records(self):
         with pytest.raises(ValueError):
-            with trace.trace_scope("boom", force=True):
+            with timeline.span("mark", "boom", force=True):
                 raise ValueError("x")
-        assert trace.trace_report()["boom"]["count"] == 1
+        (ev,) = timeline.snapshot()
+        assert ev["name"] == "boom" and ev["attrs"]["error"] == "ValueError"
+        # and the ambient context is unwound: the next span has no parent
+        with timeline.span("mark", "after", force=True):
+            pass
+        assert "parent" not in timeline.snapshot()[-1]["attrs"]
 
 
-class TestTracedDecorator:
-    def test_wraps(self):
-        @trace.traced(name="fn-x")
-        def f(a, b):
-            return a + b
-
-        import os
-
-        os.environ[trace.ENABLE_TRACE] = "1"
-        try:
-            assert f(1, 2) == 3
-        finally:
-            del os.environ[trace.ENABLE_TRACE]
-        assert trace.trace_report()["fn-x"]["count"] == 1
+class TestAttrsSetOnTheWay:
+    def test_set_metadata_reaches_the_ring(self):
+        with timeline.span("serve", "complete", force=True, rid="r1") as sp:
+            sp.set_metadata(pages=3, bytes=4096)
+        (ev,) = timeline.snapshot()
+        assert ev["attrs"]["rid"] == "r1"
+        assert (ev["attrs"]["pages"], ev["attrs"]["bytes"]) == (3, 4096)
+        # the disabled path takes the same call and records nothing
+        with timeline.span("serve", "complete", rid="r2") as sp:
+            sp.set_metadata(pages=1)
+        assert len(timeline.snapshot()) == 1
 
 
 class TestEngineIntegration:
-    def test_allreduce_emits_scope(self, monkeypatch):
+    def test_allreduce_emits_span(self, monkeypatch):
         """The collective engine's hot path is traced when enabled."""
         import threading
 
@@ -99,5 +105,6 @@ class TestEngineIntegration:
         for c in chans:
             c.close()
         np.testing.assert_allclose(outs[0], 2 * np.ones(4))
-        rep = trace.trace_report()
-        assert any(k.startswith("engine.all_reduce[") for k in rep)
+        spans = [e for e in timeline.snapshot() if e["kind"] == "collective"
+                 and e["name"].startswith("engine.all_reduce[")]
+        assert len(spans) == 2 and all(e["dur"] > 0 for e in spans)

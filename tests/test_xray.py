@@ -462,13 +462,16 @@ class TestServeDistributedTrace:
                    if (e["attrs"] or {}).get("trace") == trace]
             kinds = {(e["kind"], e["name"]) for e in evs}
             # router admission + completion, the worker's frame receipt,
-            # and the engine's prefill span: ONE distributed trace
+            # and the engine's admission with the prefill inside it: ONE
+            # distributed trace
             assert ("request", "accept") in kinds
             assert ("request", "complete") in kinds
             assert ("serve", "request-recv") in kinds
             assert ("serve", "prefill") in kinds
+            admit = next(e for e in evs if e["name"] == "admit")
+            assert admit["attrs"]["parent"] == h.router_span
             prefill = next(e for e in evs if e["name"] == "prefill")
-            assert prefill["attrs"]["parent"] == h.router_span
+            assert prefill["attrs"]["parent"] == admit["attrs"]["span"]
             recv = next(e for e in evs if e["name"] == "request-recv")
             assert recv["attrs"]["parent"] == h.router_span
         finally:
