@@ -18,10 +18,18 @@ length bucket + one decode trace — the recompile-hazard discipline):
   generated token.  The cached prefix comes straight out of the paged
   pool (prefix-chain hit), so a shared system prompt costs its pages'
   load, not its FLOPs — the measured delta in ``bench.py --serve``.
-* **decode** — one token for every active slot: write K/V at each
-  slot's position, attend over ``[0, pos]``, greedy argmax (greedy on
-  purpose: a replayed request deterministically re-derives the same
-  continuation from its committed prefix, docs/serving.md).
+* **decode** — one token for every active slot: write one K/V row per
+  slot at the slot's position, attend over ``[0, pos]`` of the slab
+  itself, greedy argmax (greedy on purpose: a replayed request
+  deterministically re-derives the same continuation from its committed
+  prefix, docs/serving.md).
+
+The slabs (``[L, B, H, S, D]``, one for K and one for V) are updated in
+place by all three programs that write them (prefill, decode and the
+restore of cached pages): each takes them donated and writes only the
+rows it adds, so no call copies a slab or a layer of it.  A slab handed
+to one of these programs is gone; the engine, and anyone who calls the
+jitted functions, keeps only the returned pair.
 
 Fault surface: the engine is process-local and carries no collective
 state — worker death is handled ABOVE it by the router's replay ladder
@@ -31,6 +39,7 @@ committed their full pages to the pool first.
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import deque
@@ -133,11 +142,14 @@ class InferenceEngine:
         dt = cfg.compute_dtype
         self._k = jnp.zeros((L, B, H, S, D), dt)
         self._v = jnp.zeros((L, B, H, S, D), dt)
-        # no donate_argnums: the CPU backend ignores donation (with a
-        # warning per compile); on chip the slab update is small next to
-        # the model math and the jit cache keys per prefill bucket shape
-        self._decode_j = jax.jit(self._decode_fn)
-        self._prefill_j = jax.jit(self._prefill_fn)
+        # Every program that writes the slabs takes them donated and
+        # updates them in place, on every backend: a call that copied
+        # them would move 3 GB at GPT-2 large (PERF.md, PR 25).  The
+        # rule that brings: a slab handed to one of these is deleted --
+        # keep only the returned pair.
+        self._decode_j = jax.jit(self._decode_fn, donate_argnums=(1, 2))
+        self._prefill_j = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
+        self._restore_j = jax.jit(self._restore_fn, donate_argnums=(0, 1))
         # kf-xray serving MFU: analytic prefill/decode FLOPs accumulate
         # per step into the kf_model_flops_s gauge (+ kf_mfu when a chip
         # peak is known; None on the CPU mesh — docs/xray.md)
@@ -211,15 +223,22 @@ class InferenceEngine:
             x = nn.layernorm_apply(lp["ln1"], h)
             q, k, v = self._layer_qkv(lp, x, positions[None])
             with jax.named_scope("kv_write"):
+                # (no index is negative: normalising them is a third of
+                # this function's tracing time, once a bucket)
                 k_slab = jax.lax.dynamic_update_slice(
-                    k_slab, k[None], (li, slot, 0, start, 0))
+                    k_slab, k[None], (li, slot, 0, start, 0),
+                    allow_negative_indices=False)
                 v_slab = jax.lax.dynamic_update_slice(
-                    v_slab, v[None], (li, slot, 0, start, 0))
+                    v_slab, v[None], (li, slot, 0, start, 0),
+                    allow_negative_indices=False)
             with jax.named_scope("attn_core"):
-                keys = jax.lax.dynamic_index_in_dim(k_slab[li], slot, 0,
-                                                    keepdims=True)
-                values = jax.lax.dynamic_index_in_dim(v_slab[li], slot, 0,
-                                                      keepdims=True)
+                # one dynamic slice of (layer, slot): taking the layer
+                # first would materialise all its slots
+                at, size = (li, slot, 0, 0, 0), (1, 1) + k_slab.shape[2:]
+                keys = jax.lax.dynamic_slice(
+                    k_slab, at, size, allow_negative_indices=False)[0]
+                values = jax.lax.dynamic_slice(
+                    v_slab, at, size, allow_negative_indices=False)[0]
                 o = self._attend(q, keys, values, mask)
             h = self._out_and_mlp(lp, h, o)
         h = nn.layernorm_apply(params["ln_f"], h)
@@ -229,6 +248,41 @@ class InferenceEngine:
             logits = nn.dense_apply(params["head"], last).astype(jnp.float32)
             tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
         return k_slab, v_slab, tok
+
+    @staticmethod
+    def _row_windows(pos, s_max):
+        """Per slot ``b``: where the aligned window of ``S`` that holds
+        position ``pos[b]`` starts, and which of its rows that is.  Each
+        start is a scalar ``p // w * w`` on purpose: from that the
+        compiler knows the window is tile-aligned and updates it in
+        place; sliced out of a vector of starts it no longer does, and
+        the write takes five times as long (tests/test_tpu_compile.py)."""
+        w = math.gcd(s_max, 128)                # divides S: never clamped
+        lane = jnp.arange(w)[:, None]
+        return [(p // w * w, lane == p % w) for p in pos]
+
+    @staticmethod
+    def _write_rows(slab, li, new, windows):
+        """Row ``b`` of ``new`` [B, H, 1, D] into layer ``li`` of
+        ``slab`` at slot ``b``'s position, in place, as a
+        read-modify-write of the window of :meth:`_row_windows`.  The TPU
+        lays ``S`` along the lanes, 128 to a tile, so that window is what
+        a one-row ``dynamic_update_slice`` touches anyway, unrolled: 5 us
+        and 140 KB of code a slot and layer.  Window by window, XLA fuses
+        the slice, the select and the update of K and V into one in-place
+        loop (2.9 against 6.9 ms a step at GPT-2 large, PERF.md PR 25).
+        A scatter would be one operation, but the compiler lays the whole
+        slab out anew around it (1.2 s a step)."""
+        size = (1, 1, slab.shape[2], windows[0][1].shape[0], slab.shape[4])
+        for b, (start, hit) in enumerate(windows):
+            at = (li, b, 0, start, 0)
+            old = jax.lax.dynamic_slice(slab, at, size,
+                                        allow_negative_indices=False)
+            slab = jax.lax.dynamic_update_slice(
+                slab, jnp.where(hit, jax.lax.slice_in_dim(new, b, b + 1),
+                                old), at,
+                allow_negative_indices=False)
+        return slab
 
     def _decode_fn(self, params, k_slab, v_slab, last_ids, pos):
         """One token for every slot: ``last_ids``/``pos`` are [B]; the
@@ -245,26 +299,33 @@ class InferenceEngine:
                 h = h + nn.embedding_apply(params["pos_embed"], positions,
                                            dtype=dt)
         mask = (jnp.arange(s_max)[None, :] <= positions)[:, None, None, :]
-
-        def upd(slab_b, new_b, p):  # [H,S,D], [H,1,D], scalar
-            return jax.lax.dynamic_update_slice(slab_b, new_b, (0, p, 0))
-
+        windows = self._row_windows(pos, s_max)
         for li in range(cfg.n_layers):
             lp = params[f"layer_{li}"]
             x = nn.layernorm_apply(lp["ln1"], h)
             q, k, v = self._layer_qkv(lp, x, positions)
             with jax.named_scope("kv_write"):
-                k_l = jax.vmap(upd)(k_slab[li], k, pos)
-                v_l = jax.vmap(upd)(v_slab[li], v, pos)
-                k_slab = k_slab.at[li].set(k_l)
-                v_slab = v_slab.at[li].set(v_l)
-            h = self._out_and_mlp(lp, h, self._attend(q, k_l, v_l, mask))
+                k_slab = self._write_rows(k_slab, li, k, windows)
+                v_slab = self._write_rows(v_slab, li, v, windows)
+            h = self._out_and_mlp(lp, h, self._attend(
+                q, k_slab[li], v_slab[li], mask))
         h = nn.layernorm_apply(params["ln_f"], h)
         with jax.named_scope("head"):
             logits = nn.dense_apply(params["head"], h[:, 0]
                                     ).astype(jnp.float32)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return k_slab, v_slab, tok
+
+    @staticmethod
+    @jax.named_scope("kv_write")
+    def _restore_fn(k_slab, v_slab, ks, vs, slot):
+        """Cached pages ``ks``/``vs`` [L, H, R, D] back into positions
+        ``[0, R)`` of ``slot``."""
+        at = (0, slot, 0, 0, 0)
+        return (jax.lax.dynamic_update_slice(k_slab, ks[:, None], at,
+                                             allow_negative_indices=False),
+                jax.lax.dynamic_update_slice(v_slab, vs[:, None], at,
+                                             allow_negative_indices=False))
 
     def _prefill_bucket(self, n: int) -> int:
         """Static prefill length: the smallest power-of-two multiple of
@@ -290,17 +351,23 @@ class InferenceEngine:
             buckets.append(b)
             b *= 2
         buckets.append(top)
+        cfg = self.model.cfg
         for s_pad in buckets:
-            ids = jnp.zeros(s_pad, jnp.int32)
-            # results discarded: jit populates its trace cache, the live
-            # slabs are untouched (functional updates on copies)
-            self._prefill_j(self.params, self._k, self._v, ids,
-                            jnp.int32(1), jnp.int32(0), jnp.int32(0)
-                            )[2].block_until_ready()
-        self._decode_j(self.params, self._k, self._v,
-                       jnp.zeros(self.max_batch, jnp.int32),
-                       jnp.zeros(self.max_batch, jnp.int32)
-                       )[2].block_until_ready()
+            # the slabs are donated, so each call's pair replaces the
+            # engine's; what the calls write (zeros and one row of slot 0,
+            # before any request) nobody reads
+            pages = jnp.zeros((cfg.n_layers, cfg.n_heads, s_pad,
+                               cfg.head_dim), cfg.compute_dtype)
+            self._k, self._v = self._restore_j(self._k, self._v, pages,
+                                               pages, jnp.int32(0))
+            self._k, self._v, tok = self._prefill_j(
+                self.params, self._k, self._v, jnp.zeros(s_pad, jnp.int32),
+                jnp.int32(1), jnp.int32(0), jnp.int32(0))
+        self._k, self._v, tok = self._decode_j(
+            self.params, self._k, self._v,
+            jnp.zeros(self.max_batch, jnp.int32),
+            jnp.zeros(self.max_batch, jnp.int32))
+        tok.block_until_ready()
 
     # -- scheduling ------------------------------------------------------
     @property
@@ -402,18 +469,7 @@ class InferenceEngine:
         req.slot = slot
         req.admitted_s = time.perf_counter()
         if n_cached:
-            ks = np.stack([self.pool.page_data(p)[0] for p in cached_pages],
-                          axis=2)  # [L, H, n_pages, T, D] stacked on axis 2
-            vs = np.stack([self.pool.page_data(p)[1] for p in cached_pages],
-                          axis=2)
-            L, H = ks.shape[0], ks.shape[1]
-            ks = ks.reshape(L, H, n_cached, -1)
-            vs = vs.reshape(L, H, n_cached, -1)
-            dt = self.model.cfg.compute_dtype
-            self._k = self._k.at[:, slot, :, :n_cached, :].set(
-                jnp.asarray(ks, dt))
-            self._v = self._v.at[:, slot, :, :n_cached, :].set(
-                jnp.asarray(vs, dt))
+            self._restore(slot, cached_pages, n_cached)
         suffix = req.tokens[n_cached:]
         s_pad = self._prefill_bucket(len(suffix))
         ids = np.zeros(s_pad, np.int32)
@@ -437,6 +493,22 @@ class InferenceEngine:
         with self._lock:
             self._active[slot] = req
         return True
+
+    def _restore(self, slot: int, pages: List[int], n_cached: int) -> None:
+        """Write the cached prefix's pages into ``slot``, padded with
+        zeros to a prefill bucket (one restore program per bucket; the
+        padding lands where the prefill and decode write before anyone
+        reads)."""
+        cfg, T = self.model.cfg, self._page_tokens
+        shape = (cfg.n_layers, cfg.n_heads, self._prefill_bucket(n_cached),
+                 cfg.head_dim)
+        ks = np.zeros(shape, cfg.compute_dtype)
+        vs = np.zeros(shape, cfg.compute_dtype)
+        for i, p in enumerate(pages):
+            ks[:, :, i * T:(i + 1) * T], vs[:, :, i * T:(i + 1) * T] = (
+                self.pool.page_data(p))
+        self._k, self._v = self._restore_j(self._k, self._v, ks, vs,
+                                           jnp.int32(slot))
 
     # -- completion ------------------------------------------------------
     def _retire_locked(self, slot: int, req: _Req) -> None:

@@ -10,9 +10,11 @@ view (docs/serving.md).
 """
 
 import functools
+import re
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -205,6 +207,131 @@ class TestEngine:
         assert (REGISTRY.gauge("kf_kv_cache_bytes").value
                 == eng.pool.footprint_bytes > 0)
         eng.drain()
+
+
+# -- the slab is written in place -------------------------------------------
+def _lower_slab_program(eng, program):
+    """One of the three programs that write the slabs, lowered over the
+    engine's own: (lowered, position of k_slab among the arguments)."""
+    z = jnp.zeros(eng.max_batch, jnp.int32)
+    i0 = jnp.int32(0)
+    if program == "decode":
+        return eng._decode_j.lower(eng.params, eng._k, eng._v, z, z), 1
+    if program == "prefill":
+        return eng._prefill_j.lower(
+            eng.params, eng._k, eng._v, jnp.zeros(16, jnp.int32),
+            jnp.int32(3), i0, i0), 1
+    cfg = eng.model.cfg
+    pages = jnp.zeros((cfg.n_layers, cfg.n_heads, 8, cfg.head_dim),
+                      cfg.compute_dtype)
+    return eng._restore_j.lower(eng._k, eng._v, pages, pages, i0), 0
+
+
+class TestSlabInPlace:
+    """Donation's one rule: a slab handed to a program is gone, and the
+    engine holds the returned pair (docs/serving.md)."""
+
+    @pytest.mark.parametrize("phase", ["warmup", "admission", "decode",
+                                       "prefix_hit"])
+    def test_engine_keeps_only_the_returned_slabs(self, model_and_params,
+                                                  phase):
+        model, params = model_and_params
+        eng = make_engine(model_and_params)
+        shared = list(range(1, 20))  # 2 full pages of 8
+        if phase == "decode":
+            eng.submit("a", shared, 6)
+            eng.step()
+        elif phase == "prefix_hit":
+            eng.submit("first", shared + [21], 4)
+            eng.drain()
+        old = (eng._k, eng._v)
+        if phase == "warmup":
+            eng.warmup((20,))
+            evs = []
+        else:
+            if phase == "admission":
+                eng.submit("a", shared, 6)
+            elif phase == "prefix_hit":
+                eng.submit("second", shared + [22], 4)
+            evs = eng.step()
+        kinds = [e["kind"] for e in evs]
+        assert ("admit" in kinds) == (phase in ("admission", "prefix_hit"))
+        if phase == "prefix_hit":
+            assert evs[0]["reused"] == 16
+        assert all(a.is_deleted() for a in old)
+        for a in (eng._k, eng._v):
+            assert not a.is_deleted()
+            assert np.isfinite(np.asarray(a)).all()
+        # and the engine goes on serving from them
+        eng.drain()
+        eng.submit("after", [5, 6, 7], 5)
+        done = [e for e in eng.drain() if e["kind"] == "done"]
+        assert done[0]["tokens"] == reference_tokens(model, params,
+                                                     [5, 6, 7], 5)
+
+    @pytest.mark.parametrize("program", ["decode", "prefill", "restore"])
+    def test_lowered_program_aliases_both_slabs(self, model_and_params,
+                                                program):
+        eng = make_engine(model_and_params)
+        lowered, at = _lower_slab_program(eng, program)
+        args = lowered.args_info[0]
+        assert args[at].donated and args[at + 1].donated
+        others = [a for i, a in enumerate(args) if i not in (at, at + 1)]
+        assert not any(a.donated for a in jax.tree_util.tree_leaves(others))
+        shape = "x".join(str(n) for n in eng._k.shape)
+        aliased = re.findall(
+            rf"tensor<{shape}x\w+> {{tf\.aliasing_output = (\d+) : i32}}",
+            lowered.as_text())
+        assert aliased == ["0", "1"]  # k_slab -> first result, v_slab -> second
+
+    @pytest.mark.parametrize("max_batch,warm", [(2, False), (2, True),
+                                                (3, False)])
+    def test_staggered_run_matches_full_context_reference(
+            self, model_and_params, max_batch, warm):
+        """Requests admitted mid-flight at different positions, slots
+        reused after completions, one request restored from the prefix
+        cache: every one yields, token for token, what the training-path
+        forward yields alone."""
+        model, params = model_and_params
+        eng = make_engine(model_and_params, max_batch=max_batch)
+        if warm:
+            eng.warmup((40,))
+        shared = list(range(1, 17))  # 2 full pages of 8
+        asked = {"r0": (shared + [40, 41, 42], 7),
+                 "r1": ([9, 8, 7, 6, 5], 12),
+                 "r2": ([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], 3),
+                 "r3": (shared + [50, 51], 9),
+                 "r4": ([60, 61], 10)}
+        events, slots = [], {}
+
+        def step(n=1):
+            for _ in range(n):
+                events.extend(eng.step())
+                for slot, r in eng._active.items():
+                    slots[r.rid] = slot
+
+        def finished(rid):
+            return any(e["kind"] == "done" and e["rid"] == rid
+                       for e in events)
+
+        eng.submit("r0", *asked["r0"])
+        step(3)
+        eng.submit("r1", *asked["r1"])   # joins at another position
+        step(2)
+        eng.submit("r2", *asked["r2"])   # max_batch 2: waits for a slot
+        while not finished("r0"):
+            step()
+        eng.submit("r3", *asked["r3"])   # r0's committed pages come back
+        eng.submit("r4", *asked["r4"])
+        while eng.pending_count or eng.active_count:
+            step()
+        reused = {e["rid"]: e["reused"] for e in events
+                  if e["kind"] == "admit"}
+        assert reused == {"r0": 0, "r1": 0, "r2": 0, "r3": 16, "r4": 0}
+        assert len(set(slots.values())) < len(slots)  # a slot served twice
+        done = {e["rid"]: e["tokens"] for e in events if e["kind"] == "done"}
+        assert done == {rid: reference_tokens(model, params, prompt, n)
+                        for rid, (prompt, n) in asked.items()}
 
 
 # -- chaos request-path clauses --------------------------------------------

@@ -12,6 +12,7 @@ Shapes are GPT-small's on one chip at batch 4 x sequence 2048
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
 
@@ -146,3 +147,84 @@ def test_vmem_budget_is_the_compilers(topo):
     elems = (10 << 20) // 4
     _lower(topo, _ring(ring_reduce_scatter, False),
            [((4 * elems,), jnp.float32)], 4).compile()
+
+
+# -- the serving engine's programs move no slab --------------------------------
+#: GPT-2 large with 16 slots of 1024 positions: the chat cell's programs
+#: (at fewer layers the compiler, with memory to spare, makes other choices)
+SERVE_LAYERS, SERVE_SLOTS, SERVE_SEQ = 36, 16, 1024
+
+
+@pytest.fixture(scope="module")
+def serve_programs(topo):
+    """name -> compiled text of the engine's three slab writers, lowered
+    from shapes alone (no array of that size is made here)."""
+    from kungfu_tpu.models.transformer import Transformer, TransformerConfig
+    from kungfu_tpu.serve.engine import InferenceEngine
+    from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
+
+    cfg = TransformerConfig(vocab_size=50257, d_model=1280,
+                            n_layers=SERVE_LAYERS, n_heads=20, d_ff=5120,
+                            max_seq=SERVE_SEQ, dropout=0.0, causal=True,
+                            pos="learned", dtype="bfloat16")
+    model = Transformer(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        lambda x: shaped(x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    # a one-page engine: its programs take the slab's size from the slab
+    eng = InferenceEngine(
+        model, None, max_batch=SERVE_SLOTS, max_seq=16,
+        pool=KVCachePool(PageSpec.for_model(cfg, page_tokens=16),
+                         capacity_pages=1))
+    slab = shaped((cfg.n_layers, SERVE_SLOTS, cfg.n_heads, SERVE_SEQ,
+                   cfg.head_dim), bf16)
+    pages = shaped((cfg.n_layers, cfg.n_heads, 256, cfg.head_dim), bf16)
+    slots, i0 = shaped((SERVE_SLOTS,), i32), shaped((), i32)
+    lowered = {
+        "decode": eng._decode_j.lower(params, slab, slab, slots, slots),
+        "prefill": eng._prefill_j.lower(params, slab, slab,
+                                        shaped((256,), i32), i0, i0, i0),
+        "restore": eng._restore_j.lower(slab, slab, pages, pages, i0),
+    }
+    return {name: lo.compile().as_text() for name, lo in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "restore"])
+def test_serving_program_writes_its_slab_in_place(serve_programs, program):
+    """Both slabs alias their outputs, and no operation of the compiled
+    program but the in-place updates produces a slab or a layer of one:
+    no copy of either, no materialised layer slice (PERF.md, PR 25: those were 54 of a 66 ms decode step)."""
+    text = serve_programs[program]
+    assert len(re.findall(r"may-alias|must-alias",
+                          text.split("\n", 1)[0])) == 2
+    layer = f"{SERVE_SLOTS},20,{SERVE_SEQ},64"
+    entry = text[text.index("\nENTRY"):]
+    moved = []
+    for name, dims, op in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+            entry, re.M):
+        in_place = (op == "dynamic-update-slice"
+                    or "dynamic-update-slice" in name
+                    or "dynamic_update_slice" in name)
+        if dims.endswith(layer) and not in_place and op not in (
+                "parameter", "bitcast", "get-tuple-element", "tuple"):
+            moved.append((op, name, dims))
+    assert not moved
+
+
+def test_decode_row_write_is_one_fused_window_update(serve_programs):
+    """The decode step writes a slot's row as one in-place fusion over the
+    aligned window that holds it (K and V together), not as a plain
+    ``dynamic-update-slice``: that one is unrolled, and took 9.4 ms a
+    step where the fusions take 1.9 (PERF.md, PR 25)."""
+    entry = serve_programs["decode"]
+    entry = entry[entry.index("\nENTRY"):]
+    assert not re.findall(r" dynamic-update-slice\(", entry)
+    fused = re.findall(r"^\s*%?[\w.\-]*dynamic-update-slice_fusion[\w.]* = ",
+                       entry, re.M)
+    assert len(fused) == SERVE_LAYERS * SERVE_SLOTS
