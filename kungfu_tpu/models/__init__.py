@@ -16,12 +16,17 @@ models because they are its benchmark workload:
   second ImageNet family), NHWC, bf16, optional sync-BN.
 * :mod:`kungfu_tpu.models.transformer` — GPT-style transformer (the
   flagship; BERT-base-sized config included), ring-attention capable.
+* :mod:`kungfu_tpu.models.cohere2_moe` — the ``cohere2_moe`` decoder
+  (window and full attention layers, grouped-query heads, a sparse
+  expert layer beside the attention), served by ``serve/engine.py``;
+  :mod:`kungfu_tpu.models.experts` is its expert layer.
 * :mod:`kungfu_tpu.models.fake` — gradient-shaped fake models for
   collective benchmarking without real compute (parity with
   ``tests/go/fakemodel``).
 """
 
 from kungfu_tpu.models import nn
+from kungfu_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
 from kungfu_tpu.models.mlp import MLP, mnist_slp
 from kungfu_tpu.models.resnet import ResNet, resnet50
 from kungfu_tpu.models.transformer import Transformer, TransformerConfig, bert_base, gpt_small
@@ -30,6 +35,8 @@ from kungfu_tpu.models.fake import fake_model_sizes, fake_grads
 
 __all__ = [
     "nn",
+    "Cohere2Moe",
+    "Cohere2MoeConfig",
     "MLP",
     "mnist_slp",
     "ResNet",

@@ -177,7 +177,9 @@ def layernorm_apply(p, x, eps=1e-5):
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), -1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+    y = (xf - mean) * jax.lax.rsqrt(var + eps) * p["scale"]
+    if "bias" in p:  # (a bias-free norm: models/cohere2_moe.py)
+        y = y + p["bias"]
     return y.astype(x.dtype)
 
 

@@ -201,6 +201,14 @@ class Transformer:
         B, H, S, D = x.shape
         return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
 
+    def serve_caches(self, max_batch: int, max_seq: int):
+        """What ``serve.engine.InferenceEngine`` serves this model
+        through: its slabs, its prefill and decode bodies and the host's
+        side of a page (the interface is in ``serve/caches.py``)."""
+        from kungfu_tpu.serve.caches import DenseCaches
+
+        return DenseCaches(self, max_batch, max_seq)
+
     def loss(self, params, batch, train: bool = True, rng=None, attn_fn=None, positions=None):
         """Next-token LM loss; batch = (ids, targets) both [B, S].
 

@@ -10,9 +10,11 @@ plane:
   (fixed-size pages, free-list allocation, prefix-hash reuse, LRU
   eviction) whose per-rank footprint is the ``kf_kv_cache_bytes`` gauge
   next to ``kf_opt_state_bytes``;
-* :mod:`kungfu_tpu.serve.engine` — continuous-batching decode loop over
-  :mod:`kungfu_tpu.models.transformer` (jit-compiled prefill/decode
-  steps, decode-priority admission);
+* :mod:`kungfu_tpu.serve.engine` — continuous-batching decode loop
+  (jit-compiled prefill/decode steps, decode-priority admission) over
+  whatever ``model.serve_caches`` answers: :mod:`kungfu_tpu.serve.caches`
+  for the dense transformer, :mod:`kungfu_tpu.serve.windowed` for a
+  model that mixes window and full attention layers;
 * :mod:`kungfu_tpu.serve.router` — request router + admission policy
   (FCFS, bounded queue, typed overload rejection) speaking over the
   existing host channel / p2p handler machinery, with SLO-gated fault

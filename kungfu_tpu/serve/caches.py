@@ -1,0 +1,262 @@
+"""What ``InferenceEngine`` asks of the model it serves, and the dense
+``Transformer``'s answer.
+
+The engine is the scheduler: requests, slots, admission, pages, spans.
+Everything that depends on how a model lays its keys and values out it
+asks of one object, ``model.serve_caches(max_batch, max_seq)``:
+
+``new_slabs()``
+    the device cache as a pair ``(k, v)``, each an array or a tree of
+    arrays.  The engine only ever hands the pair on.
+``prefill(params, k, v, ids, n, start, slot)`` -> ``(k, v, token)``
+    the body of the prefill program: ``ids`` ``[P]`` (the prompt past
+    ``start`` cached positions, zero-padded past ``n``) into ``slot``;
+    the greedy token after row ``n - 1``.
+``decode(params, k, v, last_ids, pos)`` -> ``(k, v, out)``
+    the body of the decode program: one token for every slot.  ``out``
+    is ONE array, so that the host's one read brings all a step has to
+    say; ``read(out)`` takes it apart.
+``read(out)`` -> ``(tokens [B], attrs or None)``
+    on the host: fetch a decode step's ``out`` (the host waits here), the
+    slots' tokens and what the step has to say of itself besides, as
+    attrs of the ``kf:serve.decode_read`` span that is open meanwhile.
+``empty_pages(rows)``, ``pages_to_slot(data, n_cached, rows, page_tokens)``
+    what the restore program writes into a slot for ``rows`` cached
+    positions (a tree shaped like ``k``, without the slot axis): zeros,
+    and the K (or V) of a cached prefix's pages ``[L, H, T, D]`` in order.
+``rows_of_slot(slab, slot, lo, hi, total)`` -> ``(rows, kept_from)``
+    positions ``[lo, hi)`` of a finished request of ``total`` tokens as
+    page data ``[L, H, hi - lo, D]``, and the first position all of
+    whose layers' rows still exist (pages before it are not ``whole``:
+    ``KVCachePool.reusable``).
+``prefill_flops(tokens, start)``, ``decode_flops(contexts)``
+    the analytic cost of a prefill and of a decode step over its live
+    contexts, for the serving MFU gauge.
+
+Both bodies take the slabs donated and write them in place; the restore
+program is the engine's own (a ``dynamic_update_slice`` into every leaf).
+:class:`DenseCaches` is the dense ``Transformer``'s (one slab ``[L, B, H,
+S, D]`` for K and one for V, every layer keeping every position);
+``serve/windowed.py`` the one of a model that mixes window and full
+attention layers.  :func:`row_windows` and :func:`write_rows`, the
+in-place write of one row a slot, are shared by both.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from kungfu_tpu.models import nn
+from kungfu_tpu.models.transformer import _rope
+from kungfu_tpu.ops import costmodel
+
+
+def row_windows(pos, s_max):
+    """Per slot ``b``: where the aligned window of ``S`` that holds
+    position ``pos[b]`` starts, and which of its rows that is.  Each
+    start is a scalar ``p // w * w`` on purpose: from that the
+    compiler knows the window is tile-aligned and updates it in
+    place; sliced out of a vector of starts it no longer does, and
+    the write takes five times as long (tests/test_tpu_compile.py)."""
+    w = math.gcd(s_max, 128)                # divides S: never clamped
+    lane = jnp.arange(w)[:, None]
+    return [(p // w * w, lane == p % w) for p in pos]
+
+
+def write_rows(slab, li, new, windows):
+    """Row ``b`` of ``new`` [B, H, 1, D] into layer ``li`` of
+    ``slab`` at slot ``b``'s position, in place, as a
+    read-modify-write of the window of :func:`row_windows`.  The TPU
+    lays ``S`` along the lanes, 128 to a tile, so that window is what
+    a one-row ``dynamic_update_slice`` touches anyway, unrolled: 5 us
+    and 140 KB of code a slot and layer.  Window by window, XLA fuses
+    the slice, the select and the update of K and V into one in-place
+    loop (2.9 against 6.9 ms a step at GPT-2 large, PERF.md PR 25).
+    A scatter would be one operation, but the compiler lays the whole
+    slab out anew around it (1.2 s a step)."""
+    size = (1, 1, slab.shape[2], windows[0][1].shape[0], slab.shape[4])
+    for b, (start, hit) in enumerate(windows):
+        at = (li, b, 0, start, 0)
+        old = jax.lax.dynamic_slice(slab, at, size,
+                                    allow_negative_indices=False)
+        slab = jax.lax.dynamic_update_slice(
+            slab, jnp.where(hit, jax.lax.slice_in_dim(new, b, b + 1),
+                            old), at,
+            allow_negative_indices=False)
+    return slab
+
+
+class DenseCaches:
+    """The dense ``Transformer`` through the engine: K and V one slab
+    ``[L, B, H, S, D]`` each in the compute dtype."""
+
+    def __init__(self, model, max_batch: int, max_seq: int):
+        self.model = model
+        self.cfg = model.cfg
+        self.batch, self.seq = int(max_batch), int(max_seq)
+
+    def new_slabs(self):
+        cfg = self.cfg
+        shape = (cfg.n_layers, self.batch, cfg.n_heads, self.seq,
+                 cfg.head_dim)
+        return (jnp.zeros(shape, cfg.compute_dtype),
+                jnp.zeros(shape, cfg.compute_dtype))
+
+    # -- forward passes --------------------------------------------------
+    @jax.named_scope("attn_proj")
+    def _layer_qkv(self, lp, x, positions):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+
+        def heads(t):
+            b, s, _ = t.shape
+            return t.reshape(b, s, cfg.n_heads, cfg.head_dim
+                             ).transpose(0, 2, 1, 3)
+
+        q = heads(nn.dense_apply(lp["wq"], x, dtype=dt))
+        k = heads(nn.dense_apply(lp["wk"], x, dtype=dt))
+        v = heads(nn.dense_apply(lp["wv"], x, dtype=dt))
+        if cfg.pos == "rope":
+            q, k = _rope(q, k, positions)
+        return q, k, v
+
+    @staticmethod
+    @jax.named_scope("attn_core")
+    def _attend(q, keys, values, mask):
+        """q [B,H,Q,D] over keys/values [B,H,S,D]; mask [B,1,Q,S] (or
+        broadcastable) True = attend.  f32 logits/softmax like the
+        training path."""
+        d = q.shape[-1]
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys
+                            ).astype(jnp.float32) / jnp.sqrt(d)
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", probs, values)
+
+    @staticmethod
+    def _merge(x):
+        b, h, s, d = x.shape
+        return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+    def _out_and_mlp(self, lp, h, o):
+        """The rest of a block after attention ``o`` [B,H,Q,D]: the
+        output projection and the MLP, each added to the stream ``h``."""
+        dt = self.cfg.compute_dtype
+        with jax.named_scope("attn_proj"):
+            h = h + nn.dense_apply(lp["wo"], self._merge(o), dtype=dt)
+        x = nn.layernorm_apply(lp["ln2"], h)
+        with jax.named_scope("mlp"):
+            y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
+            return h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
+
+    def prefill(self, params, k_slab, v_slab, ids, n, start, slot):
+        """ids [S_pad] (suffix, zero-padded past ``n``); writes K/V at
+        positions ``[start, start + S_pad)`` of ``slot`` and returns the
+        greedy next token after the last REAL row (``n - 1``)."""
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        s_pad = ids.shape[0]
+        s_max = k_slab.shape[3]
+        positions = start + jnp.arange(s_pad)
+        with jax.named_scope("embed"):
+            h = nn.embedding_apply(params["embed"], ids[None], dtype=dt)
+            if cfg.pos == "learned":
+                h = h + nn.embedding_apply(params["pos_embed"],
+                                           positions[None], dtype=dt)
+        q_pos = positions
+        key_pos = jnp.arange(s_max)
+        mask = (key_pos[None, :] <= q_pos[:, None])[None, None]  # [1,1,Q,S]
+        for li in range(cfg.n_layers):
+            lp = params[f"layer_{li}"]
+            x = nn.layernorm_apply(lp["ln1"], h)
+            q, k, v = self._layer_qkv(lp, x, positions[None])
+            with jax.named_scope("kv_write"):
+                # (no index is negative: normalising them is a third of
+                # this function's tracing time, once a bucket)
+                k_slab = jax.lax.dynamic_update_slice(
+                    k_slab, k[None], (li, slot, 0, start, 0),
+                    allow_negative_indices=False)
+                v_slab = jax.lax.dynamic_update_slice(
+                    v_slab, v[None], (li, slot, 0, start, 0),
+                    allow_negative_indices=False)
+            with jax.named_scope("attn_core"):
+                # one dynamic slice of (layer, slot): taking the layer
+                # first would materialise all its slots
+                at, size = (li, slot, 0, 0, 0), (1, 1) + k_slab.shape[2:]
+                keys = jax.lax.dynamic_slice(
+                    k_slab, at, size, allow_negative_indices=False)[0]
+                values = jax.lax.dynamic_slice(
+                    v_slab, at, size, allow_negative_indices=False)[0]
+                o = self._attend(q, keys, values, mask)
+            h = self._out_and_mlp(lp, h, o)
+        h = nn.layernorm_apply(params["ln_f"], h)
+        with jax.named_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(h, n - 1, axis=1,
+                                                keepdims=False)
+            logits = nn.dense_apply(params["head"], last).astype(jnp.float32)
+            tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+        return k_slab, v_slab, tok
+
+    def decode(self, params, k_slab, v_slab, last_ids, pos):
+        """One token for every slot: ``last_ids``/``pos`` are [B]; the
+        new K/V lands at each slot's ``pos`` and attention covers
+        ``[0, pos]``.  Inactive slots compute garbage nobody reads."""
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        s_max = k_slab.shape[3]
+        positions = pos[:, None]                     # [B, 1]
+        with jax.named_scope("embed"):
+            h = nn.embedding_apply(params["embed"], last_ids[:, None],
+                                   dtype=dt)
+            if cfg.pos == "learned":
+                h = h + nn.embedding_apply(params["pos_embed"], positions,
+                                           dtype=dt)
+        mask = (jnp.arange(s_max)[None, :] <= positions)[:, None, None, :]
+        windows = row_windows(pos, s_max)
+        for li in range(cfg.n_layers):
+            lp = params[f"layer_{li}"]
+            x = nn.layernorm_apply(lp["ln1"], h)
+            q, k, v = self._layer_qkv(lp, x, positions)
+            with jax.named_scope("kv_write"):
+                k_slab = write_rows(k_slab, li, k, windows)
+                v_slab = write_rows(v_slab, li, v, windows)
+            h = self._out_and_mlp(lp, h, self._attend(
+                q, k_slab[li], v_slab[li], mask))
+        h = nn.layernorm_apply(params["ln_f"], h)
+        with jax.named_scope("head"):
+            logits = nn.dense_apply(params["head"], h[:, 0]
+                                    ).astype(jnp.float32)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return k_slab, v_slab, tok
+
+    @staticmethod
+    def read(out):
+        return np.asarray(jax.device_get(out)), None
+
+    # -- the host's side of a page ---------------------------------------
+    def empty_pages(self, rows: int):
+        cfg = self.cfg
+        return np.zeros((cfg.n_layers, cfg.n_heads, rows, cfg.head_dim),
+                        cfg.compute_dtype)
+
+    def pages_to_slot(self, data, n_cached: int, rows: int, page_tokens: int):
+        out, t = self.empty_pages(rows), page_tokens
+        for i, page in enumerate(data):
+            out[:, :, i * t:(i + 1) * t] = page
+        return out
+
+    @staticmethod
+    def rows_of_slot(slab, slot: int, lo: int, hi: int, total: int):
+        return np.asarray(jax.device_get(slab[:, slot, :, lo:hi, :])), 0
+
+    # -- what a forward pass costs (the serving MFU gauge) ---------------
+    def prefill_flops(self, tokens: int, start: int = 0) -> int:
+        return costmodel.serve_prefill_flops(self.cfg, tokens, start)
+
+    def decode_flops(self, contexts) -> int:
+        return sum(costmodel.serve_decode_flops(self.cfg, int(n))
+                   for n in contexts)

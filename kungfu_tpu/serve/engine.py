@@ -1,4 +1,4 @@
-"""Continuous-batching inference engine over the flagship transformer.
+"""Continuous-batching inference engine.
 
 One engine = one replica: it owns the params, a device-side KV slab of
 ``max_batch`` decode slots, and a :class:`~kungfu_tpu.serve.kvcache.
@@ -24,12 +24,22 @@ length bucket + one decode trace — the recompile-hazard discipline):
   deterministically re-derives the same continuation from its committed
   prefix, docs/serving.md).
 
-The slabs (``[L, B, H, S, D]``, one for K and one for V) are updated in
-place by all three programs that write them (prefill, decode and the
+The slabs (for the dense transformer ``[L, B, H, S, D]``, one for K and
+one for V) are updated in place by all three programs that write them (prefill, decode and the
 restore of cached pages): each takes them donated and writes only the
 rows it adds, so no call copies a slab or a layer of it.  A slab handed
 to one of these programs is gone; the engine, and anyone who calls the
 jitted functions, keeps only the returned pair.
+
+The engine knows no model by name.  What depends on how a model lays
+its keys and values out -- the slabs, the bodies of the prefill and
+decode programs, the host's side of a page, a step's cost -- it asks of
+``model.serve_caches(max_batch, max_seq)`` (the interface is written
+out in ``serve/caches.py``): the dense ``Transformer`` answers with one
+slab for K and one for V (``caches.DenseCaches``), a model that mixes
+window and full attention layers with a ring and a full-length slab for
+each (``serve/windowed.py``).  Scheduler, slots, pool and spans are the
+same for both.
 
 Fault surface: the engine is process-local and carries no collective
 state — worker death is handled ABOVE it by the router's replay ladder
@@ -39,7 +49,6 @@ committed their full pages to the pool first.
 
 from __future__ import annotations
 
-import math
 import threading
 import time
 from collections import deque
@@ -49,8 +58,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kungfu_tpu.models import nn
-from kungfu_tpu.models.transformer import Transformer, _rope
 from kungfu_tpu.monitor import timeline
 from kungfu_tpu.ops import costmodel
 from kungfu_tpu.serve import slo
@@ -92,7 +99,7 @@ class InferenceEngine:
     """Single-replica continuous-batching decode loop (one per serving
     worker; thread-safe submit, single-threaded :meth:`step`)."""
 
-    def __init__(self, model: Transformer, params, *,
+    def __init__(self, model, params, *,
                  pool: Optional[KVCachePool] = None,
                  max_batch: Optional[int] = None,
                  max_seq: Optional[int] = None,
@@ -136,12 +143,9 @@ class InferenceEngine:
         self._active: Dict[int, _Req] = {}       # slot -> request
         self._free_slots = list(range(self.max_batch - 1, -1, -1))
         self._steps = 0
-        # device KV slab: [L, B, H, S, D] in compute dtype
-        L, B, H, S, D = (cfg.n_layers, self.max_batch, cfg.n_heads,
-                        self.max_seq, cfg.head_dim)
-        dt = cfg.compute_dtype
-        self._k = jnp.zeros((L, B, H, S, D), dt)
-        self._v = jnp.zeros((L, B, H, S, D), dt)
+        # the model's side of the cache (serve/caches.py)
+        self._caches = model.serve_caches(self.max_batch, self.max_seq)
+        self._k, self._v = self._caches.new_slabs()
         # Every program that writes the slabs takes them donated and
         # updates them in place, on every backend: a call that copied
         # them would move 3 GB at GPT-2 large (PERF.md, PR 25).  The
@@ -155,177 +159,36 @@ class InferenceEngine:
         # peak is known; None on the CPU mesh — docs/xray.md)
         self._mfu = costmodel.MFUMeter(rank=rank)
 
-    # -- forward passes --------------------------------------------------
-    @jax.named_scope("attn_proj")
-    def _layer_qkv(self, lp, x, positions):
-        cfg = self.model.cfg
-        dt = cfg.compute_dtype
-
-        def heads(t):
-            b, s, _ = t.shape
-            return t.reshape(b, s, cfg.n_heads, cfg.head_dim
-                             ).transpose(0, 2, 1, 3)
-
-        q = heads(nn.dense_apply(lp["wq"], x, dtype=dt))
-        k = heads(nn.dense_apply(lp["wk"], x, dtype=dt))
-        v = heads(nn.dense_apply(lp["wv"], x, dtype=dt))
-        if cfg.pos == "rope":
-            q, k = _rope(q, k, positions)
-        return q, k, v
-
-    @staticmethod
-    @jax.named_scope("attn_core")
-    def _attend(q, keys, values, mask):
-        """q [B,H,Q,D] over keys/values [B,H,S,D]; mask [B,1,Q,S] (or
-        broadcastable) True = attend.  f32 logits/softmax like the
-        training path."""
-        d = q.shape[-1]
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys
-                            ).astype(jnp.float32) / jnp.sqrt(d)
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs, values)
-
-    def _merge(self, x):
-        b, h, s, d = x.shape
-        return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-
-    def _out_and_mlp(self, lp, h, o):
-        """The rest of a block after attention ``o`` [B,H,Q,D]: the
-        output projection and the MLP, each added to the stream ``h``."""
-        dt = self.model.cfg.compute_dtype
-        with jax.named_scope("attn_proj"):
-            h = h + nn.dense_apply(lp["wo"], self._merge(o), dtype=dt)
-        x = nn.layernorm_apply(lp["ln2"], h)
-        with jax.named_scope("mlp"):
-            y = nn.gelu(nn.dense_apply(lp["ffn_in"], x, dtype=dt))
-            return h + nn.dense_apply(lp["ffn_out"], y, dtype=dt)
-
+    # -- the three programs ----------------------------------------------
+    # (methods, jitted under these names: the device trace knows the
+    # programs as ``jit__prefill_fn`` / ``jit__decode_fn``)
     def _prefill_fn(self, params, k_slab, v_slab, ids, n, start, slot):
-        """ids [S_pad] (suffix, zero-padded past ``n``); writes K/V at
-        positions ``[start, start + S_pad)`` of ``slot`` and returns the
-        greedy next token after the last REAL row (``n - 1``)."""
-        cfg = self.model.cfg
-        dt = cfg.compute_dtype
-        s_pad = ids.shape[0]
-        s_max = k_slab.shape[3]
-        positions = start + jnp.arange(s_pad)
-        with jax.named_scope("embed"):
-            h = nn.embedding_apply(params["embed"], ids[None], dtype=dt)
-            if cfg.pos == "learned":
-                h = h + nn.embedding_apply(params["pos_embed"],
-                                           positions[None], dtype=dt)
-        q_pos = positions
-        key_pos = jnp.arange(s_max)
-        mask = (key_pos[None, :] <= q_pos[:, None])[None, None]  # [1,1,Q,S]
-        for li in range(cfg.n_layers):
-            lp = params[f"layer_{li}"]
-            x = nn.layernorm_apply(lp["ln1"], h)
-            q, k, v = self._layer_qkv(lp, x, positions[None])
-            with jax.named_scope("kv_write"):
-                # (no index is negative: normalising them is a third of
-                # this function's tracing time, once a bucket)
-                k_slab = jax.lax.dynamic_update_slice(
-                    k_slab, k[None], (li, slot, 0, start, 0),
-                    allow_negative_indices=False)
-                v_slab = jax.lax.dynamic_update_slice(
-                    v_slab, v[None], (li, slot, 0, start, 0),
-                    allow_negative_indices=False)
-            with jax.named_scope("attn_core"):
-                # one dynamic slice of (layer, slot): taking the layer
-                # first would materialise all its slots
-                at, size = (li, slot, 0, 0, 0), (1, 1) + k_slab.shape[2:]
-                keys = jax.lax.dynamic_slice(
-                    k_slab, at, size, allow_negative_indices=False)[0]
-                values = jax.lax.dynamic_slice(
-                    v_slab, at, size, allow_negative_indices=False)[0]
-                o = self._attend(q, keys, values, mask)
-            h = self._out_and_mlp(lp, h, o)
-        h = nn.layernorm_apply(params["ln_f"], h)
-        with jax.named_scope("head"):
-            last = jax.lax.dynamic_index_in_dim(h, n - 1, axis=1,
-                                                keepdims=False)
-            logits = nn.dense_apply(params["head"], last).astype(jnp.float32)
-            tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-        return k_slab, v_slab, tok
-
-    @staticmethod
-    def _row_windows(pos, s_max):
-        """Per slot ``b``: where the aligned window of ``S`` that holds
-        position ``pos[b]`` starts, and which of its rows that is.  Each
-        start is a scalar ``p // w * w`` on purpose: from that the
-        compiler knows the window is tile-aligned and updates it in
-        place; sliced out of a vector of starts it no longer does, and
-        the write takes five times as long (tests/test_tpu_compile.py)."""
-        w = math.gcd(s_max, 128)                # divides S: never clamped
-        lane = jnp.arange(w)[:, None]
-        return [(p // w * w, lane == p % w) for p in pos]
-
-    @staticmethod
-    def _write_rows(slab, li, new, windows):
-        """Row ``b`` of ``new`` [B, H, 1, D] into layer ``li`` of
-        ``slab`` at slot ``b``'s position, in place, as a
-        read-modify-write of the window of :meth:`_row_windows`.  The TPU
-        lays ``S`` along the lanes, 128 to a tile, so that window is what
-        a one-row ``dynamic_update_slice`` touches anyway, unrolled: 5 us
-        and 140 KB of code a slot and layer.  Window by window, XLA fuses
-        the slice, the select and the update of K and V into one in-place
-        loop (2.9 against 6.9 ms a step at GPT-2 large, PERF.md PR 25).
-        A scatter would be one operation, but the compiler lays the whole
-        slab out anew around it (1.2 s a step)."""
-        size = (1, 1, slab.shape[2], windows[0][1].shape[0], slab.shape[4])
-        for b, (start, hit) in enumerate(windows):
-            at = (li, b, 0, start, 0)
-            old = jax.lax.dynamic_slice(slab, at, size,
-                                        allow_negative_indices=False)
-            slab = jax.lax.dynamic_update_slice(
-                slab, jnp.where(hit, jax.lax.slice_in_dim(new, b, b + 1),
-                                old), at,
-                allow_negative_indices=False)
-        return slab
+        """ids [S_pad] (suffix, zero-padded past ``n``) into ``slot``
+        past ``start`` cached positions; returns the greedy next token
+        after the last REAL row (``n - 1``)."""
+        return self._caches.prefill(params, k_slab, v_slab, ids, n, start,
+                                    slot)
 
     def _decode_fn(self, params, k_slab, v_slab, last_ids, pos):
         """One token for every slot: ``last_ids``/``pos`` are [B]; the
-        new K/V lands at each slot's ``pos`` and attention covers
-        ``[0, pos]``.  Inactive slots compute garbage nobody reads."""
-        cfg = self.model.cfg
-        dt = cfg.compute_dtype
-        s_max = k_slab.shape[3]
-        positions = pos[:, None]                     # [B, 1]
-        with jax.named_scope("embed"):
-            h = nn.embedding_apply(params["embed"], last_ids[:, None],
-                                   dtype=dt)
-            if cfg.pos == "learned":
-                h = h + nn.embedding_apply(params["pos_embed"], positions,
-                                           dtype=dt)
-        mask = (jnp.arange(s_max)[None, :] <= positions)[:, None, None, :]
-        windows = self._row_windows(pos, s_max)
-        for li in range(cfg.n_layers):
-            lp = params[f"layer_{li}"]
-            x = nn.layernorm_apply(lp["ln1"], h)
-            q, k, v = self._layer_qkv(lp, x, positions)
-            with jax.named_scope("kv_write"):
-                k_slab = self._write_rows(k_slab, li, k, windows)
-                v_slab = self._write_rows(v_slab, li, v, windows)
-            h = self._out_and_mlp(lp, h, self._attend(
-                q, k_slab[li], v_slab[li], mask))
-        h = nn.layernorm_apply(params["ln_f"], h)
-        with jax.named_scope("head"):
-            logits = nn.dense_apply(params["head"], h[:, 0]
-                                    ).astype(jnp.float32)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return k_slab, v_slab, tok
+        new K/V lands at each slot's ``pos``.  Inactive slots compute
+        garbage nobody reads."""
+        return self._caches.decode(params, k_slab, v_slab, last_ids, pos)
 
     @staticmethod
     @jax.named_scope("kv_write")
     def _restore_fn(k_slab, v_slab, ks, vs, slot):
         """Cached pages ``ks``/``vs`` [L, H, R, D] back into positions
-        ``[0, R)`` of ``slot``."""
+        ``[0, R)`` of ``slot`` (of every slab, where K and V are several:
+        a ring is restored whole)."""
         at = (0, slot, 0, 0, 0)
-        return (jax.lax.dynamic_update_slice(k_slab, ks[:, None], at,
-                                             allow_negative_indices=False),
-                jax.lax.dynamic_update_slice(v_slab, vs[:, None], at,
-                                             allow_negative_indices=False))
+
+        def put(slab, rows):
+            return jax.lax.dynamic_update_slice(
+                slab, rows[:, None], at, allow_negative_indices=False)
+
+        return (jax.tree_util.tree_map(put, k_slab, ks),
+                jax.tree_util.tree_map(put, v_slab, vs))
 
     def _prefill_bucket(self, n: int) -> int:
         """Static prefill length: the smallest power-of-two multiple of
@@ -351,13 +214,11 @@ class InferenceEngine:
             buckets.append(b)
             b *= 2
         buckets.append(top)
-        cfg = self.model.cfg
         for s_pad in buckets:
             # the slabs are donated, so each call's pair replaces the
             # engine's; what the calls write (zeros and one row of slot 0,
             # before any request) nobody reads
-            pages = jnp.zeros((cfg.n_layers, cfg.n_heads, s_pad,
-                               cfg.head_dim), cfg.compute_dtype)
+            pages = self._caches.empty_pages(s_pad)
             self._k, self._v = self._restore_j(self._k, self._v, pages,
                                                pages, jnp.int32(0))
             self._k, self._v, tok = self._prefill_j(
@@ -440,21 +301,23 @@ class InferenceEngine:
         budget = len(req.tokens) + req.max_new
         n_pages = -(-budget // T)
         cached_pages, n_cached = self.pool.lookup(req.tokens)
-        # at least one prompt token must run the forward — the last row's
-        # hidden state is where the first generated token comes from
+        # Give reuse back, a page at a time, until what is left can be
+        # used.  At least one prompt token must run the forward -- the
+        # last row's hidden state is where the first generated token
+        # comes from.  The padded prefill must FIT the slab past the
+        # cached offset: start + bucket(suffix) > max_seq would make
+        # dynamic_update_slice silently clamp the write over the restored
+        # prefix (corrupt K/V that _complete would then commit into the
+        # prefix chain); n_cached = 0 always fits, since submit() bounds
+        # the prompt by max_seq.  And the pool must still hold every row
+        # the prefix's last positions need (window layers keep only their
+        # last rows: KVCachePool.reusable).
         max_reuse = ((len(req.tokens) - 1) // T) * T
-        while n_cached > max_reuse:
-            self.pool.release([cached_pages.pop()])
-            n_cached -= T
-        # the padded prefill must FIT the slab past the cached offset:
-        # start + bucket(suffix) > max_seq would make dynamic_update_slice
-        # silently clamp the write over the restored prefix (corrupt K/V
-        # that _complete would then commit into the prefix chain).  Give
-        # reuse back until the rounded suffix fits — n_cached = 0 always
-        # does, since submit() bounds the prompt by max_seq
         while n_cached > 0 and (
-                n_cached + self._prefill_bucket(len(req.tokens) - n_cached)
-                > self.max_seq):
+                n_cached > max_reuse
+                or n_cached + self._prefill_bucket(len(req.tokens) - n_cached)
+                > self.max_seq
+                or not self.pool.reusable(cached_pages)):
             self.pool.release([cached_pages.pop()])
             n_cached -= T
         try:
@@ -482,8 +345,8 @@ class InferenceEngine:
                 self.params, self._k, self._v, jnp.asarray(ids),
                 jnp.int32(len(suffix)), jnp.int32(n_cached), jnp.int32(slot))
         req.computed = len(suffix)
-        self._mfu.add_flops(costmodel.serve_prefill_flops(
-            self.model.cfg, len(suffix), n_cached))
+        self._mfu.add_flops(self._caches.prefill_flops(len(suffix),
+                                                       n_cached))
         with timeline.span("serve", "prefill_read", rank=self.rank,
                            rid=req.rid):
             tok = int(tok)  # the host waits for the prefill here
@@ -499,14 +362,10 @@ class InferenceEngine:
         zeros to a prefill bucket (one restore program per bucket; the
         padding lands where the prefill and decode write before anyone
         reads)."""
-        cfg, T = self.model.cfg, self._page_tokens
-        shape = (cfg.n_layers, cfg.n_heads, self._prefill_bucket(n_cached),
-                 cfg.head_dim)
-        ks = np.zeros(shape, cfg.compute_dtype)
-        vs = np.zeros(shape, cfg.compute_dtype)
-        for i, p in enumerate(pages):
-            ks[:, :, i * T:(i + 1) * T], vs[:, :, i * T:(i + 1) * T] = (
-                self.pool.page_data(p))
+        data = [self.pool.page_data(p) for p in pages]
+        ks, vs = (self._caches.pages_to_slot(
+            [d[i] for d in data], n_cached, self._prefill_bucket(n_cached),
+            self._page_tokens) for i in (0, 1))
         self._k, self._v = self._restore_j(self._k, self._v, ks, vs,
                                            jnp.int32(slot))
 
@@ -538,15 +397,17 @@ class InferenceEngine:
         first_new = req.reused // T
         committed = fetched = 0
         if full > first_new and req.pages:
-            kb = np.asarray(jax.device_get(
-                self._k[:, req.slot, :, first_new * T:full * T, :]))
-            vb = np.asarray(jax.device_get(
-                self._v[:, req.slot, :, first_new * T:full * T, :]))
+            # (kept_from: the first position every layer still has)
+            (kb, kept_from), (vb, _) = (
+                self._caches.rows_of_slot(slab, req.slot, first_new * T,
+                                          full * T, req.total_len)
+                for slab in (self._k, self._v))
             for p in range(first_new, full):
                 lo = (p - first_new) * T
                 self.pool.put_page_data(req.pages[p],
                                         kb[:, :, lo:lo + T, :],
-                                        vb[:, :, lo:lo + T, :])
+                                        vb[:, :, lo:lo + T, :],
+                                        whole=p * T >= kept_from)
             self.pool.commit_chain(seq[:full * T], req.pages[:full])
             committed, fetched = full - first_new, kb.nbytes + vb.nbytes
         sp.set_metadata(pages=committed, bytes=fetched)
@@ -626,17 +487,21 @@ class InferenceEngine:
                 self._k, self._v, nxt = self._decode_j(
                     self.params, self._k, self._v,
                     jnp.asarray(last), jnp.asarray(pos))
-            with timeline.span("serve", "decode_read", rank=self.rank):
-                nxt = np.asarray(jax.device_get(nxt))  # the host waits here
+            with timeline.span("serve", "decode_read", rank=self.rank) as sp:
+                # the host waits here; what the step says of itself
+                # besides its tokens (an expert model's routing) goes on
+                # the span that is open when it is known
+                nxt, attrs = self._caches.read(nxt)
+                if attrs:
+                    sp.set_metadata(**attrs)
             slo.observe_token(time.perf_counter() - t0)
-            cfg = self.model.cfg
-            self._mfu.add_flops(sum(
-                costmodel.serve_decode_flops(cfg, int(pos[slot]) + 1)
-                for slot in active))
+            self._mfu.add_flops(
+                self._caches.decode_flops(pos[list(active)] + 1))
+            toks = nxt.tolist()  # (one conversion, not two a slot)
             for slot, r in active.items():
-                r.generated.append(int(nxt[slot]))
+                r.generated.append(toks[slot])
                 events.append({"kind": "token", "rid": r.rid,
-                               "tok": int(nxt[slot]), "n": len(r.generated)})
+                               "tok": toks[slot], "n": len(r.generated)})
                 if self._is_done(r):
                     events.append({"kind": "done", **self._complete(slot, r)})
         self._mfu.step(wall_s=time.perf_counter() - t_step0)

@@ -77,6 +77,11 @@ class PageSpec:
     head_dim: int
     page_tokens: int
     dtype: str = "float32"
+    #: positions a window layer keeps (0: every layer keeps every one).
+    #: A page committed once a request's window layers had moved past it
+    #: is not ``whole``, and a prefix can be reused only if the pages
+    #: over its last ``window`` positions are (:meth:`KVCachePool.reusable`)
+    window: int = 0
 
     @property
     def page_bytes(self) -> int:
@@ -87,15 +92,18 @@ class PageSpec:
     @classmethod
     def for_model(cls, cfg, page_tokens: Optional[int] = None,
                   dtype: Optional[str] = None) -> "PageSpec":
-        """Spec from a :class:`~kungfu_tpu.models.transformer.
-        TransformerConfig`; ``page_tokens`` defaults from the
+        """Spec from a model's config (``TransformerConfig``, or one with
+        fewer key/value heads than query heads and window layers:
+        ``Cohere2MoeConfig``); ``page_tokens`` defaults from the
         ``KF_SERVE_PAGE_TOKENS`` env."""
         if page_tokens is None:
             page_tokens = envs.parse_int_env(envs.SERVE_PAGE_TOKENS,
                                              DEFAULT_PAGE_TOKENS)
-        return cls(n_layers=cfg.n_layers, n_heads=cfg.n_heads,
+        return cls(n_layers=cfg.n_layers,
+                   n_heads=getattr(cfg, "n_kv_heads", cfg.n_heads),
                    head_dim=cfg.head_dim, page_tokens=int(page_tokens),
-                   dtype=dtype or cfg.dtype)
+                   dtype=dtype or cfg.dtype,
+                   window=getattr(cfg, "window", 0))
 
 
 def chain_hashes(tokens: Sequence[int], page_tokens: int) -> List[bytes]:
@@ -126,13 +134,16 @@ def _content_digest(k: np.ndarray, v: np.ndarray) -> bytes:
 
 
 class _Page:
-    __slots__ = ("k", "v", "key", "refs", "prefix")
+    __slots__ = ("k", "v", "key", "refs", "prefix", "whole")
 
     def __init__(self):
         self.k: Optional[np.ndarray] = None   # [L, H, T, D]
         self.v: Optional[np.ndarray] = None
         self.key: Optional[bytes] = None      # chain hash when committed
         self.refs = 0
+        #: False: the window layers' rows had been overwritten when the
+        #: page was filled, and it holds the other layers' only
+        self.whole = True
         #: the covering token prefix (all tokens the chain hash digests)
         #: — kept so a committed page is *portable*: a snapshot carries
         #: (prefix, K, V) and a restoring pool re-derives the chain hash
@@ -241,8 +252,10 @@ class KVCachePool:
             self._update_gauge()
 
     # -- page data -------------------------------------------------------
-    def put_page_data(self, pid: int, k: np.ndarray, v: np.ndarray) -> None:
-        """Fill a reserved page's host copy (``[L, H, T, D]`` each)."""
+    def put_page_data(self, pid: int, k: np.ndarray, v: np.ndarray,
+                      whole: bool = True) -> None:
+        """Fill a reserved page's host copy (``[L, H, T, D]`` each).
+        ``whole=False``: the window layers' part is not there any more."""
         want = (self.spec.n_layers, self.spec.n_heads,
                 self.spec.page_tokens, self.spec.head_dim)
         if tuple(k.shape) != want or tuple(v.shape) != want:
@@ -253,6 +266,7 @@ class KVCachePool:
                 raise ValueError(f"put_page_data on non-live page {pid}")
             page.k = np.ascontiguousarray(k)
             page.v = np.ascontiguousarray(v)
+            page.whole = bool(whole)
 
     def page_data(self, pid: int) -> Tuple[np.ndarray, np.ndarray]:
         with self._lock:
@@ -282,6 +296,11 @@ class KVCachePool:
                 if page.k is None:
                     break  # pages are filled in order; stop at the gap
                 if digest in self._by_key:
+                    # ... unless the incumbent lacks the window layers'
+                    # rows and this page has them: it takes the data
+                    held = self._pages[self._by_key[digest]]
+                    if page.whole and not held.whole:
+                        held.k, held.v, held.whole = page.k, page.v, True
                     continue
                 page.key = digest
                 page.prefix = np.asarray(
@@ -309,6 +328,18 @@ class KVCachePool:
                 out.append(pid)
             return out, len(out) * self.spec.page_tokens
 
+    def reusable(self, page_ids: Sequence[int]) -> bool:
+        """May the prefix these pages cover (a chain, in order) be
+        restored into a slot?  Its last ``spec.window`` positions must
+        come from whole pages: a window layer attends to them next, and
+        a page that is not whole no longer has them.  (The full layers'
+        rows are in every page, so earlier pages need not be whole.)"""
+        if not self.spec.window:
+            return True
+        tail = -(-self.spec.window // self.spec.page_tokens)
+        with self._lock:
+            return all(self._pages[pid].whole for pid in page_ids[-tail:])
+
     # -- durable snapshot (kf-persist) -----------------------------------
     def snapshot_committed(self) -> Dict[str, np.ndarray]:
         """Portable image of every committed page that still holds data:
@@ -332,6 +363,8 @@ class KVCachePool:
                 out[f"kv{j}_v"] = np.array(page.v)
                 out[f"kv{j}_c"] = np.frombuffer(
                     _content_digest(page.k, page.v), np.uint8).copy()
+                if not page.whole:
+                    out[f"kv{j}_w"] = np.zeros((), np.uint8)
                 j += 1
         return out
 
@@ -372,14 +405,16 @@ class KVCachePool:
                 continue
             digest = chain_hashes(
                 np.asarray(prefix, np.int64).tolist(), pt)[-1]
-            if self._adopt_committed(digest, prefix, k, v):
+            whole = bool(np.all(snap.get(f"kv{j}_w", 1)))
+            if self._adopt_committed(digest, prefix, k, v, whole):
                 restored += 1
             else:
                 rejected += 1
         return restored, rejected
 
     def _adopt_committed(self, digest: bytes, prefix: np.ndarray,
-                         k: np.ndarray, v: np.ndarray) -> bool:
+                         k: np.ndarray, v: np.ndarray,
+                         whole: bool = True) -> bool:
         """Install a verified page as committed + parked (zero refs, in
         the LRU).  ``True`` also when the digest is already committed —
         the restore's goal state holds either way."""
@@ -393,6 +428,7 @@ class KVCachePool:
             page.k = np.ascontiguousarray(k)
             page.v = np.ascontiguousarray(v)
             page.prefix = np.asarray(prefix, np.int64)
+            page.whole = whole
             page.key = digest
             self._by_key[digest] = pid
             page.refs = 0

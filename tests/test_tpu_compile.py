@@ -228,3 +228,125 @@ def test_decode_row_write_is_one_fused_window_update(serve_programs):
     fused = re.findall(r"^\s*%?[\w.\-]*dynamic-update-slice_fusion[\w.]* = ",
                        entry, re.M)
     assert len(fused) == SERVE_LAYERS * SERVE_SLOTS
+
+
+# -- the same guarantee for the model with two caches -----------------------------
+#: command-a-plus-05-2026 as its cell serves it: one period of layers,
+#: 16 of 128 experts, 32 slots of 8192 positions, rings of 4096
+MOE_SLOTS, MOE_SEQ, MOE_RING, MOE_HELD = 32, 8192, 4096, 16
+MOE_PREFILL = (256, 8192)
+
+
+@pytest.fixture(scope="module")
+def moe_programs(topo):
+    """name -> compiled program of the engine serving ``cohere2_moe`` at
+    the published widths, lowered from shapes alone."""
+    from kungfu_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
+    from kungfu_tpu.serve.engine import InferenceEngine
+    from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
+
+    cfg = Cohere2MoeConfig(vocab_size=32768, n_layers=4,
+                           experts_held=(0, MOE_HELD), max_seq=MOE_SEQ)
+    model = Cohere2Moe(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        lambda x: shaped(x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = InferenceEngine(
+        model, None, max_batch=MOE_SLOTS, max_seq=MOE_SEQ,
+        pool=KVCachePool(PageSpec.for_model(cfg, page_tokens=256),
+                         capacity_pages=1))
+    slab = tuple(shaped(s, bf16) for s in eng._caches.shapes())
+    assert [s.shape for s in slab] == [
+        (3, MOE_SLOTS, 8, MOE_RING, 128), (1, MOE_SLOTS, 8, MOE_SEQ, 128)]
+    pages = tuple(shaped(p.shape, bf16) for p in eng._caches.empty_pages(256))
+    slots, i0 = shaped((MOE_SLOTS,), i32), shaped((), i32)
+    lowered = {"decode": eng._decode_j.lower(params, slab, slab, slots,
+                                             slots),
+               "restore": eng._restore_j.lower(slab, slab, pages, pages, i0)}
+    for n in MOE_PREFILL:
+        lowered[f"prefill{n}"] = eng._prefill_j.lower(
+            params, slab, slab, shaped((n,), i32), i0, i0, i0)
+    return {name: lo.compile() for name, lo in lowered.items()}
+
+
+def _entry_ops(text):
+    """(name, dtype, dims, operation) of the entry computation's
+    instructions."""
+    entry = text[text.index("\nENTRY"):]
+    return re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
+        entry, re.M)
+
+
+@pytest.mark.parametrize("program", ["decode", "restore"]
+                         + [f"prefill{n}" for n in MOE_PREFILL])
+def test_two_cache_program_writes_its_slabs_in_place(moe_programs, program):
+    """All four slabs (a ring and a full slab, for K and for V) alias
+    their outputs, and nothing but the in-place updates produces an
+    array the size of a slab, of one layer of one, or of the weights of a
+    layer's experts or query projection: no copy of a slab, no
+    materialised layer, no weights laid out anew every step."""
+    text = moe_programs[program].as_text()
+    assert len(re.findall(r"may-alias|must-alias",
+                          text.split("\n", 1)[0])) == 4
+    moved = []
+    for name, dtype, dims, op in _entry_ops(text):
+        elems = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        in_place = "dynamic-update-slice" in name or "dynamic_update_slice" \
+            in name or op == "dynamic-update-slice"
+        # the smallest of those: a layer of the ring, 32 x 8 x 4096 x 128
+        if elems >= MOE_SLOTS * 8 * MOE_RING * 128 and not in_place \
+                and op not in ("parameter", "bitcast", "get-tuple-element",
+                               "tuple"):
+            moved.append((op, name, dtype, dims))
+    assert not moved
+
+
+def test_decode_expert_product_has_the_same_shapes_whatever_the_routing(
+        moe_programs):
+    """The routed product of a decode step is one batched product over
+    every held expert and every slot: in the compiled program each
+    layer's three products carry ``[16, 32, 4096]`` whatever the tokens
+    chose, there is no grouped (``ragged``) product whose work follows
+    the routing, and no loop or branch under ``moe_experts``.  A step
+    then reads every held expert every time, as a deployment's does
+    (PERF.md, PR 27)."""
+    text = moe_programs["decode"].as_text()
+    assert "ragged" not in text
+    entry = text[text.index("\nENTRY"):]
+    weights = re.findall(
+        r"%(params__layer_\d____moe____experts____(?:gate|up|down)__[.\d]*) = "
+        r"bf16\[16,4096,4096\]", entry)
+    assert len(weights) == 4 * 3
+    for w in weights:
+        # one reader each: a product over all 16 experts at once, of
+        # static shape (the compiler joins gate and up, and the down
+        # product with the weighted sum, into one fusion each)
+        readers = re.findall(
+            r"^\s*%?[\w.\-]+ = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\([^\n]*%"
+            + re.escape(w) + r"[,)][^\n]*op_name=\"([^\"]*)\"", entry, re.M)
+        assert len(readers) == 1, (w, readers)
+        dtype, dims, op, name = readers[0]
+        assert op in ("fusion", "convolution"), readers
+        assert "/mlp/moe_experts/" in name and "dot_general" in name
+        assert dims in (f"{MOE_HELD},{MOE_SLOTS},4096", f"{MOE_SLOTS},4096")
+    assert not re.findall(
+        r"= [^\n]* (while|conditional)\([^\n]*moe_experts", text)
+
+
+def test_two_cache_programs_fit_beside_the_weights(moe_programs):
+    """9.47 GB of weights and 2.68 GB of slabs are arguments; what a
+    program adds is small for a decode step and under 2 GB for the
+    longest prefill (no ``[heads, P, S]`` scores, no ``[P, heads * D]``
+    queries: PERF.md, PR 27)."""
+    stats = {n: p.memory_analysis() for n, p in moe_programs.items()}
+    args = stats["decode"].argument_size_in_bytes
+    assert 12.1e9 < args < 12.2e9
+    assert stats["decode"].temp_size_in_bytes < 0.1e9
+    assert stats["prefill256"].temp_size_in_bytes < 0.5e9
+    assert stats["prefill8192"].temp_size_in_bytes < 2.0e9
