@@ -23,13 +23,63 @@ def _rand_qkv(b, h, s, d, dtype=jnp.float32, seed=0):
     )
 
 
+#: id -> (heads, S, D, causal, dtype, (block_q, block_k), VMEM budget or
+#: None): one case for every branch ``tile_plan`` can take.  A small
+#: budget sends a small S down the streamed branch (a hold of two tiles).
+PLAN_CASES = {
+    "causal": (4, 256, 32, True, jnp.float32, (None, None), None),
+    "noncausal": (4, 256, 32, False, jnp.float32, (None, None), None),
+    "cells_s1024_d64": (1, 1024, 64, True, jnp.float32, (None, None), None),
+    "cells_s1024_d64_bf16": (1, 1024, 64, True, jnp.bfloat16, (None, None),
+                             None),
+    "s2048_d128_streamed_f32": (1, 2048, 128, True, jnp.float32,
+                                (None, None), None),
+    "s2048_d128_resident_bf16": (1, 2048, 128, True, jnp.bfloat16,
+                                 (None, None), None),
+    "s1152_off_tile": (1, 1152, 64, True, jnp.float32, (None, None), None),
+    "s300_off_tile": (2, 300, 32, True, jnp.float32, (None, None), None),
+    "s300_noncausal": (2, 300, 32, False, jnp.float32, (None, None), None),
+    "s300_wide_k": (1, 300, 32, True, jnp.float32, (64, 128), None),
+    "s300_wide_q": (1, 300, 32, False, jnp.float32, (128, 64), None),
+    "streamed": (2, 256, 32, True, jnp.float32, (32, 32), 64 * 7424),
+    "streamed_off_tile": (2, 250, 32, True, jnp.float32, (32, 64),
+                          128 * 7424),
+    "streamed_noncausal": (2, 250, 32, False, jnp.float32, (64, 32),
+                           64 * 7424),
+}
+PLAN_PATHS = {"s2048_d128_streamed_f32": "streamed", "streamed": "streamed",
+              "streamed_off_tile": "streamed",
+              "streamed_noncausal": "streamed"}
+
+
+def _plan_case(name, monkeypatch):
+    """(q, k, v, causal, blocks, tolerance scale) of a case, with the
+    VMEM budget it asks for in place and its branch checked."""
+    from kungfu_tpu.ops.pallas import attention as A
+
+    h, s, d, causal, dtype, blocks, budget = PLAN_CASES[name]
+    if budget is not None:
+        monkeypatch.setattr(A, "VMEM_BUDGET_BYTES", budget)
+    plan = A.tile_plan(s, d, dtype, causal, *blocks)
+    assert plan.path == PLAN_PATHS.get(name, "resident"), plan
+    q, k, v = _rand_qkv(1, h, s, d, dtype, seed=s + d)
+    return q, k, v, causal, blocks, (1 if dtype == jnp.float32 else 2e3)
+
+
+def _f32(*xs):
+    return tuple(x.astype(jnp.float32) for x in xs)
+
+
 class TestFlashForward:
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_matches_xla_attention(self, causal):
-        q, k, v = _rand_qkv(2, 2, 256, 32)
-        ref = default_attention(q, k, v, causal)
-        got = flash_attention(q, k, v, causal=causal, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(got), atol=2e-5)
+    @pytest.mark.parametrize("case", PLAN_CASES)
+    def test_matches_xla_attention(self, monkeypatch, case):
+        q, k, v, causal, (bq, bk), tol = _plan_case(case, monkeypatch)
+        ref = default_attention(*_f32(q, k, v), causal)
+        got = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                              interpret=True)
+        np.testing.assert_allclose(np.asarray(ref),
+                                   np.asarray(got.astype(jnp.float32)),
+                                   atol=2e-5 * tol)
 
     def test_ragged_seq_len_padding(self):
         # S not a multiple of the block sizes exercises the tail mask
@@ -82,32 +132,74 @@ class TestFlashBackwardPallasKernels:
     KF_PALLAS_BWD=pallas and run in interpret mode, cross-checked against
     plain-XLA autodiff AND the blocked-jnp reference backward."""
 
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_kernel_grads_match_xla(self, monkeypatch, causal):
+    @pytest.mark.parametrize("case", PLAN_CASES)
+    def test_kernel_grads_match_xla(self, monkeypatch, case):
         monkeypatch.setenv("KF_PALLAS_BWD", "pallas")
-        q, k, v = _rand_qkv(1, 2, 160, 32, seed=3)
+        q, k, v, causal, (bq, bk), tol = _plan_case(case, monkeypatch)
+        w = jnp.asarray(np.random.default_rng(9).normal(size=q.shape),
+                        jnp.float32)
 
         def loss_flash(q, k, v):
-            return jnp.sum(
-                flash_attention(q, k, v, causal=causal, interpret=True) ** 2
-            )
+            o = flash_attention(q, k, v, causal=causal, block_q=bq,
+                                block_k=bk, interpret=True)
+            return jnp.sum(o.astype(jnp.float32) * w)
 
         def loss_ref(q, k, v):
-            return jnp.sum(default_attention(q, k, v, causal) ** 2)
+            return jnp.sum(default_attention(q, k, v, causal) * w)
 
+        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(*_f32(q, k, v))
+        for name, a, b in zip("qkv", gf, gr):
+            np.testing.assert_allclose(
+                np.asarray(a.astype(jnp.float32)), np.asarray(b),
+                atol=2e-5 * tol, err_msg=f"d{name}"
+            )
+
+    @pytest.mark.parametrize("case", ["causal", "noncausal", "s300_off_tile",
+                                      "streamed_off_tile",
+                                      "streamed_noncausal"])
+    @pytest.mark.parametrize("bwd", ["pallas", "blocked"])
+    def test_with_lse_grads_match_xla(self, monkeypatch, case, bwd):
+        """``flash_attention_with_lse`` under a loss that reads both
+        outputs: the lse cotangent is not zero (ring attention's merge)."""
+        from kungfu_tpu.ops.pallas import flash_attention_with_lse
+
+        monkeypatch.setenv("KF_PALLAS_BWD", bwd)
+        q, k, v, causal, (bq, bk), _ = _plan_case(case, monkeypatch)
+        q, k, v = (t[0] for t in (q, k, v))            # [BH, S, D]
+        rng = np.random.default_rng(11)
+        w = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+        u = jnp.asarray(rng.normal(size=q.shape[:2]), jnp.float32)
+
+        def loss_flash(q, k, v):
+            o, lse = flash_attention_with_lse(
+                q, k, v, causal=causal, block_q=bq, block_k=bk,
+                interpret=True)
+            return jnp.sum(o * w) + jnp.sum(lse * u)
+
+        def loss_ref(q, k, v):
+            sc = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(q.shape[-1])
+            if causal:
+                n = q.shape[1]
+                sc = jnp.where(jnp.tril(jnp.ones((n, n), bool)), sc, -1e30)
+            lse = jax.scipy.special.logsumexp(sc, axis=-1)
+            o = jnp.einsum("bqk,bkd->bqd", jnp.exp(sc - lse[..., None]), v)
+            return jnp.sum(o * w) + jnp.sum(lse * u)
+
+        np.testing.assert_allclose(loss_flash(q, k, v), loss_ref(q, k, v),
+                                   rtol=1e-5)
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for name, a, b in zip("qkv", gf, gr):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=5e-4, err_msg=f"d{name}"
-            )
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-5, err_msg=f"d{name}")
 
     def test_kernel_matches_blocked_jnp(self, monkeypatch):
         """Bit-level-ish agreement between the two backward impls on the
         same saved (out, lse) — isolates the kernels from fwd noise,
         including the ragged-tail padding path (S=200 vs 128-blocks)."""
         from kungfu_tpu.ops.pallas.attention import (
-            _bwd_blocked, _bwd_pallas, _fwd_call,
+            _bwd_blocked, _bwd_pallas, _fwd_call, tile_plan,
         )
 
         rng = np.random.default_rng(7)
@@ -116,9 +208,10 @@ class TestFlashBackwardPallasKernels:
             jnp.asarray(rng.normal(size=(bh, s, d)), jnp.float32)
             for _ in range(4)
         )
-        out, lse = _fwd_call(q, k, v, True, 128, 128, True)
+        plan = tile_plan(s, d, q.dtype, True, 128, 128)
+        out, lse = _fwd_call(q, k, v, True, plan, True)
         ref = _bwd_blocked(q, k, v, out, lse, do, True, 128)
-        got = _bwd_pallas(q, k, v, out, lse, do, True, 128, 128, True)
+        got = _bwd_pallas(q, k, v, out, lse, do, True, plan, True)
         for name, a, b in zip(("dq", "dk", "dv"), got, ref):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=2e-4, err_msg=name
@@ -326,37 +419,86 @@ class TestXentRouting:
         np.testing.assert_allclose(ref, got, atol=1e-5)
 
 
-class TestDefaultBlocks:
-    """Adaptive flash block resolution (round-3 v5e sweep: big K/V tiles,
-    but never mostly-padding ones)."""
+class TestTilePlan:
+    """``tile_plan``: the flash kernels' schedule from (S, D, dtype,
+    causal) alone -- tile sizes, what a grid step holds, and how much of
+    what it computes the mask keeps."""
 
-    def test_sweep_winners_at_long_seq(self):
-        from kungfu_tpu.ops.pallas.attention import _default_blocks
+    @staticmethod
+    def _plan(s, d=64, dtype=jnp.bfloat16, causal=True, bq=None, bk=None):
+        from kungfu_tpu.ops.pallas.attention import tile_plan
 
-        assert _default_blocks(2048, None, None) == (256, 1024)
-        assert _default_blocks(8192, None, None) == (256, 1024)
+        return tile_plan(s, d, dtype, causal, bq, bk)
 
-    def test_short_seq_never_pads_a_whole_tile(self):
-        from kungfu_tpu.ops.pallas.attention import _default_blocks
+    @pytest.mark.parametrize("s,d,path,tile,hold", [
+        (1024, 64, "resident", 128, 1024),      # the train cells' call
+        (2048, 64, "resident", 256, 2048),      # chip_smoke's train leg
+        (2048, 128, "resident", 256, 2048),     # the old defaults' shape
+        (8192, 128, "streamed", 512, 2048),
+    ])
+    def test_long_seq_holds_what_fits(self, s, d, path, tile, hold):
+        p = self._plan(s, d)
+        assert (p.path, p.block_q, p.block_k, p.hold) == (
+            path, tile, tile, hold)
 
-        assert _default_blocks(128, None, None) == (128, 128)
-        assert _default_blocks(100, None, None) == (128, 128)
-        assert _default_blocks(300, None, None) == (128, 128)
+    @pytest.mark.parametrize("s,s_pad", [(128, 128), (100, 128), (300, 384)])
+    def test_short_seq_never_pads_a_whole_tile(self, s, s_pad):
+        p = self._plan(s)
+        assert (p.block_q, p.block_k, p.s_pad) == (128, 128, s_pad)
 
-    def test_padding_allowance_caps_waste(self):
-        from kungfu_tpu.ops.pallas.attention import _default_blocks
+    @pytest.mark.parametrize("s,d,dtype", [
+        (1152, 64, jnp.bfloat16), (1536, 64, jnp.bfloat16),
+        (8320, 128, jnp.bfloat16), (5000, 128, jnp.float32),
+        (2049, 128, jnp.float32)])
+    def test_padding_allowance_caps_waste(self, s, d, dtype):
+        """Resident or streamed, S is padded by less than a tile or by an
+        eighth, whichever is more (1152 stays 1152: the old 1024-wide K
+        block would have made it 2048)."""
+        p = self._plan(s, d, dtype)
+        assert p.s_pad % p.hold == 0 and p.hold % p.block_q == 0
+        assert p.s_pad - s <= max(s // 8, p.block_q - 1)
 
-        # S=1152 with a 1024 block would pad to 2048 (~78% waste)
-        bq, bk = _default_blocks(1152, None, None)
-        assert bk <= 256
-        # allowance scales with S: 1536 tolerates a 512 tile, not 1024
-        assert _default_blocks(1536, None, None)[1] == 512
+    @pytest.mark.parametrize("bq,bk,want", [(32, 64, (32, 64)),
+                                            (None, 64, (256, 64)),
+                                            (96, None, (96, 256))])
+    def test_explicit_blocks_pass_through(self, bq, bk, want):
+        p = self._plan(2048, bq=bq, bk=bk)
+        assert (p.block_q, p.block_k) == want
+        assert p.s_pad % p.block_q == 0 and p.s_pad % p.block_k == 0
 
-    def test_explicit_blocks_pass_through(self):
-        from kungfu_tpu.ops.pallas.attention import _default_blocks
+    @pytest.mark.parametrize("s,d,most", [(1024, 64, 1.15), (2048, 64, 1.25),
+                                          (2048, 128, 1.25),
+                                          (8192, 128, 1.25)])
+    def test_computed_share_stays_near_the_triangle(self, s, d, most):
+        """Score elements computed over the causal triangle's: 2.0 at
+        S 1024 and 1.5 at S 2048 with the (256, 1024) blocks this
+        replaces; not causal, every tile is needed."""
+        assert 1.0 <= self._plan(s, d).computed_share <= most
+        assert self._plan(s, d, causal=False).computed_share == 1.0
 
-        assert _default_blocks(2048, 32, 64) == (32, 64)
-        assert _default_blocks(2048, None, 64) == (256, 64)
+    @pytest.mark.parametrize("s,bq,bk", [(1024, None, None),
+                                         (2048, None, None),
+                                         (8192, None, None), (2048, 32, 64),
+                                         (1024, 1024, 1024)])
+    def test_runs_stay_inside_their_budget(self, s, bq, bk):
+        """Tiles of a row are joined up to ``RUN_ELEMENTS`` scores a
+        product; a tile larger than that stands alone."""
+        from kungfu_tpu.ops.pallas.attention import RUN_ELEMENTS
+
+        p = self._plan(s, bq=bq, bk=bk)
+        assert p.wide >= 1
+        assert (p.wide * p.block_q * p.block_k <= RUN_ELEMENTS
+                or p.wide == 1)
+        assert (p.wide + 1) * p.block_q * p.block_k > RUN_ELEMENTS
+
+    def test_budget_decides_the_path(self, monkeypatch):
+        from kungfu_tpu.ops.pallas import attention as A
+
+        assert self._plan(1024).path == "resident"
+        monkeypatch.setattr(A, "VMEM_BUDGET_BYTES", 2 << 20)
+        p = self._plan(1024)
+        assert (p.path, p.hold, p.block_q) == ("streamed", 256, 128)
+        assert p.computed_share == self._plan(1024, bq=128).computed_share
 
 
 class TestFusedLMHead:

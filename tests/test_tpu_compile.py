@@ -8,7 +8,9 @@ compile that passes is not a chip run.  Interpret-mode tests cannot see
 any of this.
 
 Shapes are GPT-small's on one chip at batch 4 x sequence 2048
-(``chip_smoke.py``), and ZeRO's 4 MiB bucket on the four devices.
+(``chip_smoke.py``), the train cells' own attention call (GPT-2 medium,
+4 x 16 heads of 1024 x 64), one attention shape past the VMEM budget
+(``tile_plan`` streams it), and ZeRO's 4 MiB bucket on the four devices.
 """
 
 import os
@@ -24,7 +26,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from kungfu_tpu.ops.pallas.attention import flash_attention
+from kungfu_tpu.ops.pallas.attention import flash_attention, tile_plan
 from kungfu_tpu.ops.pallas.collectives import (ring_all_gather,
                                                ring_reduce_scatter)
 from kungfu_tpu.ops.pallas.lm_head import lm_head_nll
@@ -58,6 +60,9 @@ def _no_compile_cache():
 
 N, D, V = 8192, 768, 32128          # tokens on a chip, d_model, vocab
 QKV = (4, 12, 2048, 64)             # [B, H, S, head_dim]
+QKV_CELL = (4, 16, 1024, 64)        # gpt2m-train-*: what the cells call
+QKV_LONG = (1, 8, 8192, 128)        # past the VMEM budget: streamed
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 BUCKET = (4 << 20) // 4             # ZeRO's 4 MiB bucket, in f32 elements
 
 
@@ -84,6 +89,8 @@ def _ring(fn, bidirectional):
 
 bf16, i32 = jnp.bfloat16, jnp.int32
 QKV3 = [(QKV, bf16)] * 3
+QKV3_CELL = [(QKV_CELL, bf16)] * 3
+QKV3_LONG = [(QKV_LONG, bf16)] * 3
 XENT = [((N, V), bf16), ((N,), i32)]
 HEAD = [((N, D), bf16), ((D, V), bf16), ((N,), i32)]
 
@@ -91,6 +98,10 @@ HEAD = [((N, D), bf16), ((D, V), bf16), ((N,), i32)]
 CASES = [
     ("flash_fwd", _flash, QKV3, 1, 1),
     ("flash_fwd_bwd", _grad(_flash, (0, 1, 2)), QKV3, 1, 3),
+    ("flash_fwd_cell", _flash, QKV3_CELL, 1, 1),
+    ("flash_fwd_bwd_cell", _grad(_flash, (0, 1, 2)), QKV3_CELL, 1, 3),
+    ("flash_fwd_streamed", _flash, QKV3_LONG, 1, 1),
+    ("flash_fwd_bwd_streamed", _grad(_flash, (0, 1, 2)), QKV3_LONG, 1, 3),
     ("xent_fwd", _xent, XENT, 1, 1),
     ("xent_fwd_bwd", _grad(_xent, 0), XENT, 1, 2),
     ("lm_head_fwd", _head, HEAD, 1, 1),
@@ -118,11 +129,62 @@ def _lower(topo, fn, args, chips):
         for shape, dtype in args])
 
 
-@pytest.mark.parametrize("fn,args,chips,kernels",
-                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
-def test_compiles_for_v5e(topo, fn, args, chips, kernels):
+@pytest.mark.parametrize("name,fn,args,chips,kernels", CASES,
+                         ids=[c[0] for c in CASES])
+def test_compiles_for_v5e(topo, name, fn, args, chips, kernels):
     text = _lower(topo, fn, args, chips).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    if name.startswith("flash"):
+        # the benchmark's flash_ms_per_step / flash_roofline and
+        # compile_for_chip.py find the kernels by these names
+        called = re.findall(r"^\s*%?([\w.\-]+) = [^\n]*"
+                            r'custom_call_target="tpu_custom_call"', text, re.M)
+        for kernel in FLASH_KERNELS[:kernels]:
+            assert len([c for c in called if re.search(
+                kernel + r"(?![a-z])", c)]) == 1, called
+
+
+def test_flash_shapes_take_both_paths():
+    """The cases above cover both branches of ``tile_plan``."""
+    assert tile_plan(*QKV[-2:], bf16, True).path == "resident"
+    assert tile_plan(*QKV_CELL[-2:], bf16, True).path == "resident"
+    assert tile_plan(*QKV_LONG[-2:], bf16, True).path == "streamed"
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("qkv", [QKV_CELL, QKV_LONG],
+                         ids=["resident", "streamed"])
+def test_flash_products_take_bf16_operands(qkv):
+    """With bfloat16 inputs no product of the three kernels has two
+    float32 operands (the MXU does those in several bfloat16 passes):
+    q, k, v and dO meet it as they arrive, P and dS cast to their
+    partner's type, and every product accumulates in float32."""
+    x = jax.ShapeDtypeStruct(qkv, bf16)
+    jaxpr = jax.make_jaxpr(_grad(_flash, (0, 1, 2)))(x, x, x).jaxpr
+    kernels = {e.params["name"]: e.params["jaxpr"] for e in _equations(jaxpr)
+               if e.primitive.name == "pallas_call"}
+    assert sorted(kernels) == sorted(FLASH_KERNELS)
+    products = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+    for name, kernel in kernels.items():
+        dots = [e for e in _equations(kernel)
+                if e.primitive.name == "dot_general"]
+        # the walk over the tiles is unrolled: every run of tiles writes
+        # the kernel's products once
+        assert dots and len(dots) % products[name] == 0, (name, len(dots))
+        for e in dots:
+            assert [v.aval.dtype for v in e.invars] == [bf16, bf16], (name, e)
+            assert e.outvars[0].aval.dtype == jnp.float32, (name, e)
 
 
 @pytest.mark.parametrize("fn,per_device_mib,needs", [
