@@ -79,10 +79,7 @@ native:
 ## the per-phase medians must sit inside tests/xray_budget.json
 ## (docs/xray.md).
 xray-gate:
-	$(PY) bench.py --xray --quick > /tmp/_kf_xray_gate.json
-	grep -q '"vs_baseline": 1.0' /tmp/_kf_xray_gate.json
-	grep -q '"budget_ok": true' /tmp/_kf_xray_gate.json
-	@echo "xray-gate: all checks green"
+	$(PY) examples/xray_gate.py
 
 ## sentinel-gate: the kf-sentinel detection gate (the same stanza
 ## scripts/check.sh runs): 3-rank paced mesh, chaos delay clauses armed
@@ -92,9 +89,7 @@ xray-gate:
 ## planted rank/edge, and `kfhist --verdict` over the durable history
 ## must reproduce the identical verdicts offline (docs/sentinel.md).
 sentinel-gate:
-	$(PY) bench.py --sentinel --quick > /tmp/_kf_sentinel_gate.json
-	grep -q '"vs_baseline": 1.0' /tmp/_kf_sentinel_gate.json
-	@echo "sentinel-gate: all checks green"
+	$(PY) examples/sentinel_gate.py
 
 ## trace-demo: 4-peer local run with an injected 400 ms straggler on
 ## rank 2 (every 9th matching send, so most collectives stay clean and
@@ -140,8 +135,7 @@ multislice-demo:
 ## the UCB bandit measures its windows, majority-votes, and performs the
 ## consensus-fenced lockstep swap onto the measured-latency MST — the
 ## script asserts the swap fires on EVERY rank and the step time
-## recovers (docs/adaptation.md; the full A/B vs every fixed strategy
-## is `python bench.py --adapt`).
+## recovers (docs/adaptation.md).
 adapt-demo:
 	$(PY) examples/adapt_interference.py
 
@@ -152,7 +146,7 @@ adapt-demo:
 ## requests from their committed positions on the survivors.  Asserts
 ## zero lost accepted requests, >=1 replay, replayed tokens equal to
 ## the greedy reference, and measured prefix reuse (docs/serving.md;
-## the full SLO A/B incl. a slice kill is `python bench.py --serve`).
+## a whole-slice kill is tests/test_serve.py::TestRouterLive).
 serve-demo:
 	$(PY) examples/serve_demo.py
 
@@ -161,9 +155,7 @@ serve-demo:
 ## runs serial (issue, wait, compute) then depth-k pipelined
 ## (host_bucket_pipeline over the engine's async handle window) — the
 ## script asserts measured overlap > 0, BITWISE-identical final params,
-## and the kf_overlap_inflight gauge back at 0 (docs/overlap.md; the
-## full A/B incl. zero-3 and the bare shard_map+psum row is
-## `python bench.py --overlap`).
+## and the kf_overlap_inflight gauge back at 0 (docs/overlap.md).
 overlap-demo:
 	$(PY) examples/overlap_pipeline.py
 
@@ -173,8 +165,7 @@ overlap-demo:
 ## async-handle prefetch — the script asserts BITWISE-identical final
 ## params between the schedules, a measured 1F1B win, and a planned
 ## 2->1 elastic stage merge restored bitwise from the ring-mirrored
-## StageBoundary (docs/pipeline.md; the full A/B with the xray bubble
-## decomposition is `python bench.py --pp`).
+## StageBoundary (docs/pipeline.md).
 pp-demo:
 	$(PY) examples/pp_demo.py
 
@@ -185,8 +176,7 @@ pp-demo:
 ## a separate 2-worker launch cold-restarts from the SAME directory —
 ## the 4-rank manifest re-carves onto the halved world and the final
 ## params are asserted BITWISE against a fixed-world numpy replay
-## (docs/persistence.md; the overhead/goodput A/B is `python bench.py
-## --persist`).
+## (docs/persistence.md).
 persist-demo:
 	$(PY) examples/preempt_restore.py
 

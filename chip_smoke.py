@@ -255,7 +255,6 @@ def leg_train(a) -> dict:
     import optax
 
     import kungfu_tpu as kf
-    from bench import measure_group
     from kungfu_tpu.optimizers import synchronous_sgd
     from kungfu_tpu.parallel.train import dp_train_step
     from kungfu_tpu.utils.compile_cache import CacheCounter
@@ -297,12 +296,10 @@ def leg_train(a) -> dict:
             else "Pallas xent" if "xent_fwd" in low["kernels"]
             else "XLA xent")
 
-    # the same steps timed by host clock around block_until_ready — each
-    # step waited for, then all dispatched before one wait — and by
-    # bench.py's chained-K difference.  All three time the jitted step
-    # behind the pulse wrapper (pulse off): the chained harness traces
-    # the step inside one compiled loop, and the wrapper syncs scalars to
-    # the host.
+    # the same steps timed by host clock around block_until_ready: each
+    # step waited for, then all dispatched before one wait.  Both time
+    # the jitted step behind the pulse wrapper (pulse off): the wrapper
+    # syncs scalars to the host.
     base = step.base
     host = []
     p, o = params, opt_state
@@ -320,13 +317,6 @@ def leg_train(a) -> dict:
     jax.block_until_ready((p, o, loss))
     one_wait = (time.perf_counter() - t0) / n_async
     del p, o
-    k_lo, k_hi = 2, 14
-    chained = measure_group(
-        {"step": lambda c: base(c[0], c[1], batch)},
-        (params, opt_state, loss), k_lo=k_lo, k_hi=k_hi, rounds=3,
-        target_sep=0, max_rounds=6)["step"]
-    if chained is None:
-        raise RuntimeError("the chained-K difference never separated")
 
     facts = {
         "device": device,
@@ -350,7 +340,6 @@ def leg_train(a) -> dict:
                 "min": round(min(host) * 1e3, 3), "n": len(host)},
             f"host_clock_one_wait_after_{n_async}_steps_ms":
                 round(one_wait * 1e3, 3),
-            f"chained_k_{k_lo}_{k_hi}_difference_ms": round(chained * 1e3, 3),
         },
         "compile_seconds": round(cache.compile_seconds, 1),
         "compile_cache": cache.facts(),

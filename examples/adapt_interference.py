@@ -3,8 +3,7 @@
 
 A 3-rank in-process host-plane cluster starts on STAR while the chaos
 layer (``KF_CHAOS_SPEC`` ``delay`` clauses, set below) throttles the
-0<->1 link on both the data path and the latency probe — the same
-injected interference ``bench.py --adapt`` measures.  The UCB bandit
+0<->1 link on both the data path and the latency probe.  The UCB bandit
 (:class:`kungfu_tpu.monitor.adapt_device.HostBanditDriver`) reads its
 measured windows, majority-votes, and performs the consensus-fenced
 lockstep swap onto the measured-latency MST, after which the step time

@@ -74,8 +74,7 @@ def main() -> int:
     if ns.optimizer == "async":
         opt.close()
     # the faster worker must not close its peer while a slower one is
-    # still pulling from its store (cf. benchmarks/gossip.py's
-    # close-after-all-workers-join guard)
+    # still pulling from its store
     peer.barrier()
 
     final = float(loss_fn(params))

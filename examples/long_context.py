@@ -106,9 +106,9 @@ def main() -> int:
     targets = jnp.roll(ids, -1, axis=1)
 
     # exactness check: the sharded ring loss IS the dense loss.  The
-    # dense reference materializes [B, H, S, S] scores, so gate it the
-    # way bench.py gates its XLA baseline — at the sequence lengths this
-    # demo exists for, the check itself would exhaust HBM
+    # dense reference materializes [B, H, S, S] scores, so gate it: at
+    # the sequence lengths this demo exists for, the check itself would
+    # exhaust HBM
     if args.seq_len < 4096:
         ref = float(model.loss(params, (ids, targets)))
         got = float(jax.jit(sharded_loss)(params, ids, targets))

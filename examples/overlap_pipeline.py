@@ -15,9 +15,7 @@ optimizer math runs).  The script asserts:
   pipeline moves wall clock only);
 * the ``kf_overlap_inflight`` gauge is back at 0 (no leaked handles).
 
-Wired into ``make overlap-demo`` and ``scripts/check.sh``; the full A/B
-with the zero-3 rows and the bare ``shard_map``+``psum`` reference is
-``python bench.py --overlap``.  See
+Wired into ``make overlap-demo`` and ``scripts/check.sh``.  See
 docs/overlap.md for the design.
 """
 

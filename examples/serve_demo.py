@@ -17,9 +17,8 @@ last committed decode position on the survivors.  The script asserts:
 * prefix reuse engaged (the shared system prompt prefilled once per
   worker, later requests reused its pages).
 
-Wired into ``make serve-demo`` and ``scripts/check.sh``; the measured
-SLO A/B (p50/p99 before/during/after worker AND slice kills at fixed
-offered load) is ``python bench.py --serve``.  See docs/serving.md.
+Wired into ``make serve-demo`` and ``scripts/check.sh``; a whole-slice
+kill is ``tests/test_serve.py::TestRouterLive``.  See docs/serving.md.
 """
 
 from __future__ import annotations

@@ -371,8 +371,8 @@ class Communicator:
             self._latency_hook = prev_hook
 
     def _time_schedules(self, x, trials):
-        """Per-schedule seconds for one allreduce of ``x``, by the
-        chained-K difference (``bench.measure_group``'s method): compile
+        """Per-schedule seconds for one allreduce of ``x``, by a
+        chained-K difference: compile
         ONE program per (schedule, K) that chains K salted allreduces and
         returns a scalar, time it to host materialization, difference two
         K values so what a dispatch costs once cancels, and interleave
@@ -767,7 +767,8 @@ class Communicator:
         carved into ``n`` equal chunks (zero-padded up to ``n * chunk``).
         Eager result has shape ``[n, chunk]``: each peer's slice is the
         1/n of the reduction it owns — (n-1)/n of the all-reduce wire
-        bytes, the measured delta in ``bench.py --zero``.
+        bytes, counted from the traced program in
+        ``tests/test_zero.py::TestZeroCommVolume``.
 
         The collective runs **bucketed** (``bucket_bytes`` per piece,
         the gradient-bucket fusion of :mod:`kungfu_tpu.ops.schedules`

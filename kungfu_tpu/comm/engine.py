@@ -61,10 +61,10 @@ CHUNK_SIZE = 1 << 20  # 1 MiB, reference session.go:292-316
 
 
 #: colocated peers (single-host cluster, unix-socket transport) pipeline
-#: the socket→reduce stages best with smaller chunks: measured on the
-#: loopback harness, RING over 256 KiB chunks reaches 1.03 GiB/s bus
-#: bandwidth at np=4 where the 1 MiB reference default gets 0.66
-#: (docs/perf.md); cross-host traffic keeps the reference's 1 MiB.
+#: the socket→reduce stages best with smaller chunks: on a loopback
+#: harness before PR 1, RING over 256 KiB chunks reached 1.03 GiB/s bus
+#: bandwidth at np=4 where the 1 MiB reference default got 0.66 (a CPU
+#: run, not repeated); cross-host traffic keeps the reference's 1 MiB.
 CHUNK_SIZE_COLOCATED = 256 << 10
 
 

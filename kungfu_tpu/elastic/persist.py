@@ -51,8 +51,7 @@ first.  Observability: ``kf_ckpt_last_step`` / ``kf_ckpt_age_seconds``
 through the aggregator to ``/cluster`` and kftop's ``CKPT STALE``
 alarm; ``ckpt`` timeline events mark issue/done/restore.
 
-See docs/persistence.md for the manifest format and the goodput
-methodology (``bench.py --persist``).
+See docs/persistence.md for the manifest format.
 """
 
 from __future__ import annotations

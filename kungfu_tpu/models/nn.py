@@ -132,8 +132,8 @@ def batchnorm_apply(p, stats, x, train: bool, momentum=0.9, eps=1e-5,
     normalize/scale/shift elementwise chain — BN's big HBM reads and
     writes — runs in ``compute_dtype``: the activation dtype by default,
     so bf16 activations stay 2 bytes end to end (the round-4 BN-tax
-    diagnosis: the f32 chain cost ~20% of the ResNet-50 step,
-    ``benchmarks/bn_sweep.py`` ``bf16_norm`` variant; the per-channel
+    diagnosis, before PR 1 and unmeasured on today's code: the f32 chain
+    cost ~20% of the ResNet-50 step; ROADMAP W7 is its cell; the per-channel
     mean/inv fold to scalars, so only bf16 rounding of the normalized
     output differs).  ``KF_TPU_BN_COMPUTE=f32`` restores the legacy
     all-f32 chain globally; an explicit ``compute_dtype`` wins."""

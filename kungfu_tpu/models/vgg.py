@@ -10,8 +10,8 @@ batch-norm variant included (VGG trains poorly in bf16 without it): plain
 3x3 conv stacks + 2x2 maxpool, classifier head sized by ``num_classes``.
 
 VGG's uniform 3x3/channel-doubling stacks are nearly all MXU work — the
-historical "heavy" ImageNet model is a natural throughput payload for
-``benchmarks/system.py`` next to ResNet-50.
+historical "heavy" ImageNet model is a natural throughput payload
+next to ResNet-50.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ ONE pure-stdlib implementation shared by the two consumers, exactly the
 aggregator) and the *offline* ``kfhist --verdict`` reader both call
 :func:`changepoint` over the same sample window, so a live alert and the
 post-mortem replay of the durable history can never disagree — asserted
-in tests and in the ``bench.py --sentinel`` gate.
+in tests and in the ``examples/sentinel_gate.py`` drill.
 
 The test is a **median-shift vs MAD** score, chosen for the same reasons
 skew.py picks medians over means:

@@ -8,7 +8,7 @@ stream through the SAME :mod:`~kungfu_tpu.monitor.detect` math the
 online :class:`~kungfu_tpu.monitor.sentinel.Sentinel` runs, with the
 same env-default knobs, so the offline verdict and the live alert are
 one implementation and cannot disagree (asserted in tests and the
-``bench.py --sentinel`` gate).
+``examples/sentinel_gate.py`` drill).
 
 Modes::
 

@@ -20,7 +20,8 @@ doctrine: the effect verdict is a pure function of (decision record,
 effect-series samples), so ``kfhist --decisions`` recomputing it
 offline from the durable streams produces records byte-identical
 (``json.dumps(..., sort_keys=True)``) to the ones the live ledger
-appended — asserted in tests and the ``bench.py --pulse`` gate.
+appended — asserted in ``tests/test_pulse.py`` and, over a live bandit
+mesh, ``tests/test_bandit.py``.
 
 Field discipline: record field names are a declared closed schema
 (:data:`LEDGER_FIELDS`), written through :func:`ledger_record` and read

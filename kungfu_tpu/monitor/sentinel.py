@@ -16,9 +16,9 @@ cluster rollup on a period, and per sample:
    sample buffers.  The buffers are capped at EXACTLY the tail
    :func:`~kungfu_tpu.monitor.detect.changepoint` normalizes to, so the
    online verdict and ``kfhist --verdict`` replayed over the durable
-   history are the SAME object — asserted in tests and the ``bench.py
-   --sentinel`` gate (the skew.py one-implementation doctrine applied to
-   alerting);
+   history are the SAME object — asserted in tests and the
+   ``examples/sentinel_gate.py`` drill (the skew.py one-implementation
+   doctrine applied to alerting);
 3. **alerts** — a rule crossing its line is edge-triggered ONCE (the
    ``_active`` set; no wall-clock cooldown, so fake-clock tests are
    deterministic): ``timeline.event("alert", rule, force=True)`` ticks

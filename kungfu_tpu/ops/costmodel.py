@@ -116,8 +116,8 @@ def train_step_flops(cfg, batch: int, seq: int) -> int:
 
 def serve_prefill_flops(cfg, tokens: int, start: int = 0) -> int:
     """Prefill of ``tokens`` new positions on top of ``start`` cached
-    ones (prefix reuse skips the cached positions' FLOPs — exactly the
-    saving ``bench.py --serve`` measures in computed tokens): matmul +
+    ones (prefix reuse skips the cached positions' FLOPs — the saving
+    ``kf_serve_prefill_tokens_total`` counts in computed tokens): matmul +
     attention into the growing ``[0, start+tokens)`` context, plus ONE
     logits row (prefill emits only the last position's token)."""
     if tokens <= 0:

@@ -1,7 +1,8 @@
 """Fused LM-head + softmax-cross-entropy — logits never touch HBM.
 
-The round-4 analysis (docs/perf.md "disposition of the 0.49× row")
-identified the only honest way to beat XLA's fused xent backward: fuse
+The round-4 analysis (before PR 1; the kernel has run in no cell since,
+ROADMAP R8) identified the only honest way to beat XLA's fused xent
+backward: fuse
 the *consumers* of dlogits — the LM-head matmuls dW = hᵀ·dlogits and
 dh = dlogits·Wᵀ — so the [N, V] dlogits (and the [N, V] logits) never
 materialize.  This module is that kernel pair, flash-attention-shaped:
@@ -20,7 +21,7 @@ materialize.  This module is that kernel pair, flash-attention-shaped:
   ``dlogits_tile = (exp(logits_tile − lse) − onehot)·g`` lives only in
   VMEM.
 
-Roofline (docs/perf.md carries the signed-off version): per logits
+Roofline (by count, not measured on today's code): per logits
 element the fusion saves ~12 HBM bytes (bf16 logits write+read, f32
 log-probs write+read, bf16 dlogits write+read) and pays 2·D recompute
 MACs — at v5e ratios (197 TFLOP/s : 819 GB/s ≈ 240 FLOP/byte) the

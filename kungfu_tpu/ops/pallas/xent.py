@@ -33,9 +33,10 @@ from kungfu_tpu.ops.pallas._sharding import vma_of as _vma
 from kungfu_tpu.ops.pallas._sharding import sds as _sds
 from kungfu_tpu.utils.envs import LaunchKnobs
 
-#: measured on TPU v5e (docs/perf.md): (256, 2048) tiles run the fwd+bwd
-#: sweep ~1.5x faster than the round-3 (128, 512) defaults — big enough
-#: to pipeline HBM reads, small enough for VMEM double-buffering
+#: from a TPU v5e sweep before PR 1 (not repeated on today's code):
+#: (256, 2048) tiles ran the fwd+bwd sweep ~1.5x faster than the round-3
+#: (128, 512) defaults — big enough to pipeline HBM reads, small enough
+#: for VMEM double-buffering
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_V = 2048
 _NEG_INF = -1e30
@@ -243,8 +244,9 @@ _xent.defvjp(_xent_fwd, _xent_bwd)
 
 
 #: Per-shape kernel-vs-XLA routing thresholds, seeded from v5e
-#: measurements taken before PR 1 (docs/perf.md) and not repeated on
-#: today's code:
+#: measurements taken before PR 1 and unmeasured on today's code: no cell
+#: sits on the far side of either, and ROADMAP R8 decides them in one
+#: (``scope_ms_per_step.head``).  What the old sweep read:
 #:
 #: * fwd-only: the kernel streams the logits once and beats XLA's
 #:   materialized log-softmax ~2x at HBM scale (2.49 vs 5.00 ms at
@@ -255,8 +257,7 @@ _xent.defvjp(_xent_fwd, _xent_bwd)
 #:   shape) — UNLESS its O(N*V) log-prob + residual set does not fit,
 #:   where the kernel is the only variant that runs at all (the batch-8
 #:   LM OOMs only the XLA path on 16 GiB).  The byte estimate is
-#:   logits + f32 log-probs per element; re-measure the crossover with
-#:   ``benchmarks/xent_sweep.py --crossover`` and adjust via env.
+#:   logits + f32 log-probs per element.
 XENT_FWD_MIN_ELEMENTS = 1 << 22
 XENT_TRAIN_XLA_BUDGET_MB = 2048
 

@@ -63,8 +63,8 @@ class PairAveragingOptimizer:
         self._rr_next = 0
         self._step_count = 0
         self._recv_buf = None  # reused registered-receive buffer
-        #: cumulative wall seconds / bytes spent inside blob pulls —
-        #: benchmarks/gossip.py derives the measured pull bandwidth
+        #: cumulative wall seconds / bytes spent inside blob pulls (their
+        #: ratio is the pull bandwidth)
         self.pull_seconds = 0.0
         self.pull_bytes = 0
         #: steps that averaged with a pulled model / fell back to local

@@ -21,7 +21,7 @@ is its own process world.  This module is the cross-DCN pipeline axis:
   non-adjacent layer chunks — the virtual-stage schedule is derived by
   a greedy dependency simulation, so any ``v`` is deadlock-free by
   construction), and :func:`schedule_sequential` (the naive baseline
-  ``bench.py --pp`` measures 1F1B against).
+  ``examples/pp_demo.py`` runs 1F1B against).
 * **ZeRO composition** — gradients reduce-scatter over the stage's DP
   group in buckets issued as async handles the moment that stage's last
   backward retires; the PP drain (the bubble) hides the DP wire exactly
@@ -132,8 +132,8 @@ def schedule_sequential(n_micro: int, n_stages: int, stage: int
                         ) -> List[Tuple[str, int, int]]:
     """Naive sequential microbatching — each microbatch runs its full
     forward AND backward through the whole pipe before the next starts,
-    so every DCN hop sits on the critical path.  The baseline the
-    ``bench.py --pp`` gate measures 1F1B against."""
+    so every DCN hop sits on the critical path.  The baseline
+    ``examples/pp_demo.py`` runs 1F1B against."""
     del n_stages, stage
     ops: List[Tuple[str, int, int]] = []
     for m in range(n_micro):

@@ -474,7 +474,8 @@ def record_opt_state_gauge(opt_state) -> int:
 # plus the parameter all-gather every stage pays once per step ((n-1)/n*N;
 # stage 3 pays it *inside* the step, stages 1/2 at the step boundary via
 # the partitioner).  So stage 2 halves the gradient comm of the stage-1
-# all-reduce path — the measured claim in ``bench.py --zero`` — and stage 3
+# all-reduce path — counted from the traced program in
+# ``tests/test_zero.py::TestZeroCommVolume`` — and stage 3
 # additionally drops the at-rest parameter replication to 1/n.
 #
 # The persistent sharded-state GEOMETRY is IDENTICAL across stages (and to
@@ -914,10 +915,10 @@ def zero_train_step(loss_fn, inner: optax.GradientTransformation, comm,
 
 def zero_comm_bytes(total_params: int, n: int, stage: int,
                     itemsize: int = 4) -> dict:
-    """Analytic per-rank wire bytes per training step (ring convention,
-    the busbw accounting ``bench.py`` uses): the honest denominator for
-    the measured :func:`~kungfu_tpu.ops.schedules.traced_collective_bytes`
-    rows.  Keys: ``grad_bytes`` (all-reduce at stage 1, reduce-scatter at
+    """Analytic per-rank wire bytes per training step (ring convention):
+    the honest denominator for
+    the traced :func:`~kungfu_tpu.ops.schedules.traced_collective_bytes`
+    counts.  Keys: ``grad_bytes`` (all-reduce at stage 1, reduce-scatter at
     stages 2/3), ``param_bytes`` (the per-step parameter all-gather —
     partitioner-inserted at stages 1/2, explicit in-step at stage 3) and
     their ``total_bytes``."""
@@ -945,7 +946,8 @@ def zero_comm_bytes(total_params: int, n: int, stage: int,
 # bucket i+k while bucket i's math runs.  Bucket order, tags, and
 # per-bucket arithmetic are IDENTICAL to the serial loop (one geometry,
 # PR 7's invariant), so serial and pipelined runs produce bitwise-equal
-# results — only the wall clock moves (measured: bench.py --overlap).
+# results — only the wall clock moves (``examples/overlap_pipeline.py``
+# asserts both).
 
 
 def host_bucket_spans(chunk: int, widths) -> list:

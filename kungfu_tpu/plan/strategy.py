@@ -46,5 +46,5 @@ def auto_select(num_hosts: int) -> Strategy:
     BINARY_TREE_STAR otherwise (``session/strategy.go:90-99``); this build
     diverges for the single-host case: colocated peers talk over unix
     sockets where RING pipelines chunked transfers ~20% faster than the
-    root-bottlenecked STAR (measured at np∈{2,4}, docs/perf.md)."""
+    root-bottlenecked STAR (a CPU run at np∈{2,4} before PR 1)."""
     return Strategy.RING if num_hosts <= 1 else Strategy.BINARY_TREE_STAR

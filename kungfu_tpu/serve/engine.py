@@ -17,7 +17,8 @@ length bucket + one decode trace — the recompile-hazard discipline):
   into the slab at ``[cached, prompt_len)`` and emitting the first
   generated token.  The cached prefix comes straight out of the paged
   pool (prefix-chain hit), so a shared system prompt costs its pages'
-  load, not its FLOPs — the measured delta in ``bench.py --serve``.
+  load, not its FLOPs (``tests/test_serve.py::TestEngine`` counts the
+  prefill tokens it saves).
 * **decode** — one token for every active slot: write one K/V row per
   slot at the slot's position, attend over ``[0, pos]`` of the slab
   itself, greedy argmax (greedy on purpose: a replayed request

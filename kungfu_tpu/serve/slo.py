@@ -67,8 +67,8 @@ def note_active(n: int) -> None:
 
 def count_prefill(computed: int = 0, reused: int = 0) -> None:
     """Prefill work accounting: ``computed`` tokens ran the forward,
-    ``reused`` came out of the paged cache's prefix chain — the measured
-    basis of the prefix-reuse claim (bench.py --serve)."""
+    ``reused`` came out of the paged cache's prefix chain — the counted
+    basis of the prefix-reuse claim."""
     if computed:
         REGISTRY.counter(PREFILL_COUNTER, what="computed").inc(computed)
     if reused:

@@ -10,7 +10,7 @@ then sets nothing), else one fixed directory inside the checkout.
 
 Called where a process first touches JAX on the chip path
 (``Peer.start`` under ``-backend tpu``, ``chip_smoke.py``'s legs,
-``bench.py``'s payload entry).  CPU test processes never call it, so
+``kfbench/lib/harness.py``).  CPU test processes never call it, so
 the tests run with the cache off.
 """
 

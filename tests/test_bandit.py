@@ -355,6 +355,60 @@ class TestFencedSwapLockstep:
         timeline.reset()
 
 
+    def test_ledger_names_the_swap_that_fixed_the_step(self, peers,
+                                                       monkeypatch,
+                                                       tmp_path):
+        """Every consensus swap writes a durable decision record; fed
+        the step times that followed, the ledger's ``improved`` verdict
+        names the swap onto the arm the ranks ended on, and the offline
+        replay of the durable streams recomputes every judged verdict
+        byte-identically.  Step times are synthetic: no clock."""
+        import json
+
+        from kungfu_tpu.monitor import history, ledger
+        from kungfu_tpu.monitor.adapt_device import HostBanditDriver
+
+        monkeypatch.setenv("KF_SENTINEL_DIR", str(tmp_path))
+        # the floor: a swap is judged from samples that fit between
+        # consecutive votes
+        monkeypatch.setenv("KF_SENTINEL_WINDOW", "2")
+        ledger.reset()
+        led = ledger.ledger_for(str(tmp_path))
+        ring = history.HistoryRing(str(tmp_path), "cluster")
+        drivers = [
+            HostBanditDriver(p, arms=("STAR", "RING"), check_every=2,
+                             min_pulls=1, min_swap_collectives=1)
+            for p in peers
+        ]
+        try:
+            for _ in range(12):
+                dt = 0.1 if drivers[0].active == "STAR" else 0.001
+                # the sentinel's role: ONE record a step lands in the
+                # durable cluster stream AND feeds the online join
+                rec = {"series": {"step_time_s": dt}}
+                ring.append(rec)
+                led.on_sample(rec)
+                run_all([lambda d=d: d.step(dt) for d in drivers])
+            arms = {d.active for d in drivers}
+            assert arms == {"RING"}, arms
+            improved = [row for row in led.view()["decisions"]
+                        if ledger.lfield(row["effect"], "verdict")
+                        == "improved"]
+            assert any(
+                ledger.lfield(row["decision"], "actor") == "bandit-host"
+                and ledger.lfield(row["decision"], "knob") == "strategy"
+                and ledger.lfield(row["decision"], "new") == "RING"
+                for row in improved), led.view()
+            judged = [r for r in ledger.replay_effects(
+                str(tmp_path))["decisions"] if r["online"] is not None]
+            assert judged
+            for r in judged:
+                assert json.dumps(r["online"], sort_keys=True) \
+                    == json.dumps(r["replayed"], sort_keys=True)
+        finally:
+            ledger.reset()
+
+
 class TestCollectiveBanditPolicy:
     """The PolicyRunner wiring: the bandit rides the per-step policy
     callbacks, fed by the loop's measured collective seconds."""

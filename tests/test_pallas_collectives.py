@@ -619,3 +619,67 @@ class TestShardedTrainerSchedule:
         with pytest.raises(ValueError, match="unknown schedule"):
             ShardedTrainer(cfg, MeshPlan(dp=2, pp=1, sp=1, tp=1),
                            schedule="bogus")
+
+
+class TestCompiledOnTpu:
+    """The COMPILED kernels (``interpret=False``), the program a step
+    would run, against what the interpreter suite above pins.  A
+    Mosaic-only fault (a slot race, semaphore drift) cannot show in
+    interpret mode; it must fail here.  Skipped off the chip, and
+    tests/conftest.py holds every ordinary run to the CPU; on a host
+    with chips::
+
+        python -m pytest --noconftest tests/test_pallas_collectives.py \\
+            -k TestCompiledOnTpu
+    """
+
+    @pytest.fixture(autouse=True)
+    def _needs_chips(self):
+        if jax.default_backend() != "tpu" or jax.device_count() < 2:
+            pytest.skip("the compiled ring kernels run over ICI: "
+                        "needs >= 2 TPU chips")
+
+    # 2180 f32 elements -> 24 padded rows: a ragged tail inside the tile
+    # AND tall enough that the bidirectional band split engages
+    CHUNK = 2180
+
+    @pytest.mark.parametrize("bidi", [False, True])
+    def test_reduce_scatter_close_vs_emulation_exact_bitwise(self, bidi):
+        n = jax.device_count()
+        rng = np.random.default_rng(int(bidi))
+        x = rng.standard_normal((n, n * self.CHUNK)).astype(np.float32)
+        xi = rng.integers(-1000, 1000,
+                          (n, n * self.CHUNK)).astype(np.float32)
+
+        def rs(impl, interpret):
+            return lambda row: ring_reduce_scatter(
+                row[0], "x", bidirectional=bidi, impl=impl,
+                interpret=interpret)[None]
+
+        def ref(row):
+            return jax.lax.psum_scatter(
+                row[0], "x", scatter_dimension=0, tiled=True)[None]
+
+        np.testing.assert_allclose(
+            _world(n, rs("pallas", False), jnp.asarray(x)),
+            _world(n, rs("lax", None), jnp.asarray(x)),
+            rtol=1e-5, atol=1e-5)
+        # order-exact data: every reduction order gives the same bits
+        assert (_world(n, rs("pallas", False), jnp.asarray(xi)).tobytes()
+                == _world(n, ref, jnp.asarray(xi)).tobytes())
+
+    @pytest.mark.parametrize("bidi", [False, True])
+    def test_all_gather_bitwise_vs_lax(self, bidi):
+        n = jax.device_count()
+        rng = np.random.default_rng(2 + int(bidi))
+        s = jnp.asarray(
+            rng.standard_normal((n, self.CHUNK)).astype(np.float32))
+
+        def ag(sh):
+            return ring_all_gather(sh[0], "x", bidirectional=bidi,
+                                   impl="pallas", interpret=False)[None]
+
+        def ref(sh):
+            return jax.lax.all_gather(sh[0], "x", axis=0, tiled=True)[None]
+
+        assert _world(n, ag, s).tobytes() == _world(n, ref, s).tobytes()

@@ -7,7 +7,6 @@ import json
 import os
 import subprocess
 import sys
-import types
 import urllib.error
 import urllib.request
 
@@ -609,10 +608,6 @@ class TestScripts:
         v = json.loads(r.stdout)["verdicts"]["step_time_s"]
         assert v["shifted"] and v["direction"] == "up"
 
-    def test_kfbench_diff_self_check(self):
-        r = self._run("kfbench-diff", "--self-check")
-        assert r.returncode == 0, r.stderr
-
 
 @pytest.mark.slow
 class TestLiveMesh:
@@ -623,9 +618,8 @@ class TestLiveMesh:
         # planted rank, kfhist replay identical to the incident verdicts
         sys.path.insert(0, ROOT)
         try:
-            import bench
-            row = bench.payload_sentinel(types.SimpleNamespace(quick=True))
+            from examples import sentinel_gate
+            row = sentinel_gate.run()
         finally:
             sys.path.remove(ROOT)
-        assert row["vs_baseline"] == 1.0, row["checks"]
         assert all(row["checks"].values()), row["checks"]

@@ -109,8 +109,7 @@ class TestEngine:
         assert eng.pending_count == 2
 
     def test_prefix_reuse_reduces_prefill_work(self, model_and_params):
-        """The measured claim behind bench.py --serve: a shared prefix
-        prefills only its un-cached suffix."""
+        """A shared prefix prefills only its un-cached suffix."""
         eng = make_engine(model_and_params)
         shared = list(range(1, 20))  # 19 tokens: 2 full pages of 8
         eng.submit("first", shared + [21], 4)
