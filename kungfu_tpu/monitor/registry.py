@@ -224,7 +224,7 @@ METRIC_HELP: Dict[str, str] = {
     "kf_serve_ttft_seconds":
         "time to first token (admission to first decode), worker-side",
     "kf_serve_token_seconds":
-        "decode-step latency per generated token, worker-side",
+        "time between two deliveries of decode tokens, worker-side",
     "kf_serve_e2e_seconds":
         "end-to-end request latency (submit to completion incl. "
         "routing, queueing, and any post-failure replay), router-side",

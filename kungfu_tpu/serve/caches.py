@@ -12,10 +12,18 @@ asks of one object, ``model.serve_caches(max_batch, max_seq)``:
     the body of the prefill program: ``ids`` ``[P]`` (the prompt past
     ``start`` cached positions, zero-padded past ``n``) into ``slot``;
     the greedy token after row ``n - 1``.
-``decode(params, k, v, last_ids, pos)`` -> ``(k, v, out)``
-    the body of the decode program: one token for every slot.  ``out``
-    is ONE array, so that the host's one read brings all a step has to
-    say; ``read(out)`` takes it apart.
+``decode(params, k, v, last_ids, pos, live)`` -> ``(k, v, out)``
+    the body of the decode program: one token for every slot.  ``live``
+    ``[B]`` says which slots the step is for: the row of any other is
+    NOT written (the engine dispatches a step before it has read the one
+    before, so a slot it leaves out may hold a request that is finishing
+    and whose rows are yet to be committed: docs/serving.md).  ``out``
+    is ONE array whose first ``B`` entries are the slots' tokens (the
+    next step takes them from it on the device), so that the host's one
+    read brings all a step has to say; ``read(out)`` takes it apart.
+``new_out()``
+    what stands for a step's ``out`` before any step ran: zeros of its
+    shape and dtype.
 ``read(out)`` -> ``(tokens [B], attrs or None)``
     on the host: fetch a decode step's ``out`` (the host waits here), the
     slots' tokens and what the step has to say of itself besides, as
@@ -55,16 +63,20 @@ from kungfu_tpu.models.transformer import _rope
 from kungfu_tpu.ops import costmodel
 
 
-def row_windows(pos, s_max):
+def row_windows(pos, s_max, live):
     """Per slot ``b``: where the aligned window of ``S`` that holds
-    position ``pos[b]`` starts, and which of its rows that is.  Each
-    start is a scalar ``p // w * w`` on purpose: from that the
-    compiler knows the window is tile-aligned and updates it in
-    place; sliced out of a vector of starts it no longer does, and
-    the write takes five times as long (tests/test_tpu_compile.py)."""
+    position ``pos[b]`` starts, and which of its rows that is -- none of
+    them where ``live[b]`` is false: such a slot's window is written
+    back as it was read (the row to hit is a scalar, so the select over
+    the window is the same vector work either way).  Each start is a
+    scalar ``p // w * w`` on purpose: from that the compiler knows the
+    window is tile-aligned and updates it in place; sliced out of a
+    vector of starts it no longer does, and the write takes five times
+    as long (tests/test_tpu_compile.py)."""
     w = math.gcd(s_max, 128)                # divides S: never clamped
     lane = jnp.arange(w)[:, None]
-    return [(p // w * w, lane == p % w) for p in pos]
+    return [(p // w * w, lane == jnp.where(l, p % w, -1))
+            for p, l in zip(pos, live)]
 
 
 def write_rows(slab, li, new, windows):
@@ -201,10 +213,11 @@ class DenseCaches:
             tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
         return k_slab, v_slab, tok
 
-    def decode(self, params, k_slab, v_slab, last_ids, pos):
-        """One token for every slot: ``last_ids``/``pos`` are [B]; the
-        new K/V lands at each slot's ``pos`` and attention covers
-        ``[0, pos]``.  Inactive slots compute garbage nobody reads."""
+    def decode(self, params, k_slab, v_slab, last_ids, pos, live):
+        """One token for every slot: ``last_ids``/``pos``/``live`` are
+        [B]; a live slot's new K/V lands at its ``pos`` and attention
+        covers ``[0, pos]``.  The others compute garbage nobody reads,
+        and write nothing."""
         cfg = self.cfg
         dt = cfg.compute_dtype
         s_max = k_slab.shape[3]
@@ -216,7 +229,7 @@ class DenseCaches:
                 h = h + nn.embedding_apply(params["pos_embed"], positions,
                                            dtype=dt)
         mask = (jnp.arange(s_max)[None, :] <= positions)[:, None, None, :]
-        windows = row_windows(pos, s_max)
+        windows = row_windows(pos, s_max, live)
         for li in range(cfg.n_layers):
             lp = params[f"layer_{li}"]
             x = nn.layernorm_apply(lp["ln1"], h)
@@ -232,6 +245,9 @@ class DenseCaches:
                                     ).astype(jnp.float32)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return k_slab, v_slab, tok
+
+    def new_out(self):
+        return jnp.zeros(self.batch, jnp.int32)
 
     @staticmethod
     def read(out):

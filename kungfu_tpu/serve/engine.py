@@ -10,6 +10,20 @@ step for ALL active slots — new requests join the running batch between
 decode steps instead of waiting for a batch boundary, and long prompts
 cannot starve in-flight decodes.
 
+The loop runs **one decode step ahead of the host**.  A step's tokens
+are the next step's input, and they stay on the device: :meth:`step`
+dispatches step n+1 from step n's output as it left the program, and
+only then waits for step n's tokens.  The host knows every slot's
+position and which slots go on without seeing a token (a request whose
+token in flight is its ``max_new``-th is left out), so the device runs
+steps back to back and a token's event arrives one call after its
+step was dispatched, when the host really has it.  What the host cannot
+know is a request that ends on ``eos_id``: the step behind has then
+computed its slot once more, and the program itself keeps that row out
+of the cache (``_decode_fn``).  A call that admits does so first: the
+prefill queues on the device behind the step in flight and starts the
+moment that step ends (docs/serving.md, "One step ahead").
+
 Phases are jit-compiled with static shapes (one trace per prefill
 length bucket + one decode trace — the recompile-hazard discipline):
 
@@ -53,7 +67,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +108,14 @@ class _Req:
     @property
     def total_len(self) -> int:
         return len(self.tokens) + len(self.generated)
+
+
+class _Flight(NamedTuple):
+    """A dispatched decode step whose tokens the host has not read."""
+    out: jax.Array                  # the program's ``out``, on the device
+    rows: Dict[int, _Req]           # slot -> the request it computes
+    contexts: np.ndarray            # positions each row attends over
+    dispatched_s: float
 
 
 class InferenceEngine:
@@ -147,6 +169,12 @@ class InferenceEngine:
         # the model's side of the cache (serve/caches.py)
         self._caches = model.serve_caches(self.max_batch, self.max_seq)
         self._k, self._v = self._caches.new_slabs()
+        # the newest decode step's ``out`` (its first max_batch entries
+        # the slots' tokens), which the next step takes on the device;
+        # and that step itself, until the host has read its tokens
+        self._out = self._caches.new_out()
+        self._flight: Optional[_Flight] = None
+        self._delivered_s = 0.0         # when tokens last reached the host
         # Every program that writes the slabs takes them donated and
         # updates them in place, on every backend: a call that copied
         # them would move 3 GB at GPT-2 large (PERF.md, PR 25).  The
@@ -170,11 +198,22 @@ class InferenceEngine:
         return self._caches.prefill(params, k_slab, v_slab, ids, n, start,
                                     slot)
 
-    def _decode_fn(self, params, k_slab, v_slab, last_ids, pos):
-        """One token for every slot: ``last_ids``/``pos`` are [B]; the
-        new K/V lands at each slot's ``pos``.  Inactive slots compute
-        garbage nobody reads."""
-        return self._caches.decode(params, k_slab, v_slab, last_ids, pos)
+    def _decode_fn(self, params, k_slab, v_slab, prev, fresh, pos):
+        """One token for every slot the step is for, those at a ``pos``
+        [B] above 0: its last token is ``fresh`` [B] where the host knows
+        it (a slot admitted since the step before) and, at -1, the one
+        the step before made for it (``prev``, that step's ``out``, read
+        here and not on the host).  The new K/V lands at the slot's
+        ``pos`` -- unless the last token is ``eos_id``: the host
+        dispatched this step before it could see that the request had
+        ended, and a row written for it could replace one that is yet to
+        be committed.  The other slots compute garbage nobody reads and
+        write nothing."""
+        last = jnp.where(fresh >= 0, fresh, prev[:self.max_batch])
+        live = pos > 0
+        if self.eos_id is not None:
+            live &= last != self.eos_id
+        return self._caches.decode(params, k_slab, v_slab, last, pos, live)
 
     @staticmethod
     @jax.named_scope("kv_write")
@@ -225,11 +264,11 @@ class InferenceEngine:
             self._k, self._v, tok = self._prefill_j(
                 self.params, self._k, self._v, jnp.zeros(s_pad, jnp.int32),
                 jnp.int32(1), jnp.int32(0), jnp.int32(0))
-        self._k, self._v, tok = self._decode_j(
-            self.params, self._k, self._v,
-            jnp.zeros(self.max_batch, jnp.int32),
-            jnp.zeros(self.max_batch, jnp.int32))
-        tok.block_until_ready()
+        nobody = jnp.zeros(self.max_batch, jnp.int32)  # position 0: not live
+        self._k, self._v, self._out = self._decode_j(
+            self.params, self._k, self._v, self._out,
+            jnp.full(self.max_batch, -1, jnp.int32), nobody)
+        self._out.block_until_ready()
 
     # -- scheduling ------------------------------------------------------
     @property
@@ -434,9 +473,11 @@ class InferenceEngine:
 
     # -- the step --------------------------------------------------------
     def step(self) -> List[dict]:
-        """One continuous-batching iteration: admit (bounded), decode
-        every active slot, retire finished requests.  Returns events:
-        ``{"kind": "admit"|"token"|"done", ...}`` in occurrence order."""
+        """One continuous-batching iteration: admit (bounded), dispatch
+        the next decode step for every slot that goes on, read the
+        tokens of the step the previous call dispatched, retire finished
+        requests.  Returns events: ``{"kind": "admit"|"token"|"done",
+        ...}`` in the order the host came to know them."""
         with self._lock:
             pending, active = len(self._pending), len(self._active)
         with timeline.span("serve", "step", rank=self.rank,
@@ -447,6 +488,11 @@ class InferenceEngine:
         events: List[dict] = []
         self._steps += 1
         t_step0 = time.perf_counter()
+        # consume cancel flags on the step thread (the only retirer)
+        with self._lock:
+            doomed = [(s, r) for s, r in self._active.items() if r.canceled]
+            for s, r in doomed:
+                self._retire_locked(s, r)
         admitted = 0
         while admitted < self.admit_per_step:
             with self._lock:
@@ -466,55 +512,88 @@ class InferenceEngine:
                            "tok": req.generated[-1], "n": 1})
             if self._is_done(req):
                 events.append({"kind": "done", **self._complete(req.slot, req)})
-        # consume cancel flags on the step thread (the only retirer)
-        with self._lock:
-            doomed = [(s, r) for s, r in self._active.items() if r.canceled]
-            for s, r in doomed:
-                self._retire_locked(s, r)
-        with self._lock:
-            active = dict(self._active)
-        if active:
-            t0 = time.perf_counter()
-            # batch: the live slots; width: the slots the decode program
-            # computes, live or not
-            with timeline.span("serve", "decode", rank=self.rank,
-                               batch=len(active), width=self.max_batch):
-                B = self.max_batch
-                last = np.zeros(B, np.int32)
-                pos = np.zeros(B, np.int32)
-                for slot, r in active.items():
-                    last[slot] = r.generated[-1]
-                    pos[slot] = r.total_len - 1
-                self._k, self._v, nxt = self._decode_j(
-                    self.params, self._k, self._v,
-                    jnp.asarray(last), jnp.asarray(pos))
-            with timeline.span("serve", "decode_read", rank=self.rank) as sp:
-                # the host waits here; what the step says of itself
-                # besides its tokens (an expert model's routing) goes on
-                # the span that is open when it is known
-                nxt, attrs = self._caches.read(nxt)
-                if attrs:
-                    sp.set_metadata(**attrs)
-            slo.observe_token(time.perf_counter() - t0)
-            self._mfu.add_flops(
-                self._caches.decode_flops(pos[list(active)] + 1))
-            toks = nxt.tolist()  # (one conversion, not two a slot)
-            for slot, r in active.items():
-                r.generated.append(toks[slot])
-                events.append({"kind": "token", "rid": r.rid,
-                               "tok": toks[slot], "n": len(r.generated)})
-                if self._is_done(r):
-                    events.append({"kind": "done", **self._complete(slot, r)})
+        # step n+1 goes to the device, then step n's tokens come back
+        behind = self._flight
+        self._flight = self._dispatch(behind)
+        if behind is not None:
+            self._read(behind, events)
         self._mfu.step(wall_s=time.perf_counter() - t_step0)
         slo.note_active(self.active_count)
         return events
 
+    def _dispatch(self, behind: Optional[_Flight]) -> Optional[_Flight]:
+        """Start a decode step for every active request that goes on by
+        what the host can know, behind the unread step ``behind`` (or
+        None): a request with a token in flight takes it from the device
+        and stands one position further; one whose token in flight is
+        its last by ``max_new`` is left out."""
+        with self._lock:
+            active = dict(self._active)
+        B = self.max_batch
+        fresh = np.full(B, -1, np.int32)
+        pos = np.zeros(B, np.int32)
+        rows: Dict[int, _Req] = {}
+        for slot, r in active.items():
+            flying = int(behind is not None and behind.rows.get(slot) is r)
+            if len(r.generated) + flying >= r.max_new:
+                continue
+            rows[slot] = r
+            pos[slot] = r.total_len + flying - 1
+            if not flying:
+                fresh[slot] = r.generated[-1]
+        if not rows:
+            return None
+        t0 = time.perf_counter()
+        # batch: the slots the step is for; width: the slots the decode
+        # program computes, live or not; ahead: dispatched before the
+        # step behind it was read
+        with timeline.span("serve", "decode", rank=self.rank,
+                           batch=len(rows), width=B,
+                           ahead=int(behind is not None)):
+            self._k, self._v, self._out = self._decode_j(
+                self.params, self._k, self._v, self._out,
+                jnp.asarray(fresh), jnp.asarray(pos))
+        return _Flight(self._out, rows, pos[list(rows)] + 1, t0)
+
+    def _read(self, flight: _Flight, events: List[dict]) -> None:
+        """Wait for the tokens of a dispatched step and hand them out.
+        A row whose request is gone meanwhile (it ended on ``eos_id`` in
+        the step before, or was cancelled) is dropped."""
+        with timeline.span("serve", "decode_read", rank=self.rank) as sp:
+            # the host waits here; what the step says of itself besides
+            # its tokens (an expert model's routing) goes on the span
+            # that is open when it is known
+            toks, attrs = self._caches.read(flight.out)
+            with self._lock:
+                rows = [(slot, r) for slot, r in flight.rows.items()
+                        if self._active.get(slot) is r]
+            sp.set_metadata(discarded=len(flight.rows) - len(rows),
+                            **(attrs or {}))
+        now = time.perf_counter()
+        if rows:
+            # what a client feels: the time between two deliveries (a
+            # step after an idle stretch or an admission: since it left)
+            slo.observe_token(now - max(flight.dispatched_s,
+                                        self._delivered_s))
+            self._delivered_s = now
+        self._mfu.add_flops(self._caches.decode_flops(flight.contexts))
+        toks = toks.tolist()  # (one conversion, not two a slot)
+        for slot, r in rows:
+            r.generated.append(toks[slot])
+            events.append({"kind": "token", "rid": r.rid,
+                           "tok": toks[slot], "n": len(r.generated)})
+            if self._is_done(r):
+                events.append({"kind": "done", **self._complete(slot, r)})
+
     def drain(self, max_steps: int = 10_000) -> List[dict]:
-        """Run steps until idle (tests / local mode); bounded so a
-        non-terminating request cannot wedge the caller."""
+        """Run steps until idle (tests / local mode), the step in flight
+        read too (behind a request that ended on ``eos_id`` one is left
+        with nobody's row in it); bounded so a non-terminating request
+        cannot wedge the caller."""
         out: List[dict] = []
         for _ in range(max_steps):
-            if not (self.pending_count or self.active_count):
+            if not (self.pending_count or self.active_count
+                    or self._flight is not None):
                 break
             out.extend(self.step())
         return out

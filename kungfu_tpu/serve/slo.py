@@ -13,8 +13,10 @@ The three serving latencies (docs/serving.md):
 
 * **TTFT** (``kf_serve_ttft_seconds``) — admission to first decoded
   token, measured at the worker (includes engine queue wait);
-* **per-token** (``kf_serve_token_seconds``) — decode-step wall time
-  per active request, measured at the worker;
+* **per-token** (``kf_serve_token_seconds``) — the time between two
+  deliveries of decode tokens to the host, measured at the worker (the
+  engine runs one step ahead: a delivery, not a step's own dispatch and
+  read, is what a client feels);
 * **e2e** (``kf_serve_e2e_seconds``) — submit to completion, measured
   at the router (includes routing, wire, queue, replay after a worker
   death — the number a user feels).

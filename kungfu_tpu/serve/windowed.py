@@ -84,23 +84,25 @@ class WindowedCaches:
                      for _ in "kv")
 
     # -- the two forward passes ------------------------------------------
-    def decode(self, params, k, v, last_ids, pos):
-        """One token for every slot (``last_ids``/``pos`` ``[B]``; a slot
-        at position 0 holds no request: it computes what nobody reads and
-        is counted nowhere).  Returns the slabs and ONE int32 vector: the
+    def decode(self, params, k, v, last_ids, pos, live):
+        """One token for every slot (``last_ids``/``pos``/``live``
+        ``[B]``; a slot that is not live computes what nobody reads,
+        writes no row -- a ring holds exactly ``W``, so a row too many
+        would overwrite one that is yet to be committed -- and is
+        counted nowhere).  Returns the slabs and ONE int32 vector: the
         ``B`` tokens, then the step's routing over the live slots and all
         layers -- held experts that received a token, the busiest
         expert's tokens, the tokens received in all -- so that the host's
         one read brings both."""
         cfg, model, ring = self.cfg, self.model, self.ring
         (kw, kf), (vw, vf) = k, v
-        live = pos > 0
         rows = jnp.arange(ring)
         # ring row r holds the last position <= pos that lands on it
         see_w = (pos[:, None] - (pos[:, None] - rows) % ring >= 0
                  )[:, None, None, None]
         see_f = (jnp.arange(self.seq) <= pos[:, None])[:, None, None, None]
-        at_w, at_f = row_windows(pos % ring, ring), row_windows(pos, self.seq)
+        at_w = row_windows(pos % ring, ring, live)
+        at_f = row_windows(pos, self.seq, live)
 
         class Step:
             """A decode step's cache: one row a slot into each slab,
@@ -140,6 +142,9 @@ class WindowedCaches:
                                  jnp.sum(counts)])
         return ((kw, kf), (vw, vf),
                 jnp.concatenate([tok, routing]).astype(jnp.int32))
+
+    def new_out(self):
+        return jnp.zeros(self.batch + 3, jnp.int32)
 
     def read(self, out):
         """A decode step's ``out`` on the host: the slots' tokens, and

@@ -23,6 +23,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from tests import _lookahead  # noqa: E402
+
 from kfbench.lib import files  # noqa: E402
 from kungfu_tpu.models import experts  # noqa: E402
 from kungfu_tpu.models.cohere2_moe import Cohere2Moe  # noqa: E402
@@ -68,9 +70,9 @@ def build(adapter, cfg, seed=0):
     return Cohere2Moe(dataclasses.replace(model.cfg, dtype="float32")), params
 
 
-def engine(model, params, slots=3, capacity=64):
+def engine(model, params, slots=3, capacity=64, eos_id=None):
     return InferenceEngine(
-        model, params, max_batch=slots, max_seq=MAX_SEQ,
+        model, params, max_batch=slots, max_seq=MAX_SEQ, eos_id=eos_id,
         pool=KVCachePool(PageSpec.for_model(model.cfg, page_tokens=PAGE),
                          capacity_pages=capacity))
 
@@ -182,43 +184,97 @@ def test_decode_returns_the_routing_of_live_slots_only(adapter, monkeypatch):
     cfg = tiny_cfg(0, 16)
     model, params = build(adapter, cfg)
     eng = engine(model, params, slots=3)
-    spans = []
-
-    class Span:
-        def __init__(self, name, attrs):
-            self.name, self.attrs = name, dict(attrs)
-            spans.append(self)
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-        def set_metadata(self, **attrs):
-            self.attrs.update(attrs)
-
-    from kungfu_tpu.monitor import timeline
-    monkeypatch.setattr(timeline, "span",
-                        lambda kind, name, **attrs: Span(name, attrs))
+    spans = _lookahead.record_spans(monkeypatch)
 
     def last(name):
         return [s for s in spans if s.name == name][-1].attrs
 
     eng.submit("a", ids_of(3, 5), 4)
-    eng.step()
+    eng.step()                          # admits a, dispatches its step
+    assert last("decode")["batch"] == 1 and last("decode")["ahead"] == 0
+    eng.step()                          # the next step, then that one read
     r = last("decode_read")
     # one live slot, every expert held: top-4 of each of 4 layers
     assert r["experts_touched"] == 16 and r["expert_load_max"] == 1
     assert r["experts_held"] == 64 and r["expert_load_mean"] == 16 / 64
-    assert last("decode")["batch"] == 1
+    assert r["discarded"] == 0
+    assert last("decode")["batch"] == 1 and last("decode")["ahead"] == 1
     eng.submit("b", ids_of(4, 7), 4)
-    eng.step()
-    assert last("decode")["batch"] == 2
+    eng.step()                          # admits b, dispatches a and b, reads
+    assert last("decode")["batch"] == 2 and last("decode")["ahead"] == 1
+    assert last("decode_read")["expert_load_mean"] == 16 / 64
+    eng.step()                          # a's last token is in flight: b alone
+    assert last("decode")["batch"] == 1
     assert last("decode_read")["expert_load_mean"] == 32 / 64
     assert last("decode_read")["experts_touched"] <= 32
     # a dense model's step has nothing to add to its span
     assert "experts_touched" not in last("decode")
+
+
+# -- one decode step ahead of the host, over the rings ---------------------
+#: rid -> (prompt, max_new): _lookahead.mixed_run's roles.  ``stops`` has
+#: a prompt longer than the window and room to wrap the ring twice
+MIXED = {"by_n": (ids_of(31, 5), 9), "stops": (ids_of(32, 11), 21),
+         "dropped": (ids_of(33, 6), 20), "late": (ids_of(34, 3), 12),
+         "next": (ids_of(35, 4), 5)}
+
+
+@pytest.fixture(scope="module")
+def mixed(ref, adapter):
+    """The mixed set through the two caches with an ``eos_id`` that ends
+    ``stops`` early, past the window: (model, params, what the plain
+    float32 reference decodes, events, slots, engine)."""
+    cfg = tiny_cfg(0, 16)
+    with jax.default_matmul_precision("highest"):
+        model, params = build(adapter, cfg)
+        forward = jax.jit(lambda p, ids: ref.logits(cfg, p, ids))
+
+        def decode(prompt, n):
+            """Greedy, a full forward pass a token (padded: causal)."""
+            seq = list(prompt)
+            for _ in range(n):
+                ids = np.zeros(MAX_SEQ, np.int32)
+                ids[:len(seq)] = seq
+                seq.append(int(np.argmax(np.asarray(
+                    forward(params, jnp.asarray(ids)))[len(seq) - 1])))
+            return seq[len(prompt):]
+
+        reference = {rid: decode(*a) for rid, a in MIXED.items()}
+        eos = _lookahead.pick_eos(reference, MIXED, earliest=8)
+        want = {rid: _lookahead.until_eos(toks, eos)
+                for rid, toks in reference.items()}
+        eng = engine(model, params, eos_id=eos)
+        events, slots = _lookahead.mixed_run(eng, MIXED)
+    return model, params, want, events, slots, eng
+
+
+def test_mixed_requests_one_step_ahead_decode_what_the_reference_decodes(
+        mixed):
+    """One ends by ``max_new``, one on ``eos_id`` with more tokens than
+    the window holds, one is cancelled with its step in flight, one is
+    admitted while others decode, one takes the slot the discarded row
+    left: token for token the plain reference's, every ``done`` returned."""
+    model, params, want, events, slots, eng = mixed
+    got = _lookahead.tokens_of(events)
+    assert set(got) == set(MIXED) - {"dropped"}
+    assert got == {rid: want[rid] for rid in got}
+    assert got["stops"][-1] == eng.eos_id
+    assert WINDOW < len(MIXED["stops"][0]) + len(got["stops"]) < MAX_SEQ
+    assert len(got["by_n"]) == 9
+
+
+@pytest.mark.parametrize("against", ["same_schedule", "alone"])
+def test_committed_pages_one_step_ahead_hold_the_same_bytes(mixed, against):
+    """The discarded row of the request that ended on ``eos_id`` would
+    land on the ring row of a position the commit still counts as held,
+    and the row of a slot left out on one that is yet to be committed:
+    the pool holds byte for byte, whole or not, what engines hold that
+    never compute such a row (tests/test_serve.py has the dense twin)."""
+    model, params, want, events, slots, eng = mixed
+    assert not all(whole for _, _, _, whole
+                   in _lookahead.committed(eng.pool).values())
+    _lookahead.check_committed(lambda: engine(model, params), MIXED, want,
+                               events, slots, eng.pool, against)
 
 
 # -- (b) the share: eight chips' routed parts, the shared experts once ----
@@ -424,7 +480,7 @@ def test_one_initialisation_scaled_by_the_whole_models_depth(adapter):
 #: below, as the commit before this model lowered them (PR 25's tree).
 #: A PR that changes the dense path on purpose records its own.
 GPT2_PROGRAMS = {
-    "decode": "07ca0e31732822541cb0c8a08853d349368c67d3f3877684cfd3cd7f3b19fece",
+    "decode": "27ba8358f322633b0d3314a33806b063ab7f5ed8181766275c7320c26bb487c4",
     "prefill": "3213586b14c887b542fe403c8f4bab439f9c5f541bf8942be4b754ed27095e31",
     "restore": "98d26d159b3a0ab4227b24739a9cdf57bb6888c63efda7b1929ca9f30b72aa69",
 }
@@ -443,7 +499,7 @@ def test_gpt2_programs_lower_bitwise_as_before(program):
         z, i0 = jnp.zeros(3, jnp.int32), jnp.int32(0)
         pages = jnp.zeros((2, 4, 8, 8), jnp.bfloat16)
         lowered = {
-            "decode": lambda: eng._decode_j.lower(p, eng._k, eng._v, z, z),
+            "decode": lambda: eng._decode_j.lower(p, eng._k, eng._v, z, z, z),
             "prefill": lambda: eng._prefill_j.lower(
                 p, eng._k, eng._v, jnp.zeros(8, jnp.int32), i0, i0, i0),
             "restore": lambda: eng._restore_j.lower(eng._k, eng._v, pages,

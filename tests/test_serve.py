@@ -26,6 +26,8 @@ from kungfu_tpu.serve.engine import InferenceEngine
 from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
 from kungfu_tpu.serve.router import ServeRouter, ServeWorker
 
+from tests import _lookahead
+
 CFG = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=2,
                         d_ff=64, max_seq=128, dtype="float32")
 
@@ -44,12 +46,12 @@ def _fresh_chaos():
 
 
 def make_engine(model_and_params, pages=128, max_batch=4, page_tokens=8,
-                rank=None):
+                rank=None, eos_id=None):
     model, params = model_and_params
     pool = KVCachePool(PageSpec.for_model(CFG, page_tokens=page_tokens),
                        capacity_pages=pages)
     return InferenceEngine(model, params, pool=pool, max_batch=max_batch,
-                           max_seq=CFG.max_seq, rank=rank)
+                           max_seq=CFG.max_seq, rank=rank, eos_id=eos_id)
 
 
 @functools.lru_cache(maxsize=None)
@@ -215,7 +217,7 @@ def _lower_slab_program(eng, program):
     z = jnp.zeros(eng.max_batch, jnp.int32)
     i0 = jnp.int32(0)
     if program == "decode":
-        return eng._decode_j.lower(eng.params, eng._k, eng._v, z, z), 1
+        return eng._decode_j.lower(eng.params, eng._k, eng._v, z, z, z), 1
     if program == "prefill":
         return eng._prefill_j.lower(
             eng.params, eng._k, eng._v, jnp.zeros(16, jnp.int32),
@@ -331,6 +333,231 @@ class TestSlabInPlace:
         done = {e["rid"]: e["tokens"] for e in events if e["kind"] == "done"}
         assert done == {rid: reference_tokens(model, params, prompt, n)
                         for rid, (prompt, n) in asked.items()}
+
+
+# -- one decode step ahead of the host --------------------------------------
+#: rid -> (prompt, max_new): _lookahead.mixed_run's roles
+MIXED = {"by_n": (list(range(1, 12)), 9), "stops": ([9, 8, 7, 6, 5], 24),
+         "dropped": ([3, 1, 4, 1, 5, 9], 30), "late": ([60, 61], 14),
+         "next": ([5, 6, 7], 6)}
+
+
+def make_eos_engine(model_and_params, eos_id):
+    """Three slots (``mixed_run``'s), pages of 4: more of them commit."""
+    return make_engine(model_and_params, max_batch=3, page_tokens=4,
+                       eos_id=eos_id)
+
+
+class TestOneStepAhead:
+    """Step n+1 is dispatched from the device's own tokens before step
+    n's are read (docs/serving.md, "One step ahead")."""
+
+    @pytest.fixture(scope="class")
+    def mixed(self, model_and_params):
+        """The mixed set served with an ``eos_id`` that ends ``stops``
+        early: (eos, what the reference decodes, events, slots, engine)."""
+        model, params = model_and_params
+        reference = {rid: reference_tokens(model, params, prompt, n)
+                     for rid, (prompt, n) in MIXED.items()}
+        eos = _lookahead.pick_eos(reference, MIXED, earliest=8)
+        want = {rid: _lookahead.until_eos(toks, eos)
+                for rid, toks in reference.items()}
+        eng = make_eos_engine(model_and_params, eos)
+        events, slots = _lookahead.mixed_run(eng, MIXED)
+        return eos, want, events, slots, eng
+
+    def test_mixed_requests_decode_what_the_reference_decodes(self, mixed):
+        """One ends by ``max_new``, one on ``eos_id``, one is cancelled
+        with its step in flight, one is admitted while others decode, one
+        takes the slot a discarded row left: token for token the
+        full-context forward's, and ``drain()`` returns every ``done``."""
+        eos, want, events, slots, eng = mixed
+        got = _lookahead.tokens_of(events)
+        assert set(got) == set(MIXED) - {"dropped"}
+        assert got == {rid: want[rid] for rid in got}
+        assert got["stops"][-1] == eos and len(got["stops"]) < 24
+        assert len(got["by_n"]) == 9 and eos not in got["by_n"]
+        # every token was announced once, in order, a call after its step
+        for rid, toks in got.items():
+            assert [e["tok"] for e in events
+                    if e["kind"] == "token" and e["rid"] == rid] == toks
+
+    @pytest.mark.parametrize("against", ["same_schedule", "alone"])
+    def test_committed_pages_hold_the_same_bytes(self, model_and_params,
+                                                 mixed, against):
+        """The pool after the mixed run holds, byte for byte and under
+        the same chains, what engines hold that never discard a row:
+        ``same_schedule`` a fresh engine without ``eos_id`` given the
+        same requests at the lengths they stopped at (what differs is
+        the discarded row alone); ``alone`` one fresh engine a request,
+        which therefore never has a step in flight behind a request that
+        is finishing."""
+        eos, want, events, slots, eng = mixed
+        _lookahead.check_committed(
+            lambda: make_eos_engine(model_and_params, None), MIXED, want,
+            events, slots, eng.pool, against)
+
+    def test_read_of_step_n_follows_dispatch_of_step_n_plus_1(
+            self, model_and_params, monkeypatch):
+        """The order is held by counting, not timing: in the steady state
+        the read of step n is entered only after the decode program was
+        called n+1 times, on a call that admits too (its prefill queues
+        on the device behind the step in flight); ``ahead`` is 1 on those
+        steps and 0 where nothing was in flight; ``discarded`` is 1 on
+        the step after an ``eos_id`` ending and nowhere else."""
+        model, params = model_and_params
+        asked = {"stops": MIXED["stops"], "goes_on": ([60, 61], 20)}
+        want = {rid: reference_tokens(model, params, *a)
+                for rid, a in asked.items()}
+        eos = _lookahead.pick_eos(want, asked, earliest=5)
+        n_stops = want["stops"].index(eos) + 1
+        eng = make_eos_engine(model_and_params, eos)
+        spans = _lookahead.record_spans(monkeypatch)
+        calls = []
+        decode_j, read = eng._decode_j, eng._caches.read
+
+        def counted_decode(*a):
+            calls.append("decode")
+            return decode_j(*a)
+
+        def counted_read(out):
+            calls.append("read")
+            return read(out)
+
+        eng._decode_j = counted_decode
+        monkeypatch.setattr(eng._caches, "read", counted_read)
+        for rid, a in asked.items():
+            eng.submit(rid, *a)
+        events = []
+        for _ in range(3):
+            events.extend(eng.step())
+        # call 1 admits and dispatches step 1; call 2 admits the second
+        # request, dispatches step 2 and reads step 1; call 3 is the
+        # steady state: step 3 goes out, then step 2 is read
+        assert calls == ["decode", "decode", "read", "decode", "read"]
+        events.extend(eng.drain())
+        reads = [i for i, c in enumerate(calls) if c == "read"]
+        for n, at in enumerate(reads[:-1], 1):
+            assert calls[:at].count("decode") == n + 1, n
+        assert calls[:reads[-1]].count("decode") == len(reads)  # the last
+        decodes = [s.attrs for s in spans if s.name == "decode"]
+        assert [d["ahead"] for d in decodes] == [0] + [1] * (
+            len(decodes) - 1)
+        got = _lookahead.tokens_of(events)
+        assert got == {"stops": want["stops"][:n_stops],
+                       "goes_on": want["goes_on"]}
+        # "stops" made its first token in its prefill and its k-th in
+        # step k - 1: the eos in step n_stops - 1.  The step behind that
+        # one computed its slot once more; the one after leaves it out
+        discarded = [s.attrs["discarded"] for s in spans
+                     if s.name == "decode_read"]
+        assert len(discarded) == len(decodes)
+        assert discarded == [int(i == n_stops - 1)
+                             for i in range(len(discarded))]
+        assert [d["batch"] for d in decodes[n_stops - 2:n_stops + 1]] \
+            == [2, 2, 1]
+
+    def test_nothing_compiles_after_two_token_warm_ups(self,
+                                                       model_and_params):
+        """Warmed the benchmark's way (two-token requests through
+        ``submit``/``drain``, one a prefill bucket and page count), a
+        longer run over the same buckets and page counts, with
+        admissions, completions and steps in flight mixed, adds no entry
+        to the cache of any of the engine's jitted programs."""
+        eng = make_eos_engine(model_and_params, None)
+        asked = dict(MIXED, stops=(MIXED["stops"][0], 12))
+        lens = {rid: len(p) for rid, (p, _) in asked.items()}
+        pages = sorted({(lens[rid] + n - 1) // 4
+                        for rid, (_, n) in asked.items()})
+        warm = [4 * f - 1 for f in pages if f >= 1]
+        warm += [b for b in sorted({eng._prefill_bucket(n)
+                                    for n in lens.values()})
+                 if b not in {eng._prefill_bucket(n) for n in warm}]
+        for i, n in enumerate(warm):
+            # (no prompt of the run starts as a warm-up's does: no hit)
+            eng.submit(f"warm{i}",
+                       [63] + [(7 * i + j) % 60 + 1 for j in range(n - 1)], 2)
+        assert len(_lookahead.tokens_of(eng.drain())) == len(warm)
+        programs = (eng._decode_j, eng._prefill_j, eng._restore_j)
+        before = [p._cache_size() for p in programs]
+        assert before[0] == 1
+        events, _ = _lookahead.mixed_run(eng, asked)
+        assert len(_lookahead.tokens_of(events)) == 4
+        assert [p._cache_size() for p in programs] == before
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_random_schedules_decode_what_the_reference_decodes(
+            self, model_and_params, seed):
+        """Arrivals, cancellations and width changes at random calls,
+        with an ``eos_id`` the model makes often: every request that was
+        not cancelled returns the reference's tokens up to its end, and
+        no page stays held."""
+        model, params = model_and_params
+        rng = np.random.default_rng(seed)
+        eos = 29
+        eng = make_eos_engine(model_and_params, eos)
+        asked = {f"r{i}": (rng.integers(1, 60, rng.integers(1, 21)).tolist(),
+                           int(rng.integers(1, 17))) for i in range(12)}
+        waiting, cancelled, events = list(asked), set(), []
+        while waiting or eng.pending_count or eng.active_count:
+            if waiting and rng.random() < 0.5:
+                rid = waiting.pop(0)
+                eng.submit(rid, *asked[rid])
+            if rng.random() < 0.1:
+                live = sorted(r.rid for r in eng._active.values())
+                if live:
+                    victim = live[rng.integers(len(live))]
+                    assert eng.cancel(victim)
+                    cancelled.add(victim)
+            if rng.random() < 0.15:
+                eng.set_width(int(rng.integers(1, 4)))
+            events.extend(eng.step())
+        got = _lookahead.tokens_of(events)
+        assert set(got) == set(asked) - cancelled and len(got) >= 6
+        for rid, toks in got.items():
+            want = reference_tokens(model, params, *asked[rid])
+            assert toks == _lookahead.until_eos(want, eos), rid
+        assert eng.pool.stats()["live"] == 0 and eng._flight is None
+
+    def test_drain_reads_the_step_behind_an_eos_ending(self, model_and_params,
+                                                       monkeypatch):
+        """The only request ends on ``eos_id`` with a step in flight that
+        holds nobody's row but its own: ``drain()`` reads that step too
+        and leaves none behind."""
+        model, params = model_and_params
+        asked = {"stops": MIXED["stops"]}
+        want = {"stops": reference_tokens(model, params, *asked["stops"])}
+        eos = _lookahead.pick_eos(want, asked)
+        eng = make_eos_engine(model_and_params, eos)
+        spans = _lookahead.record_spans(monkeypatch)
+        eng.submit("stops", *asked["stops"])
+        got = _lookahead.tokens_of(eng.drain())
+        assert got == {"stops": _lookahead.until_eos(want["stops"], eos)}
+        assert eng._flight is None and eng.pool.stats()["live"] == 0
+        reads = [s.attrs["discarded"] for s in spans
+                 if s.name == "decode_read"]
+        assert reads[-1] == 1 and not any(reads[:-1])
+        assert len(reads) == len([s for s in spans if s.name == "decode"])
+
+    def test_cancelling_everything_leaves_nothing_behind(self,
+                                                         model_and_params):
+        """A step whose every row was cancelled is read and dropped; the
+        engine is idle and serves the next request from the same slots."""
+        model, params = model_and_params
+        eng = make_eos_engine(model_and_params, None)
+        for rid in ("by_n", "stops"):
+            eng.submit(rid, *MIXED[rid])
+        for _ in range(4):
+            eng.step()
+        assert len(eng._flight.rows) == 2
+        assert eng.cancel("by_n") and eng.cancel("stops")
+        assert eng.active_count == 2        # the step in flight counts
+        assert eng.drain() == [] and eng.active_count == 0
+        assert eng.pool.stats()["live"] == 0
+        eng.submit("after", *MIXED["next"])
+        done = _lookahead.tokens_of(eng.drain())
+        assert done["after"] == reference_tokens(model, params,
+                                                 *MIXED["next"])
 
 
 # -- chaos request-path clauses --------------------------------------------

@@ -223,7 +223,7 @@ def test_train_step_carries_every_scope(train_step):
 def test_decode_and_prefill_carry_every_scope(engine):
     z = jnp.zeros(engine.max_batch, jnp.int32)
     found = scopes_in(engine._decode_j.lower(
-        engine.params, engine._k, engine._v, z, z))
+        engine.params, engine._k, engine._v, z, z, z))
     assert found >= set(MODEL_SCOPES) | {"kv_write"}
     found = scopes_in(engine._prefill_j.lower(
         engine.params, engine._k, engine._v, jnp.zeros(16, jnp.int32),

@@ -248,7 +248,8 @@ def serve_programs(topo):
     pages = shaped((cfg.n_layers, cfg.n_heads, 256, cfg.head_dim), bf16)
     slots, i0 = shaped((SERVE_SLOTS,), i32), shaped((), i32)
     lowered = {
-        "decode": eng._decode_j.lower(params, slab, slab, slots, slots),
+        "decode": eng._decode_j.lower(params, slab, slab, slots, slots,
+                                     slots),
         "prefill": eng._prefill_j.lower(params, slab, slab,
                                         shaped((256,), i32), i0, i0, i0),
         "restore": eng._restore_j.lower(slab, slab, pages, pages, i0),
@@ -327,8 +328,8 @@ def moe_programs(topo):
         (3, MOE_SLOTS, 8, MOE_RING, 128), (1, MOE_SLOTS, 8, MOE_SEQ, 128)]
     pages = tuple(shaped(p.shape, bf16) for p in eng._caches.empty_pages(256))
     slots, i0 = shaped((MOE_SLOTS,), i32), shaped((), i32)
-    lowered = {"decode": eng._decode_j.lower(params, slab, slab, slots,
-                                             slots),
+    lowered = {"decode": eng._decode_j.lower(
+        params, slab, slab, shaped((MOE_SLOTS + 3,), i32), slots, slots),
                "restore": eng._restore_j.lower(slab, slab, pages, pages, i0)}
     for n in MOE_PREFILL:
         lowered[f"prefill{n}"] = eng._prefill_j.lower(
