@@ -20,6 +20,9 @@ models because they are its benchmark workload:
   (window and full attention layers, grouped-query heads, a sparse
   expert layer beside the attention), served by ``serve/engine.py``;
   :mod:`kungfu_tpu.models.experts` is its expert layer.
+* :mod:`kungfu_tpu.models.pangu_moe` — the ``pangu_ultra_moe`` decoder
+  (latent attention in two orders, sandwich norms, a dense layer before
+  the expert layers, which are ``experts`` again), served likewise.
 * :mod:`kungfu_tpu.models.fake` — gradient-shaped fake models for
   collective benchmarking without real compute (parity with
   ``tests/go/fakemodel``).
@@ -28,6 +31,7 @@ models because they are its benchmark workload:
 from kungfu_tpu.models import nn
 from kungfu_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
 from kungfu_tpu.models.mlp import MLP, mnist_slp
+from kungfu_tpu.models.pangu_moe import PanguMoe, PanguMoeConfig
 from kungfu_tpu.models.resnet import ResNet, resnet50
 from kungfu_tpu.models.transformer import Transformer, TransformerConfig, bert_base, gpt_small
 from kungfu_tpu.models.vgg import VGG, vgg16
@@ -38,6 +42,8 @@ __all__ = [
     "Cohere2Moe",
     "Cohere2MoeConfig",
     "MLP",
+    "PanguMoe",
+    "PanguMoeConfig",
     "mnist_slp",
     "ResNet",
     "resnet50",

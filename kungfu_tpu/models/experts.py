@@ -1,6 +1,7 @@
 """A sparse expert layer as one chip of an expert-parallel deployment
 holds it: sigmoid scores over every published expert, top-k with the
-chosen scores normalised, gated-SiLU experts, shared experts averaged,
+chosen scores normalised (and scaled, where the model has a
+``routed_scaling_factor``), gated-SiLU experts, shared experts averaged,
 no capacity and no dropped token.
 
 The layer is told which experts it holds (``held = (first, count)``, a
@@ -75,18 +76,22 @@ def _picks(idx, held: Tuple[int, int]):
 
 
 @jax.named_scope("moe_router")
-def route(p, x, top_k: int, held: Tuple[int, int], live=None):
+def route(p, x, top_k: int, held: Tuple[int, int], live=None,
+          scale: float = 1.0):
     """x ``[T, d]`` -> the ``top_k`` experts of every token ``[T, k]``,
     their weights ``[T, k]`` float32, and the tokens each held expert
     received ``[count]`` int32 (rows not ``live`` are counted nowhere).
 
     ``s = sigmoid(x Wr)`` in float32 at full precision -- a choice is
     discrete, and a product rounded to bfloat16 flips near-ties -- then
-    the k largest, each divided by the sum of the k."""
+    the k largest, each divided by the sum of the k and multiplied by
+    ``scale``."""
     s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), p["w"],
                                   precision=HIGHEST))
     w, idx = jax.lax.top_k(s, top_k)
     w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scale != 1.0:
+        w = w * scale
     picked = _picks(idx, held)
     if live is not None:
         picked = picked & live[:, None, None]
@@ -150,15 +155,16 @@ def shared_mean(p, x):
 
 
 def apply(p, x, *, top_k: int, held: Tuple[int, int], dense: bool,
-          live=None):
+          live=None, scale: float = 1.0):
     """x ``[T, d]`` -> (``routed + shared`` float32 ``[T, d]``, tokens
     per held expert ``[count]``).  ``dense`` picks the routed product's
-    form (above); ``live`` ``[T]`` bool says which rows count.  The
+    form (above); ``live`` ``[T]`` bool says which rows count; ``scale``
+    multiplies the routed weights (``route``), not the shared part.  The
     sorted form takes :data:`SORTED_CHUNK` tokens at a time, so that
     what it sorts and what the experts make of it stay small beside the
     weights."""
     def layer(x, live):
-        idx, w, counts = route(p["router"], x, top_k, held, live)
+        idx, w, counts = route(p["router"], x, top_k, held, live, scale)
         product = experts_dense if dense else experts_sorted
         y = product(p["experts"], x, idx, w, held)
         return y + shared_mean(p["shared"], x), counts

@@ -14,7 +14,8 @@ plane:
   (jit-compiled prefill/decode steps, decode-priority admission) over
   whatever ``model.serve_caches`` answers: :mod:`kungfu_tpu.serve.caches`
   for the dense transformer, :mod:`kungfu_tpu.serve.windowed` for a
-  model that mixes window and full attention layers;
+  model that mixes window and full attention layers,
+  :mod:`kungfu_tpu.serve.latent` for latent (MLA) attention;
 * :mod:`kungfu_tpu.serve.router` — request router + admission policy
   (FCFS, bounded queue, typed overload rejection) speaking over the
   existing host channel / p2p handler machinery, with SLO-gated fault

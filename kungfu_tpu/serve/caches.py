@@ -2,12 +2,18 @@
 ``Transformer``'s answer.
 
 The engine is the scheduler: requests, slots, admission, pages, spans.
-Everything that depends on how a model lays its keys and values out it
-asks of one object, ``model.serve_caches(max_batch, max_seq)``:
+Everything that depends on what a model keeps of a position, and how it
+lays that out, it asks of one object, ``model.serve_caches(max_batch,
+max_seq)``.  What a position keeps comes in TWO PARTS, called ``k`` and
+``v`` after the dense model's: per-head keys and values of one shape
+there, and in ``serve/windowed.py``; in ``serve/latent.py`` one
+compressed row for all the heads and their shared rotary key, of
+different widths.  The engine never looks inside a part: it hands the
+pair on, commits a page of each and restores a page of each.
 
 ``new_slabs()``
-    the device cache as a pair ``(k, v)``, each an array or a tree of
-    arrays.  The engine only ever hands the pair on.
+    the device cache as the pair ``(k, v)``, each part an array or a
+    tree of arrays ``[layers, slots, heads, positions, width]``.
 ``prefill(params, k, v, ids, n, start, slot)`` -> ``(k, v, token)``
     the body of the prefill program: ``ids`` ``[P]`` (the prompt past
     ``start`` cached positions, zero-padded past ``n``) into ``slot``;
@@ -28,26 +34,34 @@ asks of one object, ``model.serve_caches(max_batch, max_seq)``:
     on the host: fetch a decode step's ``out`` (the host waits here), the
     slots' tokens and what the step has to say of itself besides, as
     attrs of the ``kf:serve.decode_read`` span that is open meanwhile.
-``empty_pages(rows)``, ``pages_to_slot(data, n_cached, rows, page_tokens)``
+``empty_pages(rows)`` -> ``(ks, vs)``
     what the restore program writes into a slot for ``rows`` cached
-    positions (a tree shaped like ``k``, without the slot axis): zeros,
-    and the K (or V) of a cached prefix's pages ``[L, H, T, D]`` in order.
+    positions that hold nothing, one for each part (each a tree shaped
+    like its part of the slabs, without the slot axis): zeros.
+``pages_to_slot(data, n_cached, rows, page_tokens)``
+    the same for ONE part of a cached prefix: that part of its pages,
+    ``[L, H, T, W]`` each and in order, as what the restore program
+    writes.
 ``rows_of_slot(slab, slot, lo, hi, total)`` -> ``(rows, kept_from)``
-    positions ``[lo, hi)`` of a finished request of ``total`` tokens as
-    page data ``[L, H, hi - lo, D]``, and the first position all of
-    whose layers' rows still exist (pages before it are not ``whole``:
-    ``KVCachePool.reusable``).
+    positions ``[lo, hi)`` of one part of a finished request of
+    ``total`` tokens as page data ``[L, H, hi - lo, W]``, and the first
+    position all of whose layers' rows still exist (pages before it are
+    not ``whole``: ``KVCachePool.reusable``).
 ``prefill_flops(tokens, start)``, ``decode_flops(contexts)``
     the analytic cost of a prefill and of a decode step over its live
     contexts, for the serving MFU gauge.
 
 Both bodies take the slabs donated and write them in place; the restore
 program is the engine's own (a ``dynamic_update_slice`` into every leaf).
-:class:`DenseCaches` is the dense ``Transformer``'s (one slab ``[L, B, H,
-S, D]`` for K and one for V, every layer keeping every position);
-``serve/windowed.py`` the one of a model that mixes window and full
-attention layers.  :func:`row_windows` and :func:`write_rows`, the
-in-place write of one row a slot, are shared by both.
+``serve.kvcache.PageSpec`` counts a page's bytes from the two parts'
+widths.  :class:`DenseCaches` is the dense ``Transformer``'s (one slab
+``[L, B, H, S, D]`` for K and one for V, every layer keeping every
+position); ``serve/windowed.py`` the one of a model that mixes window and
+full attention layers; ``serve/latent.py`` the one of latent attention.
+:func:`row_windows` and :func:`write_rows`, the in-place write of one row
+a slot, are shared by all three, :func:`pages_in_order` and
+:func:`slot_rows`, the host's side of a part that keeps every position,
+by the first and the last.
 """
 
 from __future__ import annotations
@@ -100,6 +114,22 @@ def write_rows(slab, li, new, windows):
                             old), at,
             allow_negative_indices=False)
     return slab
+
+
+def pages_in_order(data, rows: int, page_tokens: int):
+    """One part of a cached prefix's pages, ``[L, H, T, W]`` each and in
+    order, as ``[L, H, rows, W]``: zeros past the last page."""
+    first, t = data[0], page_tokens
+    out = np.zeros(first.shape[:2] + (rows,) + first.shape[3:], first.dtype)
+    for i, page in enumerate(data):
+        out[:, :, i * t:(i + 1) * t] = page
+    return out
+
+
+def slot_rows(slab, slot: int, lo: int, hi: int):
+    """Positions ``[lo, hi)`` of ``slot`` of a slab ``[L, B, H, S, W]``
+    on the host, ``[L, H, hi - lo, W]``."""
+    return np.asarray(jax.device_get(slab[:, slot, :, lo:hi, :]))
 
 
 class DenseCaches:
@@ -256,18 +286,17 @@ class DenseCaches:
     # -- the host's side of a page ---------------------------------------
     def empty_pages(self, rows: int):
         cfg = self.cfg
-        return np.zeros((cfg.n_layers, cfg.n_heads, rows, cfg.head_dim),
+        part = np.zeros((cfg.n_layers, cfg.n_heads, rows, cfg.head_dim),
                         cfg.compute_dtype)
+        return part, part
 
-    def pages_to_slot(self, data, n_cached: int, rows: int, page_tokens: int):
-        out, t = self.empty_pages(rows), page_tokens
-        for i, page in enumerate(data):
-            out[:, :, i * t:(i + 1) * t] = page
-        return out
+    @staticmethod
+    def pages_to_slot(data, n_cached: int, rows: int, page_tokens: int):
+        return pages_in_order(data, rows, page_tokens)
 
     @staticmethod
     def rows_of_slot(slab, slot: int, lo: int, hi: int, total: int):
-        return np.asarray(jax.device_get(slab[:, slot, :, lo:hi, :])), 0
+        return slot_rows(slab, slot, lo, hi), 0
 
     # -- what a forward pass costs (the serving MFU gauge) ---------------
     def prefill_flops(self, tokens: int, start: int = 0) -> int:

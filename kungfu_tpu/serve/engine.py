@@ -53,8 +53,10 @@ decode programs, the host's side of a page, a step's cost -- it asks of
 out in ``serve/caches.py``): the dense ``Transformer`` answers with one
 slab for K and one for V (``caches.DenseCaches``), a model that mixes
 window and full attention layers with a ring and a full-length slab for
-each (``serve/windowed.py``).  Scheduler, slots, pool and spans are the
-same for both.
+each (``serve/windowed.py``), latent attention with one compressed row
+for all heads and their shared rotary key (``serve/latent.py``: there
+``_k`` and ``_v`` differ in width).  Scheduler, slots, pool and spans
+are the same for all three.
 
 Fault surface: the engine is process-local and carries no collective
 state — worker death is handled ABOVE it by the router's replay ladder
@@ -258,9 +260,9 @@ class InferenceEngine:
             # the slabs are donated, so each call's pair replaces the
             # engine's; what the calls write (zeros and one row of slot 0,
             # before any request) nobody reads
-            pages = self._caches.empty_pages(s_pad)
-            self._k, self._v = self._restore_j(self._k, self._v, pages,
-                                               pages, jnp.int32(0))
+            ks, vs = self._caches.empty_pages(s_pad)
+            self._k, self._v = self._restore_j(self._k, self._v, ks, vs,
+                                               jnp.int32(0))
             self._k, self._v, tok = self._prefill_j(
                 self.params, self._k, self._v, jnp.zeros(s_pad, jnp.int32),
                 jnp.int32(1), jnp.int32(0), jnp.int32(0))

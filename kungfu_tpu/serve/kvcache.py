@@ -7,8 +7,12 @@ stdlib, no jax, no sockets) so its invariants are unit-testable the way
 slab; this pool owns the *host-side* pages — capacity accounting,
 prefix-reuse bookkeeping, and the replay source of truth.
 
-Model: a page holds ``page_tokens`` consecutive tokens' K and V for
-every layer (``[n_layers, n_heads, page_tokens, head_dim]`` each).  A
+Model: a page holds what ``page_tokens`` consecutive positions keep for
+every layer, in two parts: K and V, ``[n_layers, n_heads, page_tokens,
+head_dim]`` each -- or, for latent attention, ONE compressed row for all
+the heads and their shared rotary key, ``[n_layers, 1, page_tokens,
+kv_lora_rank]`` and ``[n_layers, 1, page_tokens, qk_rope_dim]``
+(``PageSpec.v_head_dim``: the second part's width where it differs).  A
 request reserves ``ceil(total_tokens / page_tokens)`` pages at
 admission — admission control is capacity-real, not optimistic — and
 releases them at completion.  Completed *full* pages are committed
@@ -70,13 +74,17 @@ class CacheExhausted(RuntimeError):
 
 @dataclass(frozen=True)
 class PageSpec:
-    """Geometry of one page: K+V for every layer of a model."""
+    """Geometry of one page: the two parts a position keeps, for every
+    layer of a model."""
 
     n_layers: int
     n_heads: int
     head_dim: int
     page_tokens: int
     dtype: str = "float32"
+    #: width of the second part where it is not the first's (0: K and V
+    #: alike; a latent cache keeps ``c_kv`` and a narrower ``k_r``)
+    v_head_dim: int = 0
     #: positions a window layer keeps (0: every layer keeps every one).
     #: A page committed once a request's window layers had moved past it
     #: is not ``whole``, and a prefix can be reused only if the pages
@@ -84,26 +92,39 @@ class PageSpec:
     window: int = 0
 
     @property
+    def widths(self) -> Tuple[int, int]:
+        """The last axis of a page's two parts."""
+        return self.head_dim, self.v_head_dim or self.head_dim
+
+    def part_shape(self, part: int) -> Tuple[int, int, int, int]:
+        """``[n_layers, n_heads, page_tokens, width]`` of part 0 or 1."""
+        return (self.n_layers, self.n_heads, self.page_tokens,
+                self.widths[part])
+
+    @property
     def page_bytes(self) -> int:
-        # K and V, all layers, page_tokens rows of [n_heads, head_dim]
-        return (2 * self.n_layers * self.n_heads * self.page_tokens
-                * self.head_dim * np.dtype(self.dtype).itemsize)
+        # both parts, all layers, page_tokens rows of [n_heads, width]
+        return (self.n_layers * self.n_heads * self.page_tokens
+                * sum(self.widths) * np.dtype(self.dtype).itemsize)
 
     @classmethod
     def for_model(cls, cfg, page_tokens: Optional[int] = None,
                   dtype: Optional[str] = None) -> "PageSpec":
-        """Spec from a model's config (``TransformerConfig``, or one with
-        fewer key/value heads than query heads and window layers:
-        ``Cohere2MoeConfig``); ``page_tokens`` defaults from the
-        ``KF_SERVE_PAGE_TOKENS`` env."""
+        """Spec from a model's config: ``TransformerConfig``; one with
+        fewer key/value heads than query heads and window layers
+        (``Cohere2MoeConfig``); or one that says itself what a position
+        keeps, ``cache_row = (heads, first width, second width)`` (a
+        latent cache: ``PanguMoeConfig``).  ``page_tokens`` defaults from
+        the ``KF_SERVE_PAGE_TOKENS`` env."""
         if page_tokens is None:
             page_tokens = envs.parse_int_env(envs.SERVE_PAGE_TOKENS,
                                              DEFAULT_PAGE_TOKENS)
-        return cls(n_layers=cfg.n_layers,
-                   n_heads=getattr(cfg, "n_kv_heads", cfg.n_heads),
-                   head_dim=cfg.head_dim, page_tokens=int(page_tokens),
-                   dtype=dtype or cfg.dtype,
-                   window=getattr(cfg, "window", 0))
+        row = getattr(cfg, "cache_row", None)
+        heads, width, second = row or (
+            getattr(cfg, "n_kv_heads", cfg.n_heads), cfg.head_dim, 0)
+        return cls(n_layers=cfg.n_layers, n_heads=heads, head_dim=width,
+                   page_tokens=int(page_tokens), dtype=dtype or cfg.dtype,
+                   window=getattr(cfg, "window", 0), v_head_dim=second)
 
 
 def chain_hashes(tokens: Sequence[int], page_tokens: int) -> List[bytes]:
@@ -254,12 +275,12 @@ class KVCachePool:
     # -- page data -------------------------------------------------------
     def put_page_data(self, pid: int, k: np.ndarray, v: np.ndarray,
                       whole: bool = True) -> None:
-        """Fill a reserved page's host copy (``[L, H, T, D]`` each).
+        """Fill a reserved page's host copy (``spec.part_shape`` each).
         ``whole=False``: the window layers' part is not there any more."""
-        want = (self.spec.n_layers, self.spec.n_heads,
-                self.spec.page_tokens, self.spec.head_dim)
-        if tuple(k.shape) != want or tuple(v.shape) != want:
-            raise ValueError(f"page data shape {k.shape} != {want}")
+        want = self.spec.part_shape(0), self.spec.part_shape(1)
+        if (tuple(k.shape), tuple(v.shape)) != want:
+            raise ValueError(
+                f"page data shapes {k.shape}, {v.shape} != {want}")
         with self._lock:
             page = self._pages.get(pid)
             if page is None or page.refs <= 0:
@@ -382,8 +403,7 @@ class KVCachePool:
         requests' pages."""
         restored = rejected = 0
         pt = self.spec.page_tokens
-        shape = (self.spec.n_layers, self.spec.n_heads, pt,
-                 self.spec.head_dim)
+        shapes = self.spec.part_shape(0), self.spec.part_shape(1)
         idx = sorted(int(name[2:-2]) for name in snap
                      if name.startswith("kv") and name.endswith("_p")
                      and name[2:-2].isdigit())
@@ -394,8 +414,7 @@ class KVCachePool:
             want = snap.get(f"kv{j}_c")
             if (prefix is None or k is None or v is None or want is None
                     or len(prefix) == 0 or len(prefix) % pt
-                    or tuple(np.shape(k)) != shape
-                    or tuple(np.shape(v)) != shape):
+                    or (tuple(np.shape(k)), tuple(np.shape(v))) != shapes):
                 rejected += 1
                 continue
             k = np.ascontiguousarray(k, np.dtype(self.spec.dtype))

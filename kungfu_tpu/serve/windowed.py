@@ -226,19 +226,24 @@ class WindowedCaches:
         return (kw, kf), (vw, vf), tok.astype(jnp.int32)
 
     # -- the host's side of a page ---------------------------------------
-    def empty_pages(self, rows: int):
-        """What :meth:`pages_to_slot` gives for ``rows`` cached positions
-        that hold nothing (the engine's warm-up)."""
+    def _empty_part(self, rows: int):
         (lw, _, g, w, d), (lf, _, _, _, _) = self.shapes()
         dt = self.cfg.compute_dtype
         return (np.zeros((lw, g, w, d), dt), np.zeros((lf, g, rows, d), dt))
+
+    def empty_pages(self, rows: int):
+        """What :meth:`pages_to_slot` gives, for K and for V, for
+        ``rows`` cached positions that hold nothing (the engine's
+        warm-up)."""
+        part = self._empty_part(rows)
+        return part, part
 
     def pages_to_slot(self, data, n_cached: int, rows: int, page_tokens: int):
         """The K (or V) of a cached prefix's pages, ``[L, G, T, D]`` each
         in order, as what the restore program writes into a slot: the
         window layers' last ``W`` positions at their ring rows, the full
         layers' ``n_cached`` positions padded to ``rows``."""
-        ring, out_f = self.empty_pages(rows)
+        ring, out_f = self._empty_part(rows)
         w, t = self.ring, page_tokens
         wl, fl = list(self.cfg.window_layers), list(self.cfg.full_layers)
         for i, page in enumerate(data):

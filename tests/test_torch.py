@@ -199,9 +199,15 @@ class TestMultiEngine:
             w_true = torch.tensor([[1.0], [-2.0], [0.5], [3.0]])
             Y = X @ w_true
 
+            # same init on all ranks, built BEFORE the worker threads
+            # start (torch's seed is process-global: see above)
+            models = []
+            for _ in engines:
+                torch.manual_seed(1)
+                models.append(torch.nn.Linear(4, 1, bias=False))
+
             def worker(rank, e):
-                torch.manual_seed(1)  # same init on all ranks
-                m = torch.nn.Linear(4, 1, bias=False)
+                m = models[rank]
                 opt = SynchronousSGDOptimizer(
                     torch.optim.SGD(m.parameters(), lr=0.05), engine=e
                 )

@@ -326,7 +326,8 @@ def moe_programs(topo):
     slab = tuple(shaped(s, bf16) for s in eng._caches.shapes())
     assert [s.shape for s in slab] == [
         (3, MOE_SLOTS, 8, MOE_RING, 128), (1, MOE_SLOTS, 8, MOE_SEQ, 128)]
-    pages = tuple(shaped(p.shape, bf16) for p in eng._caches.empty_pages(256))
+    pages = tuple(shaped(p.shape, bf16)
+                  for p in eng._caches.empty_pages(256)[0])
     slots, i0 = shaped((MOE_SLOTS,), i32), shaped((), i32)
     lowered = {"decode": eng._decode_j.lower(
         params, slab, slab, shaped((MOE_SLOTS + 3,), i32), slots, slots),
@@ -413,3 +414,164 @@ def test_two_cache_programs_fit_beside_the_weights(moe_programs):
     assert stats["decode"].temp_size_in_bytes < 0.1e9
     assert stats["prefill256"].temp_size_in_bytes < 0.5e9
     assert stats["prefill8192"].temp_size_in_bytes < 2.0e9
+
+
+# -- and for the model with a latent cache ---------------------------------------
+#: openPangu-Ultra-MoE-718B as its cell serves it: a dense layer and four
+#: expert layers, 8 of 256 experts, an eighth of the vocabulary, 32 slots
+#: of 16,384 positions
+MLA_SLOTS, MLA_SEQ, MLA_LAYERS, MLA_HELD = 32, 16384, 5, 8
+MLA_PREFILL = (256, 16384)
+MLA_SAYS = 4        # what a step's ``out`` holds behind the tokens
+
+
+@pytest.fixture(scope="module")
+def mla_programs(topo):
+    """name -> compiled program of the engine serving ``pangu_moe`` at
+    the published widths, lowered from shapes alone."""
+    from kungfu_tpu.models.pangu_moe import PanguMoe, PanguMoeConfig
+    from kungfu_tpu.serve.engine import InferenceEngine
+    from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
+
+    cfg = PanguMoeConfig(vocab_size=19200, n_layers=MLA_LAYERS,
+                         init_layers=61, n_dense=1,
+                         experts_held=(0, MLA_HELD), max_seq=MLA_SEQ)
+    model = PanguMoe(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        lambda x: shaped(x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = PageSpec.for_model(cfg, page_tokens=256)
+    # 1,152 bytes a position a layer, against 81,920 of per-head K and V
+    assert spec.page_bytes == MLA_LAYERS * 256 * (512 + 64) * 2
+    eng = InferenceEngine(model, None, max_batch=MLA_SLOTS, max_seq=MLA_SEQ,
+                          pool=KVCachePool(spec, capacity_pages=1))
+    c, k_r = (shaped(s, bf16) for s in eng._caches.shapes())
+    assert (c.shape, k_r.shape) == (
+        (MLA_LAYERS, MLA_SLOTS, 1, MLA_SEQ, 512),
+        (MLA_LAYERS, MLA_SLOTS, 1, MLA_SEQ, 64))
+    ks, vs = (shaped(p.shape, bf16) for p in eng._caches.empty_pages(256))
+    slots, i0 = shaped((MLA_SLOTS,), i32), shaped((), i32)
+    lowered = {"decode": eng._decode_j.lower(
+        params, c, k_r, shaped((MLA_SLOTS + MLA_SAYS,), i32), slots, slots),
+               "restore": eng._restore_j.lower(c, k_r, ks, vs, i0)}
+    for n in MLA_PREFILL:
+        lowered[f"prefill{n}"] = eng._prefill_j.lower(
+            params, c, k_r, shaped((n,), i32), i0, i0, i0)
+    return {name: lo.compile() for name, lo in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["decode", "restore"]
+                         + [f"prefill{n}" for n in MLA_PREFILL])
+def test_latent_program_writes_its_slab_in_place(mla_programs, program):
+    """Both parts of the latent slab alias their outputs, and nothing but
+    the in-place updates produces an array the size of a part or of one
+    layer of one: no copy of the slab, no materialised layer, and in the
+    decode step no per-head key or value of a cached row -- ``[B, 128, S,
+    128]`` would be 17 GB a layer (the prefill forms them for ONE slot)."""
+    text = mla_programs[program].as_text()
+    assert len(re.findall(r"may-alias|must-alias",
+                          text.split("\n", 1)[0])) == 2
+    # a part, or one layer of one, with or without its axis of one head
+    slab_like = re.compile(rf"(^|,){MLA_SLOTS},(1,)?{MLA_SEQ},(512|64)$")
+    moved = []
+    for name, dtype, dims, op in _entry_ops(text):
+        in_place = "dynamic-update-slice" in name or "dynamic_update_slice" \
+            in name or op == "dynamic-update-slice" \
+            or _fused_root(text, name) == "dynamic-update-slice"
+        if slab_like.search(dims) and not in_place and op not in (
+                "parameter", "bitcast", "get-tuple-element", "tuple"):
+            moved.append((op, name, dtype, dims))
+        if program == "decode":     # the rows of a slot, expanded a head
+            assert not re.search(rf"128,({MLA_SEQ},128|128,{MLA_SEQ})$",
+                                 dims), (name, dims)
+    assert not moved
+
+
+def _fused_root(text, name):
+    """The operation at the root of the computation a fusion calls (the
+    16,384-row prefill joins the ``k_r`` projection with its write into
+    the slab: one fusion, named after the product, whose root is the
+    in-place update)."""
+    line = re.search(r"^\s*(?:ROOT )?%?" + re.escape(name)
+                     + r" = [^\n]*calls=%?([\w.\-]+)", text, re.M)
+    if not line:
+        return None
+    body = text[text.index("\n%" + line.group(1) + " "):]
+    root = re.search(r"^\s*ROOT [^\n]*?\]\S* ([\w\-]+)\(",
+                     body[:body.index("\n}")], re.M)
+    return root.group(1) if root else None
+
+
+def _op_name(text, name):
+    found = re.search(r"^\s*(?:ROOT )?%?" + re.escape(name)
+                      + r" = [^\n]*op_name=\"([^\"]*)\"", text, re.M)
+    return found.group(1) if found else ""
+
+
+def test_latent_decode_row_write_is_a_fused_window_update(mla_programs):
+    """The decode step writes a slot's latent row as in-place fusions over
+    the aligned window that holds it, one for each part a slot and layer
+    (``c_kv`` lies with its 512 values along the lanes, ``k_r`` with the
+    positions along them: two layouts, so two loops), never as a plain
+    ``dynamic-update-slice``."""
+    entry = mla_programs["decode"].as_text()
+    entry = entry[entry.index("\nENTRY"):]
+    assert not re.findall(r" dynamic-update-slice\(", entry)
+    fused = re.findall(r"^\s*%?[\w.\-]*dynamic-update-slice_fusion[\w.]* = ",
+                       entry, re.M)
+    assert len(fused) == 2 * MLA_LAYERS * MLA_SLOTS
+
+
+def test_latent_decode_has_the_same_shapes_whatever_is_live(mla_programs):
+    """No operation of the decode step follows the data (PERF.md, PR 26):
+    the attention is two products over the whole slab of every slot under
+    a mask (scores ``[32, 128, 16384]``, the weighted sum ``[32, 128,
+    512]``: the absorbed order), the routed product one batched product
+    over every held expert, and nothing loops or branches."""
+    text = mla_programs["decode"].as_text()
+    assert "ragged" not in text
+    entry = text[text.index("\nENTRY"):]
+    assert not re.findall(r"= [^\n]* (while|conditional)\(", entry)
+    ops = [(dims, _op_name(text, name)) for name, _, dims, op
+           in _entry_ops(text) if op in ("fusion", "convolution")]
+    attn = [(dims, path) for dims, path in ops
+            if "/attn_core/mla_latent_attn/" in path]
+    assert sum(dims == f"{MLA_SLOTS},128,{MLA_SEQ}" for dims, _ in attn) \
+        >= MLA_LAYERS
+    assert sum(dims == f"{MLA_SLOTS},128,512" and "bhs,bsc->bhc" in path
+               for dims, path in attn) == MLA_LAYERS
+    # every operation under the scope has one of those two shapes or is
+    # a reduction of the first: none is sized by a context
+    for dims, path in attn:
+        assert dims in (f"{MLA_SLOTS},128,{MLA_SEQ}", f"{MLA_SLOTS},128,512",
+                        f"{MLA_SLOTS},128"), (dims, path)
+    experts_w = re.findall(
+        r"%(params__layer_\d____moe____experts____(?:gate|up|down)__[.\d]*) = "
+        r"bf16\[8,(?:7680,2048|2048,7680)\]", entry)
+    assert len(experts_w) == (MLA_LAYERS - 1) * 3
+    # the absorption: W_uk goes into the query, W_uv into the output
+    assert sum("bhn,hnc->bhc" in path for _, path in ops) == MLA_LAYERS
+    assert sum("bhc,hcv->bhv" in path for _, path in ops) == MLA_LAYERS
+
+
+def test_latent_programs_fit_beside_the_weights(mla_programs):
+    """6.83 GB of weights and the 3.02 GB slab at its TRUE size are
+    arguments (32 x 16,384 x 5 x 576 x 2 bytes: the compiler pads neither
+    part -- it lays ``k_r``'s positions along the lanes); a decode step
+    adds the float32 scores of one layer (268 MB), the longest prefill
+    the expanded keys and values of one slot (1.07 GB), the stream twice
+    and one tile's scores."""
+    stats = {n: p.memory_analysis() for n, p in mla_programs.items()}
+    args = stats["decode"].argument_size_in_bytes
+    slab = MLA_LAYERS * MLA_SLOTS * MLA_SEQ * (512 + 64) * 2
+    assert slab == 3_019_898_880
+    assert stats["restore"].alias_size_in_bytes == slab
+    assert 9.85e9 < args < 9.86e9 and 6.83e9 < args - slab < 6.84e9
+    assert stats["decode"].temp_size_in_bytes < 0.3e9
+    assert stats["prefill256"].temp_size_in_bytes < 1.8e9
+    assert stats["prefill16384"].temp_size_in_bytes < 2.8e9
