@@ -28,7 +28,9 @@ cache's choice (:func:`expanded_attention`, :func:`absorbed_attention`):
   q_nope_h``, ``r`` wide) and ``W_uv`` into the output: scores and the
   weighted sum are taken over the latent rows themselves, and no key or
   value of a cached row is ever formed.  One query row a slot against a
-  whole slab: a decode step's.
+  whole slab: a decode step's -- on the TPU one fused kernel a layer
+  (``ops/pallas/latent_attention.py``), elsewhere and at sizes it does
+  not tile XLA's two products.
 
 The layer is written once, :func:`block`; WHERE the latent rows live is
 the cache object's matter, as in ``models/cohere2_moe.py``: ``cache.write(li,
@@ -197,22 +199,59 @@ def expanded_attention(q_nope, q_rope, k_nope, k_rope, v, q_pos, scale):
     return out.reshape((n_q,) + out.shape[2:])
 
 
-def absorbed_attention(ap, q_nope, q_rope, c_kv, k_rope, see, scale):
+def absorbed_tile(heads: int, seq: int, r: int, rope: int, dtype):
+    """The key tile with which a decode step's attention over a slab of
+    ``seq`` positions is ONE fused kernel a layer
+    (``ops/pallas/latent_attention.py``), or None where it is XLA's two
+    products: off the TPU, and for shapes the kernel does not tile.  One
+    choice, from the platform and the shapes, at trace time; the kernel's
+    package is imported here and by no module's import, so a process
+    that traces no such step never pays for it (PERF.md, PR 35)."""
+    if jax.default_backend() != "tpu":
+        return None
+    from kungfu_tpu.ops.pallas import latent_attention
+
+    return latent_attention.key_tile(seq, heads, r, rope, dtype)
+
+
+def absorbed_products(q_lat, q_rope, c, k_r, li, pos, scale):
+    """Scores, softmax and weighted sum of the absorbed order as XLA's two
+    products, the ``[B, H, S]`` float32 scores between them (arguments
+    and result as ``latent_attention.latent_attn``'s): what runs where
+    the kernel does not, and what the tests hold the kernel to."""
+    c_kv, k_rope = c[li][:, 0], k_r[li][:, 0]
+    see = (jnp.arange(c.shape[3]) <= pos[:, None])[:, None]     # [B, 1, S]
+    scores = (jnp.einsum("bhc,bsc->bhs", q_lat, c_kv,
+                         preferred_element_type=F32)
+              + jnp.einsum("bhr,bsr->bhs", q_rope, k_rope,
+                           preferred_element_type=F32)) * scale
+    probs = jax.nn.softmax(jnp.where(see, scores, -1e30), axis=-1)
+    return jnp.einsum("bhs,bsc->bhc", probs.astype(c_kv.dtype), c_kv)
+
+
+def absorbed_attention(ap, q_nope, q_rope, c, k_r, li, pos, scale):
     """The absorbed order, one query row a sequence: ``q_nope`` ``[B, H,
-    nope]``, ``q_rope`` ``[B, H, rope]`` over the latent rows ``c_kv``
-    ``[B, S, r]`` and ``k_rope`` ``[B, S, rope]`` themselves, ``see``
-    ``[B, 1, S]`` True = attend -> ``[B, H, v]``.  Every product has the
-    slab's whole shape whatever is live; the mask alone follows the
-    data."""
+    nope]``, ``q_rope`` ``[B, H, rope]`` over layer ``li`` of the latent
+    rows themselves, the slab's parts ``c`` ``[L, B, 1, S, r]`` and
+    ``k_r`` ``[L, B, 1, S, rope]`` handed over whole, each sequence up to
+    its position ``pos`` ``[B]`` -> ``[B, H, v]``.  Scores, softmax and
+    weighted sum are one kernel over the slab where
+    :func:`absorbed_tile` gives a tile (the scores never reach memory and
+    a latent row is read once), else :func:`absorbed_products`.  Either
+    has the slab's whole shape whatever is live; the mask alone follows
+    the data."""
     with jax.named_scope("attn_proj"), jax.named_scope("mla_proj"):
         q_lat = jnp.einsum("bhn,hnc->bhc", q_nope, ap["w_uk"])
+    tile = absorbed_tile(q_lat.shape[1], c.shape[3], c.shape[-1],
+                         k_r.shape[-1], c.dtype)
     with jax.named_scope("attn_core"), jax.named_scope("mla_latent_attn"):
-        scores = (jnp.einsum("bhc,bsc->bhs", q_lat, c_kv,
-                             preferred_element_type=F32)
-                  + jnp.einsum("bhr,bsr->bhs", q_rope, k_rope,
-                               preferred_element_type=F32)) * scale
-        probs = jax.nn.softmax(jnp.where(see, scores, -1e30), axis=-1)
-        o_lat = jnp.einsum("bhs,bsc->bhc", probs.astype(c_kv.dtype), c_kv)
+        if tile:
+            from kungfu_tpu.ops.pallas.latent_attention import latent_attn
+
+            o_lat = latent_attn(q_lat, q_rope, c, k_r, li, pos, scale,
+                                tile=tile)
+        else:
+            o_lat = absorbed_products(q_lat, q_rope, c, k_r, li, pos, scale)
     with jax.named_scope("attn_proj"), jax.named_scope("mla_proj"):
         return jnp.einsum("bhc,hcv->bhv", o_lat, ap["w_uv"])
 

@@ -24,12 +24,19 @@ ONE ``block``; what differs is the order the attention is computed in:
 * the **decode** step writes one row a slot (in place, through the
   aligned-window read-select-write of ``caches.write_rows``, one loop
   for ``c`` and one for ``k_r``) and attends in the ABSORBED order over the slab
-  itself (``absorbed_attention``): no key or value of a cached row is
-  ever formed.  Every slot's every position is read under a mask, so no
-  operation's shape or time follows what is live (docs/serving.md).
+  itself (``absorbed_attention``, which is handed both parts whole and
+  the layer's index): no key or value of a cached row is ever formed.
+  On the TPU that is one fused kernel a layer
+  (``ops/pallas/latent_attention.py``, imported when the step is traced
+  and not before), elsewhere XLA's two products; ``read`` says which as
+  ``latent_attn_kernel``.  Every slot's every position is read under a
+  mask, so no operation's shape or time follows what is live
+  (docs/serving.md).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +88,6 @@ class LatentCaches:
         (:data:`_SAYS`): its routing over the live slots and the expert
         layers, and the latent rows of live contexts, a layer."""
         cfg, model = self.cfg, self.model
-        see = (jnp.arange(self.seq) <= pos[:, None])[:, None]    # [B, 1, S]
         at = row_windows(pos, self.seq, live)
 
         class Step:
@@ -96,8 +102,8 @@ class LatentCaches:
 
             def attend(_, li, ap, q_nope, q_rope, positions):
                 return arch.absorbed_attention(
-                    ap, q_nope[:, 0], q_rope[:, 0], c[li][:, 0],
-                    k_r[li][:, 0], see, cfg.score_scale)[:, None]
+                    ap, q_nope[:, 0], q_rope[:, 0], c, k_r, li, pos,
+                    cfg.score_scale)[:, None]
 
         h = model.embed(params, last_ids[:, None])
         counts = []
@@ -118,6 +124,17 @@ class LatentCaches:
     def new_out(self):
         return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
 
+    @functools.cached_property
+    def latent_attn_kernel(self) -> int:
+        """1 where a decode step's attention is the fused kernel, 0
+        where it is XLA's two products: ``absorbed_attention``'s own
+        choice, made from the platform and the slab's shape when the
+        step is traced."""
+        cfg = self.cfg
+        return int(arch.absorbed_tile(
+            cfg.n_heads, self.seq, cfg.kv_lora_rank, cfg.qk_rope_dim,
+            cfg.compute_dtype) is not None)
+
     def read(self, out):
         """A decode step's ``out`` on the host: the slots' tokens, and
         what it says of itself as attrs of the span that waits for them
@@ -128,6 +145,7 @@ class LatentCaches:
         # ``decode`` attends over every position of every slot under a
         # mask: a step that reads fewer rows has to say so here
         says["latent_rows_read"] = self.batch * self.seq
+        says["latent_attn_kernel"] = self.latent_attn_kernel
         says["expert_load_mean"] = says.pop("assigned") / self.held
         return out[:self.batch], says
 

@@ -172,10 +172,13 @@ def test_the_absorbed_order_equals_the_expanded_one(adapter, contexts):
     q_nope = jax.random.normal(ks[2], (b, heads, 8))
     q_rope = jax.random.normal(ks[3], (b, heads, ROPE))
     pos = jnp.asarray(contexts) - 1
-    see = (jnp.arange(MAX_SEQ) <= pos[:, None])[:, None]
     scale = model.cfg.score_scale
     assert scale == pytest.approx(1 / 12 ** 0.5)
-    got = arch.absorbed_attention(ap, q_nope, q_rope, c_kv, k_r, see, scale)
+    # (the slab's parts whole, as the decode step hands them: layer 1 of
+    # two, the other one zeros)
+    slab = lambda rows: jnp.stack([jnp.zeros_like(rows), rows])[:, :, None]
+    got = arch.absorbed_attention(ap, q_nope, q_rope, slab(c_kv), slab(k_r),
+                                  1, pos, scale)
     k_nope, v = arch.expand(ap, c_kv)
     assert k_nope.shape == v.shape == (b, heads, 8, MAX_SEQ)
     for i in range(b):
