@@ -1,0 +1,28 @@
+"""The KDA layers' share of their roofline in a decode step: the least
+time the chip could take for what the step HAS to move --
+``lib/solar_open2.py::decode_state_bytes`` over its memory bandwidth: the
+recurrent state and the convolution tail of every LIVE slot (the traced
+steps' mean ``state_slots_live``) read and written once, and the layers'
+weights read once -- against the device time under ``kda_state`` and
+``kda_proj`` together (the work is bound by bytes: a slot's state takes
+three operations a byte).  Counted so, a program that moves every slot's
+state reads low for it, passes over the state beyond one read and one
+write read low too, and a kernel that stops at the live slots does not
+make the count stale."""
+
+from kfbench.lib import decode_paths, solar_open2, spans
+
+
+def read(facts, entry):
+    took_ms = [decode_paths.scope_ms_per_run(facts, scope)
+               for scope in ("kda_state", "kda_proj")]
+    if not all(took_ms) or "peaks" not in facts:
+        return None
+    live = spans.mean(float(s.stats["state_slots_live"])
+                      for s in spans.of(facts).named("serve.decode_read")
+                      if "state_slots_live" in s.stats)
+    if live is None:
+        return None
+    least_s = solar_open2.decode_state_bytes(
+        facts["spec"]["config"], live) / facts["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * least_s / (sum(took_ms) / 1e3)

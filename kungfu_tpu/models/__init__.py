@@ -23,6 +23,10 @@ models because they are its benchmark workload:
 * :mod:`kungfu_tpu.models.pangu_moe` — the ``pangu_ultra_moe`` decoder
   (latent attention in two orders, sandwich norms, a dense layer before
   the expert layers, which are ``experts`` again), served likewise.
+* :mod:`kungfu_tpu.models.solar_open2` — the ``solar_open2`` decoder
+  (gated delta-rule linear-attention layers, whose past is a matrix a
+  head and not rows a position, beside a gated softmax layer in four;
+  ``experts`` again), served likewise.
 * :mod:`kungfu_tpu.models.fake` — gradient-shaped fake models for
   collective benchmarking without real compute (parity with
   ``tests/go/fakemodel``).
@@ -33,6 +37,7 @@ from kungfu_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
 from kungfu_tpu.models.mlp import MLP, mnist_slp
 from kungfu_tpu.models.pangu_moe import PanguMoe, PanguMoeConfig
 from kungfu_tpu.models.resnet import ResNet, resnet50
+from kungfu_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
 from kungfu_tpu.models.transformer import Transformer, TransformerConfig, bert_base, gpt_small
 from kungfu_tpu.models.vgg import VGG, vgg16
 from kungfu_tpu.models.fake import fake_model_sizes, fake_grads
@@ -47,6 +52,8 @@ __all__ = [
     "mnist_slp",
     "ResNet",
     "resnet50",
+    "SolarOpen2",
+    "SolarOpen2Config",
     "VGG",
     "vgg16",
     "Transformer",

@@ -2,31 +2,52 @@
 ``Transformer``'s answer.
 
 The engine is the scheduler: requests, slots, admission, pages, spans.
-Everything that depends on what a model keeps of a position, and how it
+Everything that depends on what a model keeps of a request, and how it
 lays that out, it asks of one object, ``model.serve_caches(max_batch,
-max_seq)``.  What a position keeps comes in TWO PARTS, called ``k`` and
-``v`` after the dense model's: per-head keys and values of one shape
-there, and in ``serve/windowed.py``; in ``serve/latent.py`` one
-compressed row for all the heads and their shared rotary key, of
-different widths.  The engine never looks inside a part: it hands the
-pair on, commits a page of each and restores a page of each.
+max_seq)``.  What is kept comes in TWO PARTS, called ``k`` and ``v``
+after the dense model's, each an array or a tree of arrays whose first
+two axes are ``[layers, slots]``.  The engine never looks inside a part:
+it hands the pair on, and writes into one slot of every array of it.  An
+array of a part is of one of two kinds:
+
+* **rows a position** ``[layers, slots, heads, positions, width]``:
+  per-head keys and values of one shape in the dense model and in
+  ``serve/windowed.py``; in ``serve/latent.py`` one compressed row for
+  all the heads and their shared rotary key, of different widths.  Rows
+  can be cut into pages of positions: a finished request's are fetched
+  and committed (``rows_of_slot``), a later request with the same prefix
+  gets them back (``pages_to_slot``) and prefills only what follows.
+* **a state a slot** ``[layers, slots, ...]`` with no axis of positions:
+  what a recurrent layer makes of ALL the positions so far
+  (``serve/recurrent.py``: a matrix a head and a short convolution's
+  last inputs).  It has one value, the newest: nothing in it is the
+  state at a page's end, so no page of positions can rebuild it.  A
+  model that has such a part says so to the pool (``PageSpec.recurrent``,
+  from the config's ``recurrent_layers``); its pages are then never
+  ``whole``, ``KVCachePool.reusable`` is false for them, and the engine
+  looks up no prefix, reserves no page and commits none: ``rows_of_slot``
+  and ``pages_to_slot`` are never asked of it.  What the cache owes
+  instead: a prefill from ``start == 0`` starts the slot's state from
+  nothing, whatever the slot held; a prefill of a padded bucket leaves
+  the state of exactly ``n`` tokens; a decode step leaves the state of a
+  slot it is not ``live`` for as it was.
 
 ``new_slabs()``
-    the device cache as the pair ``(k, v)``, each part an array or a
-    tree of arrays ``[layers, slots, heads, positions, width]``.
+    the device cache as the pair ``(k, v)``.
 ``prefill(params, k, v, ids, n, start, slot)`` -> ``(k, v, token)``
     the body of the prefill program: ``ids`` ``[P]`` (the prompt past
-    ``start`` cached positions, zero-padded past ``n``) into ``slot``;
-    the greedy token after row ``n - 1``.
+    the ``start`` positions the slot already holds, zero-padded past
+    ``n``) into ``slot``; the greedy token after row ``n - 1``.
 ``decode(params, k, v, last_ids, pos, live)`` -> ``(k, v, out)``
     the body of the decode program: one token for every slot.  ``live``
     ``[B]`` says which slots the step is for: the row of any other is
-    NOT written (the engine dispatches a step before it has read the one
-    before, so a slot it leaves out may hold a request that is finishing
-    and whose rows are yet to be committed: docs/serving.md).  ``out``
-    is ONE array whose first ``B`` entries are the slots' tokens (the
-    next step takes them from it on the device), so that the host's one
-    read brings all a step has to say; ``read(out)`` takes it apart.
+    NOT written, and its state not moved (the engine dispatches a step
+    before it has read the one before, so a slot it leaves out may hold a
+    request that is finishing and whose rows are yet to be committed:
+    docs/serving.md).  ``out`` is ONE array whose first ``B`` entries are
+    the slots' tokens (the next step takes them from it on the device),
+    so that the host's one read brings all a step has to say;
+    ``read(out)`` takes it apart.
 ``new_out()``
     what stands for a step's ``out`` before any step ran: zeros of its
     shape and dtype.
@@ -37,7 +58,9 @@ pair on, commits a page of each and restores a page of each.
 ``empty_pages(rows)`` -> ``(ks, vs)``
     what the restore program writes into a slot for ``rows`` cached
     positions that hold nothing, one for each part (each a tree shaped
-    like its part of the slabs, without the slot axis): zeros.
+    like its part of the slabs, without the slot axis): zeros -- of
+    ``rows`` positions for rows a position, an empty state for a state a
+    slot.
 ``pages_to_slot(data, n_cached, rows, page_tokens)``
     the same for ONE part of a cached prefix: that part of its pages,
     ``[L, H, T, W]`` each and in order, as what the restore program
@@ -52,16 +75,18 @@ pair on, commits a page of each and restores a page of each.
     contexts, for the serving MFU gauge.
 
 Both bodies take the slabs donated and write them in place; the restore
-program is the engine's own (a ``dynamic_update_slice`` into every leaf).
-``serve.kvcache.PageSpec`` counts a page's bytes from the two parts'
-widths.  :class:`DenseCaches` is the dense ``Transformer``'s (one slab
+program is the engine's own (a ``dynamic_update_slice`` into one slot of
+every leaf, whatever axes follow its slots).  ``serve.kvcache.PageSpec``
+counts a page's bytes from the two parts' widths over the layers that
+keep rows.  :class:`DenseCaches` is the dense ``Transformer``'s (one slab
 ``[L, B, H, S, D]`` for K and one for V, every layer keeping every
 position); ``serve/windowed.py`` the one of a model that mixes window and
-full attention layers; ``serve/latent.py`` the one of latent attention.
-:func:`row_windows` and :func:`write_rows`, the in-place write of one row
-a slot, are shared by all three, :func:`pages_in_order` and
-:func:`slot_rows`, the host's side of a part that keeps every position,
-by the first and the last.
+full attention layers; ``serve/latent.py`` the one of latent attention;
+``serve/recurrent.py`` the one of a model most of whose layers keep a
+state a slot.  :func:`row_windows` and :func:`write_rows`, the in-place
+write of one row a slot, are shared by all four, :func:`pages_in_order`
+and :func:`slot_rows`, the host's side of a part that keeps every
+position, by the first and the third.
 """
 
 from __future__ import annotations

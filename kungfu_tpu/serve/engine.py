@@ -55,8 +55,13 @@ slab for K and one for V (``caches.DenseCaches``), a model that mixes
 window and full attention layers with a ring and a full-length slab for
 each (``serve/windowed.py``), latent attention with one compressed row
 for all heads and their shared rotary key (``serve/latent.py``: there
-``_k`` and ``_v`` differ in width).  Scheduler, slots, pool and spans
-are the same for all three.
+``_k`` and ``_v`` differ in width), a model most of whose layers keep a
+recurrent state with a slab for the others beside a matrix and a
+convolution tail a slot (``serve/recurrent.py``: parts that are no rows
+a position at all, so nothing of a request can be put into a page; the
+pool's spec says so, ``PageSpec.recurrent``, and the engine then looks
+up no prefix, reserves no page and commits none).  Scheduler, slots,
+pool and spans are the same for all four.
 
 Fault surface: the engine is process-local and carries no collective
 state — worker death is handled ABOVE it by the router's replay ladder
@@ -161,6 +166,9 @@ class InferenceEngine:
         self.pool = pool if pool is not None else KVCachePool(
             PageSpec.for_model(cfg, page_tokens=page_tokens))
         self._page_tokens = self.pool.spec.page_tokens
+        # False where some layer keeps a state a slot: no page can hold a
+        # request's prefix, so none is looked up, reserved or committed
+        self._paged = not self.pool.spec.recurrent
         self._width = self.max_batch  # admitted width (policy-adjustable)
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)
@@ -222,12 +230,12 @@ class InferenceEngine:
     def _restore_fn(k_slab, v_slab, ks, vs, slot):
         """Cached pages ``ks``/``vs`` [L, H, R, D] back into positions
         ``[0, R)`` of ``slot`` (of every slab, where K and V are several:
-        a ring is restored whole)."""
-        at = (0, slot, 0, 0, 0)
-
+        a ring is restored whole, and so is a part that is a state a
+        slot, whatever axes follow its slots)."""
         def put(slab, rows):
             return jax.lax.dynamic_update_slice(
-                slab, rows[:, None], at, allow_negative_indices=False)
+                slab, rows[:, None], (0, slot) + (0,) * (slab.ndim - 2),
+                allow_negative_indices=False)
 
         return (jax.tree_util.tree_map(put, k_slab, ks),
                 jax.tree_util.tree_map(put, v_slab, vs))
@@ -342,7 +350,8 @@ class InferenceEngine:
         T = self._page_tokens
         budget = len(req.tokens) + req.max_new
         n_pages = -(-budget // T)
-        cached_pages, n_cached = self.pool.lookup(req.tokens)
+        cached_pages, n_cached = (self.pool.lookup(req.tokens)
+                                  if self._paged else ([], 0))
         # Give reuse back, a page at a time, until what is left can be
         # used.  At least one prompt token must run the forward -- the
         # last row's hidden state is where the first generated token
@@ -363,7 +372,8 @@ class InferenceEngine:
             self.pool.release([cached_pages.pop()])
             n_cached -= T
         try:
-            fresh = self.pool.alloc(n_pages - len(cached_pages))
+            fresh = self.pool.alloc(n_pages - len(cached_pages)
+                                    if self._paged else 0)
         except CacheExhausted:
             self.pool.release(cached_pages)
             return False
@@ -438,7 +448,7 @@ class InferenceEngine:
         full = (req.total_len - 1) // T
         first_new = req.reused // T
         committed = fetched = 0
-        if full > first_new and req.pages:
+        if full > first_new and req.pages:  # (no pages where not _paged)
             # (kept_from: the first position every layer still has)
             (kb, kept_from), (vb, _) = (
                 self._caches.rows_of_slot(slab, req.slot, first_new * T,

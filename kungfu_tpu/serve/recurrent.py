@@ -1,0 +1,282 @@
+"""Two kinds of cache in one manager, for a model most of whose layers
+keep a recurrent state (``models/solar_open2.py``): the engine's two
+parts are each a pair,
+
+* ``k`` = (``k_rows`` ``[Lg, B, G, max_seq, D]``, ``state``: ``Lk``
+  arrays ``[1, B, H, K, V]`` float32),
+* ``v`` = (``v_rows`` ``[Lg, B, G, max_seq, D]``, ``tails``: ``Lk``
+  arrays ``[1, B, taps - 1, 3 H K]``),
+
+the rows those of the ``Lg`` softmax layers (every position of every
+one, as ``serve/windowed.py`` keeps its full layers; they see no
+positions, so a row needs none to be read), ``state`` and ``tails`` what
+the ``Lk`` KDA layers keep of a request however long it is: a matrix a
+head (4.19 MB a slot and layer at 64 heads of 128 x 128) and the short
+convolution's last inputs.  Neither has an axis of positions: it is a
+part that is *a state a slot* (``serve/caches.py``).  Each is an
+array a layer and not one for all of them: a decode step reads a layer's
+matrices, works out the correction and writes them back where they were,
+and out of one array for all the layers the TPU compiler first copies the
+layer it is about to update (537 MB a layer and step, seen in the
+compiled step; an array of its own it updates where it lies).
+
+:class:`HybridCaches` is what ``InferenceEngine`` asks of such a model.
+Both bodies drive the model's ONE ``block``:
+
+* the **prefill** writes a softmax layer's rows into the slab and
+  attends over the slot's; a KDA layer's convolution and recurrence
+  continue from the slot's own tails and state where ``start > 0`` and
+  from nothing where it is 0 -- that is how a reused slot forgets the
+  request before -- in the chunked form, the bucket's padding past ``n``
+  masked so that what is written back is the state of exactly ``n``
+  tokens and the tail at ``n - taps + 1 .. n - 1``;
+* the **decode** step writes one row a slot (``caches.write_rows``) and
+  updates every slot's state in place (``delta_rule.kda_step``); a slot
+  the step is not ``live`` for keeps its state and its tail (the loop
+  runs one step ahead, so such a slot may hold a request that has just
+  ended, or nothing).  Every slot is processed every step: no
+  operation's shape or time follows what is live (docs/serving.md).
+
+Pages: a KDA layer keeps nothing at a page's end that a later request
+could start from, so no page of this family is ever ``whole``
+(``PageSpec.recurrent``): the engine looks up no prefix, commits
+nothing, and never asks this cache for ``rows_of_slot`` or
+``pages_to_slot``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from kungfu_tpu.models import cohere2_moe, solar_open2 as arch
+from kungfu_tpu.ops import delta_rule
+from kungfu_tpu.serve.caches import row_windows, write_rows
+
+F32 = jnp.float32
+#: of the KDA layers' matrices (the published layer keeps them so; the
+#: convolution tails are in the compute dtype).  Both bodies compute in
+#: float32 and store in whatever the state they are handed is
+STATE_DTYPE = jnp.dtype("float32")
+#: what a decode step's ``out`` says behind the slots' tokens
+_SAYS = ("experts_touched", "expert_load_max", "assigned",
+         "state_slots_live")
+
+
+def _of_slot(part, li, slot):
+    """Layer ``li``, slot ``slot`` of a part, without those two axes, by
+    one dynamic slice (taking the layer first would materialise its
+    slots)."""
+    return jax.lax.dynamic_slice(
+        part, (li, slot) + (0,) * (part.ndim - 2), (1, 1) + part.shape[2:],
+        allow_negative_indices=False)[0, 0]
+
+
+def _to_slot(part, li, slot, new):
+    return jax.lax.dynamic_update_slice(
+        part, new[None, None].astype(part.dtype),
+        (li, slot) + (0,) * (part.ndim - 2), allow_negative_indices=False)
+
+
+class HybridCaches:
+    def __init__(self, model: arch.SolarOpen2, max_batch: int, max_seq: int):
+        self.model = model
+        cfg = self.cfg = model.cfg
+        self.batch, self.seq = int(max_batch), int(max_seq)
+        #: a layer's place in its kind's parts
+        self.place = {li: i for group in (cfg.gqa_layers,
+                                          cfg.recurrent_layers)
+                      for i, li in enumerate(group)}
+        #: the experts a decode step's routing is counted over
+        self.held = cfg.n_layers * cfg.experts_held[1]
+        self.prefill_flops = model.prefill_flops
+        self.decode_flops = model.decode_flops
+        #: what the KDA layers keep for all the slots: the bytes a decode
+        #: step reads (and writes back) whatever is live
+        _, state, tails = self.shapes()
+        self.state_bytes = len(cfg.recurrent_layers) * int(
+            np.prod(state) * STATE_DTYPE.itemsize
+            + np.prod(tails) * cfg.compute_dtype.itemsize)
+
+    # -- the parts ---------------------------------------------------------
+    def shapes(self):
+        """(rows of K or of V, ONE layer's state, ONE layer's tails)."""
+        cfg = self.cfg
+        hd = cfg.kda_head_dim
+        return ((len(cfg.gqa_layers), self.batch, cfg.n_kv_heads, self.seq,
+                 cfg.head_dim),
+                (1, self.batch, cfg.kda_heads, hd, hd),
+                (1, self.batch, cfg.conv_kernel - 1, 3 * cfg.kda_width))
+
+    def new_slabs(self):
+        cfg = self.cfg
+        rows, state, tails = self.shapes()
+        dt = cfg.compute_dtype
+        a_layer = lambda shape, dtype: tuple(
+            jnp.zeros(shape, dtype) for _ in cfg.recurrent_layers)
+        return ((jnp.zeros(rows, dt), a_layer(state, STATE_DTYPE)),
+                (jnp.zeros(rows, dt), a_layer(tails, dt)))
+
+    # -- the two forward passes ------------------------------------------
+    def decode(self, params, k, v, last_ids, pos, live):
+        """One token for every slot (``last_ids``/``pos``/``live``
+        ``[B]``; a slot that is not live computes what nobody reads,
+        writes no row, keeps its state and tail, and is counted
+        nowhere).  Returns the parts and ONE int32 vector: the ``B``
+        tokens, then what the step says of itself (:data:`_SAYS`): its
+        routing over the live slots and all layers, and the slots whose
+        state it moved."""
+        cfg, model = self.cfg, self.model
+        (kr, state), (vr, tails) = k, v
+        state, tails = list(state), list(tails)
+        see = (jnp.arange(self.seq) <= pos[:, None])[:, None, None, None]
+        at = row_windows(pos, self.seq, live)
+
+        class Step:
+            """A decode step's cache: one row a slot into the slab and
+            attention over the slab itself; one token into every slot's
+            state."""
+
+            def write(_, li, kn, vn):
+                nonlocal kr, vr
+                with jax.named_scope("kv_write"):
+                    kr = write_rows(kr, self.place[li], kn, at)
+                    vr = write_rows(vr, self.place[li], vn, at)
+
+            @jax.named_scope("attn_core")
+            def attend(_, li, q, positions):
+                i = self.place[li]
+                with jax.named_scope("attn_full"):
+                    return cohere2_moe.attention(q, kr[i], vr[i], see)
+
+            def convolve(_, li, u, w):
+                i = self.place[li]
+                seen = jnp.concatenate([tails[i][0], u], axis=1)  # [B, taps, C]
+                tails[i] = jnp.where(live[:, None, None], seen[:, 1:],
+                                     tails[i][0])[None]
+                return jnp.einsum("btc,tc->bc", seen.astype(F32),
+                                  w.astype(F32))[:, None]
+
+            @jax.named_scope("attn_core")
+            def recur(_, li, q, k, v, g, b):
+                i = self.place[li]
+                with jax.named_scope("kda_state"):
+                    new, o = delta_rule.kda_step(
+                        state[i][0], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                        b[:, 0], live)
+                    state[i] = new[None]
+                return o[:, None]
+
+        h = model.embed(params, last_ids[:, None])
+        counts = []
+        for li in range(cfg.n_layers):
+            h, n = arch.block(cfg, params[f"layer_{li}"], li, h,
+                              pos[:, None], Step(), dense=True,
+                              live=live[:, None])
+            counts.append(n)
+        tok = jnp.argmax(model.logits(params, h[:, 0]), axis=-1)
+        with jax.named_scope("moe_router"):
+            counts = jnp.stack(counts)
+            says = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
+                              jnp.sum(counts), jnp.sum(live)])
+        return ((kr, tuple(state)), (vr, tuple(tails)),
+                jnp.concatenate([tok, says]).astype(jnp.int32))
+
+    def new_out(self):
+        return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
+
+    def read(self, out):
+        """A decode step's ``out`` on the host: the slots' tokens, and
+        what it says of itself as attrs of the span that waits for them
+        (docs/tracing.md)."""
+        out = np.asarray(jax.device_get(out))
+        says = dict(zip(_SAYS, out[self.batch:].tolist()))
+        says["experts_held"] = self.held
+        says["expert_load_mean"] = says.pop("assigned") / self.held
+        # ``decode`` moves every slot's state whatever is live: a step
+        # that reads fewer has to say so here
+        says["state_slots_read"] = self.batch
+        says["state_bytes_read"] = self.state_bytes
+        return out[:self.batch], says
+
+    def prefill(self, params, k, v, ids, n, start, slot):
+        """``ids`` ``[P]`` (the prompt past ``start`` positions the slot
+        already holds, zero-padded past ``n``) into ``slot``: a softmax
+        layer's rows go to ``[start, start + P)`` of the slab (the
+        padding lands where the decode writes before anyone reads); a
+        KDA layer goes on from the slot's state and tail, or from
+        nothing where ``start`` is 0, and leaves those of ``start + n``
+        tokens.  Returns the greedy token after row ``n - 1``."""
+        cfg, model = self.cfg, self.model
+        (kr, state), (vr, tails) = k, v
+        state, tails = list(state), list(tails)
+        positions = start + jnp.arange(ids.shape[0])
+        goes_on = start > 0
+
+        class Prompt:
+            """A prefill's cache: the new rows go into the slab and the
+            slot's rows come out as keys; the state and the tail come
+            out of the slot, or start empty, and go back."""
+
+            def write(me, li, kn, vn):
+                nonlocal kr, vr
+                i = self.place[li]
+                with jax.named_scope("kv_write"):
+                    kr = jax.lax.dynamic_update_slice(
+                        kr, kn[None], (i, slot, 0, start, 0),
+                        allow_negative_indices=False)
+                    vr = jax.lax.dynamic_update_slice(
+                        vr, vn[None], (i, slot, 0, start, 0),
+                        allow_negative_indices=False)
+                me.keys = (_of_slot(kr, i, slot)[None],
+                           _of_slot(vr, i, slot)[None])
+
+            @jax.named_scope("attn_core")
+            def attend(me, li, q, positions):
+                with jax.named_scope("attn_full"):
+                    return cohere2_moe.blocked_attention(
+                        q, *me.keys, positions[0], 0, None)
+
+            def convolve(_, li, u, w):
+                i = self.place[li]
+                tail = jnp.where(goes_on, _of_slot(tails[i], 0, slot), 0)
+                y, tail = delta_rule.causal_conv(u[0], w, tail, n)
+                with jax.named_scope("kv_write"):
+                    tails[i] = _to_slot(tails[i], 0, slot, tail)
+                return y[None]
+
+            @jax.named_scope("attn_core")
+            def recur(_, li, q, k, v, g, b):
+                i = self.place[li]
+                with jax.named_scope("kda_chunk"):
+                    s0 = jnp.where(goes_on, _of_slot(state[i], 0, slot
+                                                     ).astype(F32), 0.0)
+                    o, s_n = delta_rule.kda_chunked(
+                        q[0], k[0], v[0], g[0], b[0], s0, n)
+                with jax.named_scope("kv_write"):
+                    state[i] = _to_slot(state[i], 0, slot, s_n)
+                return o[None]
+
+        h = model.embed(params, ids[None])
+        for li in range(cfg.n_layers):
+            h, _ = arch.block(cfg, params[f"layer_{li}"], li, h,
+                              positions[None], Prompt(), dense=False)
+        row = jax.lax.dynamic_index_in_dim(h, n - 1, axis=1, keepdims=False)
+        tok = jnp.argmax(model.logits(params, row)[0], axis=-1)
+        return (kr, tuple(state)), (vr, tuple(tails)), tok.astype(jnp.int32)
+
+    # -- the host's side of a page ---------------------------------------
+    def empty_pages(self, rows: int):
+        """What the restore program writes into a slot for ``rows``
+        positions that hold nothing: zero rows for the softmax layers
+        and, whatever ``rows`` is, an empty state and an empty tail."""
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        (lg, _, g, _, d), state, tails = self.shapes()
+        part = np.zeros((lg, g, rows, d), dt)
+        # (host arrays, only read: one for all the layers will do)
+        a_layer = lambda shape, dtype: (np.zeros(
+            shape[:1] + shape[2:], dtype),) * len(cfg.recurrent_layers)
+        return ((part, a_layer(state, STATE_DTYPE)),
+                (part, a_layer(tails, dt)))

@@ -610,3 +610,142 @@ def test_latent_programs_fit_beside_the_weights(mla_programs):
     assert stats["decode"].temp_size_in_bytes < 0.05e9
     assert stats["prefill256"].temp_size_in_bytes < 1.8e9
     assert stats["prefill16384"].temp_size_in_bytes < 2.8e9
+
+
+# -- and for the model that keeps a recurrent state a slot ------------------------
+#: Solar-Open2-250B as its cell serves it: one period of four layers (a
+#: softmax layer and three KDA layers), 10 of 320 experts, an eighth of
+#: the vocabulary, 128 slots of 4,096 positions
+KDA_SLOTS, KDA_SEQ, KDA_LAYERS, KDA_HELD = 128, 4096, 3, 10
+KDA_PREFILL = (256, 2048)
+KDA_SAYS = 4        # what a step's ``out`` holds behind the tokens
+KDA_STATE = f"{KDA_SLOTS},64,128,128"
+KDA_ROWS = f"{KDA_SLOTS},8,{KDA_SEQ},128"
+
+
+@pytest.fixture(scope="module")
+def kda_programs(topo):
+    """name -> compiled program of the engine serving ``solar_open2`` at
+    the published widths, lowered from shapes alone."""
+    from kungfu_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
+    from kungfu_tpu.serve.engine import InferenceEngine
+    from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
+
+    cfg = SolarOpen2Config(vocab_size=24576, n_layers=4, init_layers=48,
+                           gqa_layers=(0,), experts_held=(0, KDA_HELD),
+                           max_seq=KDA_SEQ)
+    model = SolarOpen2(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        shaped, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = PageSpec.for_model(cfg, page_tokens=256)
+    # a page counts the softmax layer's rows alone, and is never whole
+    assert spec.recurrent and spec.page_bytes == 256 * 8 * 2 * 128 * 2
+    eng = InferenceEngine(model, None, max_batch=KDA_SLOTS, max_seq=KDA_SEQ,
+                          pool=KVCachePool(spec, capacity_pages=1))
+    k, v = jax.tree_util.tree_map(shaped,
+                                  jax.eval_shape(eng._caches.new_slabs))
+    assert [x.shape for x in jax.tree_util.tree_leaves((k, v))] == [
+        (1, KDA_SLOTS, 8, KDA_SEQ, 128)] + [(1, KDA_SLOTS, 64, 128, 128)] * 3 \
+        + [(1, KDA_SLOTS, 8, KDA_SEQ, 128)] + [(1, KDA_SLOTS, 3, 24576)] * 3
+    ks, vs = jax.tree_util.tree_map(shaped, eng._caches.empty_pages(256))
+    slots = jax.ShapeDtypeStruct((KDA_SLOTS,), i32, sharding=one)
+    i0 = jax.ShapeDtypeStruct((), i32, sharding=one)
+    out = jax.ShapeDtypeStruct((KDA_SLOTS + KDA_SAYS,), i32, sharding=one)
+    lowered = {"decode": eng._decode_j.lower(params, k, v, out, slots, slots),
+               "restore": eng._restore_j.lower(k, v, ks, vs, i0)}
+    for n in KDA_PREFILL:
+        lowered[f"prefill{n}"] = eng._prefill_j.lower(
+            params, k, v, jax.ShapeDtypeStruct((n,), i32, sharding=one),
+            i0, i0, i0)
+    return {name: lo.compile() for name, lo in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["decode", "restore"]
+                         + [f"prefill{n}" for n in KDA_PREFILL])
+def test_hybrid_program_updates_state_and_slab_in_place(kda_programs,
+                                                        program):
+    """All eight arrays (the rows of K and of V, three layers' states and
+    three layers' tails) alias their outputs, and nothing but the
+    in-place updates produces an array the size of a slab or of a
+    layer's state: no copy of either.  (With ONE state array for the
+    three layers the decode step copied the layer it was about to update,
+    537 MB each: serve/recurrent.py.)"""
+    text = kda_programs[program].as_text()
+    assert len(re.findall(r"may-alias|must-alias",
+                          text.split("\n", 1)[0])) == 8
+    moved = []
+    for name, dtype, dims, op in _entry_ops(text):
+        in_place = "dynamic-update-slice" in name or "dynamic_update_slice" \
+            in name or op == "dynamic-update-slice" \
+            or _fused_root(text, name) == "dynamic-update-slice"
+        if dims.endswith((KDA_STATE, KDA_ROWS)) and not in_place \
+                and op not in ("parameter", "bitcast", "get-tuple-element",
+                               "tuple"):
+            moved.append((op, name, dtype, dims))
+    assert not moved
+
+
+def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
+    """No operation of the decode step follows the data (PERF.md, PR 26):
+    ``live`` reaches the step as a mask, so one compiled program serves
+    every set of live slots, and in it nothing loops or branches, no
+    product is grouped by the routing, and every KDA layer's state --
+    all 128 slots of it -- is read by exactly one reduction (both
+    read-outs) and by the one fusion that writes all three layers' states
+    back: three trips over 1.61 GB, whatever is live."""
+    text = kda_programs["decode"].as_text()
+    assert "ragged" not in text
+    entry = text[text.index("\nENTRY"):]
+    assert not re.findall(r"= [^\n]* (while|conditional)\(", entry)
+    states = re.findall(
+        rf"^\s*%?([\w.\-]+) = f32\[1,{KDA_STATE}\]\S* parameter\(", entry,
+        re.M)
+    assert len(states) == KDA_LAYERS
+
+    def readers(of):
+        return re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*? (\w[\w\-]*)\([^\n]*%"
+            + re.escape(of) + r"[,)]", entry, re.M)
+
+    updates = set()
+    for state in states:
+        by_op = {}
+        for name, op in readers(state):
+            by_op.setdefault(op, []).append(name)
+        assert sorted(by_op) == ["bitcast", "fusion"], (state, by_op)
+        (view,), (update,) = by_op["bitcast"], by_op["fusion"]
+        updates.add(update)
+        (reduce_name, op), = readers(view)
+        assert op == "fusion" and "reduce" in reduce_name
+        assert "/attn_core/kda_state/" in _op_name(text, reduce_name)
+    assert len(updates) == 1
+    # the routed product: one batched product over the ten held experts
+    experts_w = re.findall(
+        r"%(params__layer_\d____moe____experts____(?:gate|up|down)__[.\d]*) = "
+        rf"bf16\[{KDA_HELD},(?:4096,1280|1280,4096)\]", entry)
+    assert len(experts_w) == 4 * 3
+
+
+def test_hybrid_programs_fit_beside_the_weights(kda_programs):
+    """2.85 GB of weights and 3.81 GB of cache are arguments of every
+    program: the rows of one softmax layer (2.15 GB), three layers' states
+    (1.61 GB in float32) and tails (0.06 GB).  A decode step adds some
+    0.1 GB -- a copy of one layer's state would be 0.54 -- and a prefill
+    of 2,048 tokens 1.1 GB: the decays ``D`` of the chunked recurrence
+    exist for one chunk at a time (for all 32 chunks at once they were
+    4.3 GB: ops/delta_rule.py)."""
+    stats = {n: p.memory_analysis() for n, p in kda_programs.items()}
+    cache = 2 * KDA_SLOTS * 8 * KDA_SEQ * 128 * 2 + KDA_LAYERS * KDA_SLOTS \
+        * (64 * 128 * 128 * 4 + 3 * 24576 * 2)
+    assert cache == 3_814_719_488
+    assert stats["restore"].alias_size_in_bytes == cache
+    args = stats["decode"].argument_size_in_bytes
+    assert 6.6e9 < args < 6.7e9 and 2.85e9 < args - cache < 2.86e9
+    assert stats["decode"].temp_size_in_bytes < 0.2e9
+    assert stats["prefill256"].temp_size_in_bytes < 0.3e9
+    assert stats["prefill2048"].temp_size_in_bytes < 1.5e9
