@@ -15,8 +15,13 @@ chunked form needs quotients of such products.
 * :func:`kda_step` -- one token for every slot of a decode step.  Both
   read-outs come from ONE pass over the old state (``S'^T k = S^T (a *
   k)`` and ``S^T q = S^T (a * q) + (k . q) delta``), the update is a
-  second pass that writes it back: three trips over the state, each an
-  elementwise loop whose shape is the slab's whatever is live.
+  second pass that writes it back.  As XLA compiles it that is three
+  trips over the state (it cannot hold a head's matrix across the
+  reduction, so the update reads the state again); :func:`kda_update`,
+  which a served layer calls, takes the same rule as one kernel on the
+  TPU -- each head's matrix read once and written once, where it lies
+  -- and this form everywhere else.  Either is an elementwise loop
+  whose shape is the slab's whatever is live.
 * :func:`kda_chunked` -- a whole prompt, ``CHUNK`` positions at a time.
   Within a chunk the corrections ``w_s = b_s (v_s - S'_s^T k_s)`` solve a
   unit lower-triangular system that does not involve the state the chunk
@@ -24,9 +29,10 @@ chunked form needs quotients of such products.
   left to a ``lax.scan`` over the chunks is four small products a
   chunk.
 
-XLA only: float32 on the vector unit for the one-token form (exact, and
-bound by the state's bytes anyway), products at ``highest`` precision
-for the chunked one.  Neither has a backward pass.
+Float32 on the vector unit for the one-token form, in XLA and in the
+kernel alike (exact, and bound by the state's bytes anyway); XLA's
+products at ``highest`` precision for the chunked one, which has no
+kernel.  Neither has a backward pass.
 """
 
 from __future__ import annotations
@@ -59,6 +65,38 @@ def kda_step(S, q, k, v, g, b, live):
     new = a[..., None] * s + k[..., None] * delta[:, :, None, :]
     new = jnp.where(live[:, None, None, None], new, s)
     return new.astype(S.dtype), o
+
+
+def kda_update_heads(heads: int, dk: int, dv: int, dtype):
+    """The heads a grid step holds where a decode step's update of a
+    state of ``heads`` matrices ``[dk, dv]`` is ONE fused kernel a layer
+    (``ops/pallas/kda_step.py``), or None where it is :func:`kda_step`:
+    off the TPU, and for shapes the kernel does not tile.  One choice,
+    from the platform and the shapes, at trace time; the kernel's
+    package is imported here and by no module's import, so a process
+    that traces no such step never pays for it (PERF.md, PR 35)."""
+    if jax.default_backend() != "tpu":
+        return None
+    from kungfu_tpu.ops.pallas import kda_step as kernel
+
+    return kernel.head_block(heads, dk, dv, dtype)
+
+
+def kda_update(state, q, k, v, g, b, live):
+    """:func:`kda_step` for one layer's state as the serving cache holds
+    it, ``[1, B, H, K, V]`` -> (the new state, shaped like ``state``;
+    ``o`` ``[B, H, V]`` float32): the kernel, which reads and writes
+    every head's matrix once and in place, where
+    :func:`kda_update_heads` gives a head block, else :func:`kda_step`.
+    Either moves every slot's state whatever is live; ``live`` alone
+    follows the data, and only selects what is written."""
+    heads = kda_update_heads(*state.shape[2:], state.dtype)
+    if heads:
+        from kungfu_tpu.ops.pallas.kda_step import kda_step as kernel
+
+        return kernel(state, q, k, v, g, b, live, heads=heads)
+    new, o = kda_step(state[0], q, k, v, g, b, live)
+    return new[None], o
 
 
 def _mm(spec, x, y):

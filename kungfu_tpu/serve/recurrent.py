@@ -31,11 +31,17 @@ Both bodies drive the model's ONE ``block``:
   masked so that what is written back is the state of exactly ``n``
   tokens and the tail at ``n - taps + 1 .. n - 1``;
 * the **decode** step writes one row a slot (``caches.write_rows``) and
-  updates every slot's state in place (``delta_rule.kda_step``); a slot
-  the step is not ``live`` for keeps its state and its tail (the loop
-  runs one step ahead, so such a slot may hold a request that has just
-  ended, or nothing).  Every slot is processed every step: no
-  operation's shape or time follows what is live (docs/serving.md).
+  updates every slot's state in place (``delta_rule.kda_update``: on
+  the TPU one kernel a layer, ``ops/pallas/kda_step.py``, which reads a
+  head's matrix once, takes both read-outs and the update from it and
+  writes it back where it lay; off the TPU, and at the tiny sizes'
+  heads of 8, XLA's ``delta_rule.kda_step``, three trips over the
+  state; ``kda_step_kernel`` on ``kf:serve.decode_read`` says which);
+  a slot the step is not ``live`` for keeps its state and its tail
+  (the loop runs one step ahead, so such a slot may hold a request
+  that has just ended, or nothing).  Every slot is processed every
+  step: no operation's shape or time follows what is live
+  (docs/serving.md).
 
 Pages: a KDA layer keeps nothing at a page's end that a later request
 could start from, so no page of this family is ever ``whole``
@@ -45,6 +51,8 @@ nothing, and never asks this cache for ``rows_of_slot`` or
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -162,10 +170,9 @@ class HybridCaches:
             def recur(_, li, q, k, v, g, b):
                 i = self.place[li]
                 with jax.named_scope("kda_state"):
-                    new, o = delta_rule.kda_step(
-                        state[i][0], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                    state[i], o = delta_rule.kda_update(
+                        state[i], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                         b[:, 0], live)
-                    state[i] = new[None]
                 return o[:, None]
 
         h = model.embed(params, last_ids[:, None])
@@ -186,6 +193,17 @@ class HybridCaches:
     def new_out(self):
         return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
 
+    @functools.cached_property
+    def kda_step_kernel(self) -> int:
+        """1 where a decode step's update of a layer's state is the
+        fused kernel, 0 where it is XLA's ``kda_step``:
+        ``delta_rule.kda_update``'s own choice, made from the platform
+        and the state's shape when the step is traced."""
+        cfg = self.cfg
+        return int(delta_rule.kda_update_heads(
+            cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim,
+            STATE_DTYPE) is not None)
+
     def read(self, out):
         """A decode step's ``out`` on the host: the slots' tokens, and
         what it says of itself as attrs of the span that waits for them
@@ -198,6 +216,7 @@ class HybridCaches:
         # that reads fewer has to say so here
         says["state_slots_read"] = self.batch
         says["state_bytes_read"] = self.state_bytes
+        says["kda_step_kernel"] = self.kda_step_kernel
         return out[:self.batch], says
 
     def prefill(self, params, k, v, ids, n, start, slot):

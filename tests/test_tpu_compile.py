@@ -656,13 +656,36 @@ def kda_programs(topo):
     slots = jax.ShapeDtypeStruct((KDA_SLOTS,), i32, sharding=one)
     i0 = jax.ShapeDtypeStruct((), i32, sharding=one)
     out = jax.ShapeDtypeStruct((KDA_SLOTS + KDA_SAYS,), i32, sharding=one)
-    lowered = {"decode": eng._decode_j.lower(params, k, v, out, slots, slots),
-               "restore": eng._restore_j.lower(k, v, ks, vs, i0)}
-    for n in KDA_PREFILL:
-        lowered[f"prefill{n}"] = eng._prefill_j.lower(
-            params, k, v, jax.ShapeDtypeStruct((n,), i32, sharding=one),
-            i0, i0, i0)
+    # the program asks the platform which form its state's update takes
+    # (``delta_rule.kda_update_heads``); here it is told what the chip says
+    with pytest.MonkeyPatch.context() as steer:
+        steer.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = {"decode": eng._decode_j.lower(params, k, v, out, slots,
+                                                 slots),
+                   "restore": eng._restore_j.lower(k, v, ks, vs, i0)}
+        for n in KDA_PREFILL:
+            lowered[f"prefill{n}"] = eng._prefill_j.lower(
+                params, k, v, jax.ShapeDtypeStruct((n,), i32, sharding=one),
+                i0, i0, i0)
+        assert eng._caches.kda_step_kernel == 1
     return {name: lo.compile() for name, lo in lowered.items()}
+
+
+def _state_kernels(text):
+    """name -> (operands, the operand its first output aliases, op_name)
+    of the entry computation's ``kda_step`` kernel calls."""
+    entry = text[text.index("\nENTRY"):]
+    calls = {}
+    for name, operands, rest in re.findall(
+            r"^\s*%?([\w.\-]+) = \(f32\[1," + KDA_STATE + r"\]\S*, [^\n]*?\) "
+            r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\""
+            r"([^\n]*)", entry, re.M):
+        aliased = re.search(
+            r"output_to_operand_aliasing=\{\{0\}: \((\d+), \{\}\)", rest)
+        operands = re.findall(r"%([\w.\-]+)", operands)
+        calls[name] = (operands, operands[int(aliased.group(1))]
+                       if aliased else None, _op_name(text, name))
+    return calls
 
 
 @pytest.mark.parametrize("program", ["decode", "restore"]
@@ -672,12 +695,17 @@ def test_hybrid_program_updates_state_and_slab_in_place(kda_programs,
     """All eight arrays (the rows of K and of V, three layers' states and
     three layers' tails) alias their outputs, and nothing but the
     in-place updates produces an array the size of a slab or of a
-    layer's state: no copy of either.  (With ONE state array for the
-    three layers the decode step copied the layer it was about to update,
-    537 MB each: serve/recurrent.py.)"""
+    layer's state: no copy of either.  In place is a dynamic update of a
+    slice and, in the decode step, the ``kda_step`` kernel whose first
+    output aliases the state it was handed -- and nothing else of a
+    state's size.  (With ONE state array for the three layers the decode
+    step copied the layer it was about to update, 537 MB each:
+    serve/recurrent.py.)"""
     text = kda_programs[program].as_text()
     assert len(re.findall(r"may-alias|must-alias",
                           text.split("\n", 1)[0])) == 8
+    kernels = _state_kernels(text)
+    assert len(kernels) == (KDA_LAYERS if program == "decode" else 0)
     moved = []
     for name, dtype, dims, op in _entry_ops(text):
         in_place = "dynamic-update-slice" in name or "dynamic_update_slice" \
@@ -687,7 +715,11 @@ def test_hybrid_program_updates_state_and_slab_in_place(kda_programs,
                 and op not in ("parameter", "bitcast", "get-tuple-element",
                                "tuple"):
             moved.append((op, name, dtype, dims))
+    # (a kernel call's own result is a tuple: the scan above does not see
+    # it, and what is taken out of it is a ``get-tuple-element``)
     assert not moved
+    for name, (operands, aliased, _) in kernels.items():
+        assert aliased is not None and aliased == operands[-1], name
 
 
 def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
@@ -695,9 +727,11 @@ def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
     ``live`` reaches the step as a mask, so one compiled program serves
     every set of live slots, and in it nothing loops or branches, no
     product is grouped by the routing, and every KDA layer's state --
-    all 128 slots of it -- is read by exactly one reduction (both
-    read-outs) and by the one fusion that writes all three layers' states
-    back: three trips over 1.61 GB, whatever is live."""
+    all 128 slots of it -- is read by exactly ONE operation: the
+    ``kda_step`` kernel under ``attn_core/kda_state``, whose grid is
+    every slot of every head and whose first output aliases the state.
+    Two trips over 1.61 GB, whatever is live (three before the kernel:
+    one reduction and one update fusion a state)."""
     text = kda_programs["decode"].as_text()
     assert "ragged" not in text
     entry = text[text.index("\nENTRY"):]
@@ -706,24 +740,23 @@ def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
         rf"^\s*%?([\w.\-]+) = f32\[1,{KDA_STATE}\]\S* parameter\(", entry,
         re.M)
     assert len(states) == KDA_LAYERS
+    kernels = _state_kernels(text)
+    assert len(kernels) == KDA_LAYERS
 
     def readers(of):
         return re.findall(
             r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*? (\w[\w\-]*)\([^\n]*%"
             + re.escape(of) + r"[,)]", entry, re.M)
 
-    updates = set()
+    seen = set()
     for state in states:
-        by_op = {}
-        for name, op in readers(state):
-            by_op.setdefault(op, []).append(name)
-        assert sorted(by_op) == ["bitcast", "fusion"], (state, by_op)
-        (view,), (update,) = by_op["bitcast"], by_op["fusion"]
-        updates.add(update)
-        (reduce_name, op), = readers(view)
-        assert op == "fusion" and "reduce" in reduce_name
-        assert "/attn_core/kda_state/" in _op_name(text, reduce_name)
-    assert len(updates) == 1
+        (name, op), = readers(state)
+        assert op == "custom-call" and name in kernels, (state, name, op)
+        operands, aliased, op_name = kernels[name]
+        assert aliased == state and operands.count(state) == 1
+        assert "/attn_core/kda_state/" in op_name and "kda_step" in op_name
+        seen.add(name)
+    assert len(seen) == KDA_LAYERS
     # the routed product: one batched product over the ten held experts
     experts_w = re.findall(
         r"%(params__layer_\d____moe____experts____(?:gate|up|down)__[.\d]*) = "
@@ -735,7 +768,7 @@ def test_hybrid_programs_fit_beside_the_weights(kda_programs):
     """2.85 GB of weights and 3.81 GB of cache are arguments of every
     program: the rows of one softmax layer (2.15 GB), three layers' states
     (1.61 GB in float32) and tails (0.06 GB).  A decode step adds some
-    0.1 GB -- a copy of one layer's state would be 0.54 -- and a prefill
+    0.09 GB -- a copy of one layer's state would be 0.54 -- and a prefill
     of 2,048 tokens 1.1 GB: the decays ``D`` of the chunked recurrence
     exist for one chunk at a time (for all 32 chunks at once they were
     4.3 GB: ops/delta_rule.py)."""
