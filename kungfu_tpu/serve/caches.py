@@ -47,14 +47,44 @@ array of a part is of one of two kinds:
     docs/serving.md).  ``out`` is ONE array whose first ``B`` entries are
     the slots' tokens (the next step takes them from it on the device),
     so that the host's one read brings all a step has to say;
-    ``read(out)`` takes it apart.
+    ``read(out, contexts)`` takes it apart.
 ``new_out()``
     what stands for a step's ``out`` before any step ran: zeros of its
     shape and dtype.
-``read(out)`` -> ``(tokens [B], attrs or None)``
+``read(out, contexts)`` -> ``(tokens [B], attrs)``
     on the host: fetch a decode step's ``out`` (the host waits here), the
     slots' tokens and what the step has to say of itself besides, as
-    attrs of the ``kf:serve.decode_read`` span that is open meanwhile.
+    attrs of the ``kf:serve.decode_read`` span that is open meanwhile
+    (docs/tracing.md has the table).  ``contexts`` is the engine's: the
+    positions each row of the step attended over, its own new one among
+    them, for the rows whose request is still there when the step is
+    read (one that ended on ``eos_id`` in the step before was not
+    ``live`` in the program either).  Every cache says, of each kind of
+    content it keeps, **what the step had to read and what it did
+    read**, under one noun a kind:
+
+    * ``kv_rows_live``, ``kv_rows_read``, ``kv_rows_written``,
+      ``kv_row_bytes`` -- per-head key and value rows a position
+      (:func:`kv_rows`: the dense slabs, the window rings and full
+      slabs, the hybrid cache's slab), summed over the layers that keep
+      them: a full layer has to read ``contexts[i]`` rows of slot ``i``,
+      a window layer at most its ring; a row's bytes are one layer's in
+      both parts;
+    * ``latent_rows_live``, ``latent_rows_read`` -- compressed rows a
+      position (``serve/latent.py``), a layer's;
+    * ``state_slots_live``, ``state_slots_read``, ``state_bytes_read``
+      -- a state a slot (``serve/recurrent.py``).
+
+    The ``*_live`` of rows a position is the sum of ``contexts``, which
+    the host has (the latent cache's step counts it itself, and the two
+    are one number: tests/test_serve_kv_rows.py); ``*_read`` is stated by
+    the cache from its parts' shapes, because every decode body here
+    reads every position of every slot of every part under a mask,
+    whatever is live.  **A step that reads fewer has to say so here**:
+    a kernel that skips dead rows or dead slots counts what it read in
+    the step, puts it into ``out`` and states that.  The metrics that
+    divide the one by the other, and the rooflines counted over the live
+    work, are only true while this holds.
 ``empty_pages(rows)`` -> ``(ks, vs)``
     what the restore program writes into a slot for ``rows`` cached
     positions that hold nothing, one for each part (each a tree shaped
@@ -157,21 +187,43 @@ def slot_rows(slab, slot: int, lo: int, hi: int):
     return np.asarray(jax.device_get(slab[:, slot, :, lo:hi, :]))
 
 
+def kv_rows(contexts, slabs, dtype) -> dict:
+    """The ``kv_*`` attrs of a decode step over ``slabs``, the shapes
+    ``[layers, slots, heads, rows, width]`` of K's arrays of rows a
+    position (V's are the same), for the ``contexts`` the engine hands
+    ``read``.  A slot's context has to read ``min(context, rows)`` rows
+    of each layer of a slab (a ring holds no more; a full slab's
+    ``rows`` is ``max_seq``, which no context passes) and writes one;
+    the bodies here READ every row of every slot of every slab, which
+    is what ``kv_rows_read`` states: a step that reads fewer has to say
+    so there.  On the host, one vectorised call a slab and step."""
+    contexts = np.asarray(contexts)
+    live = read = layers = 0
+    for n, slots, _, rows, _ in slabs:
+        live += n * int(np.minimum(contexts, rows).sum())
+        read += n * slots * rows
+        layers += n
+    _, _, heads, _, width = slabs[0]
+    return {"kv_rows_live": live, "kv_rows_read": read,
+            "kv_rows_written": layers * len(contexts),
+            "kv_row_bytes": 2 * heads * width * jnp.dtype(dtype).itemsize}
+
+
 class DenseCaches:
     """The dense ``Transformer`` through the engine: K and V one slab
     ``[L, B, H, S, D]`` each in the compute dtype."""
 
     def __init__(self, model, max_batch: int, max_seq: int):
         self.model = model
-        self.cfg = model.cfg
+        cfg = self.cfg = model.cfg
         self.batch, self.seq = int(max_batch), int(max_seq)
+        #: of K's slab, and of V's
+        self.shape = (cfg.n_layers, self.batch, cfg.n_heads, self.seq,
+                      cfg.head_dim)
 
     def new_slabs(self):
-        cfg = self.cfg
-        shape = (cfg.n_layers, self.batch, cfg.n_heads, self.seq,
-                 cfg.head_dim)
-        return (jnp.zeros(shape, cfg.compute_dtype),
-                jnp.zeros(shape, cfg.compute_dtype))
+        dt = self.cfg.compute_dtype
+        return jnp.zeros(self.shape, dt), jnp.zeros(self.shape, dt)
 
     # -- forward passes --------------------------------------------------
     @jax.named_scope("attn_proj")
@@ -304,9 +356,9 @@ class DenseCaches:
     def new_out(self):
         return jnp.zeros(self.batch, jnp.int32)
 
-    @staticmethod
-    def read(out):
-        return np.asarray(jax.device_get(out)), None
+    def read(self, out, contexts):
+        return np.asarray(jax.device_get(out)), kv_rows(
+            contexts, (self.shape,), self.cfg.compute_dtype)
 
     # -- the host's side of a page ---------------------------------------
     def empty_pages(self, rows: int):

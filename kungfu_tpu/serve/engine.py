@@ -572,15 +572,18 @@ class InferenceEngine:
         A row whose request is gone meanwhile (it ended on ``eos_id`` in
         the step before, or was cancelled) is dropped."""
         with timeline.span("serve", "decode_read", rank=self.rank) as sp:
-            # the host waits here; what the step says of itself besides
-            # its tokens (an expert model's routing) goes on the span
-            # that is open when it is known
-            toks, attrs = self._caches.read(flight.out)
             with self._lock:
-                rows = [(slot, r) for slot, r in flight.rows.items()
-                        if self._active.get(slot) is r]
-            sp.set_metadata(discarded=len(flight.rows) - len(rows),
-                            **(attrs or {}))
+                kept = [self._active.get(slot) is r
+                        for slot, r in flight.rows.items()]
+            # the host waits here; what the step says of itself besides
+            # its tokens (an expert model's routing; what it had to read
+            # of the cache for the contexts of the rows that are kept,
+            # and what it did read) goes on the span that is open when
+            # it is known
+            toks, attrs = self._caches.read(flight.out,
+                                            flight.contexts[kept])
+            rows = [row for row, k in zip(flight.rows.items(), kept) if k]
+            sp.set_metadata(discarded=len(kept) - len(rows), **attrs)
         now = time.perf_counter()
         if rows:
             # what a client feels: the time between two deliveries (a

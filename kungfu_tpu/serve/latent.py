@@ -135,15 +135,19 @@ class LatentCaches:
             cfg.n_heads, self.seq, cfg.kv_lora_rank, cfg.qk_rope_dim,
             cfg.compute_dtype) is not None)
 
-    def read(self, out):
+    def read(self, out, contexts):
         """A decode step's ``out`` on the host: the slots' tokens, and
         what it says of itself as attrs of the span that waits for them
-        (docs/tracing.md)."""
+        (docs/tracing.md).  A latent row is no K/V row: this cache
+        states ``latent_rows_*`` and no ``kv_*``, and its step counts
+        the live rows itself (the sum of ``contexts``, which is not
+        needed here)."""
         out = np.asarray(jax.device_get(out))
         says = dict(zip(_SAYS, out[self.batch:].tolist()))
         says["experts_held"] = self.held
         # ``decode`` attends over every position of every slot under a
         # mask: a step that reads fewer rows has to say so here
+        # (serve/caches.py)
         says["latent_rows_read"] = self.batch * self.seq
         says["latent_attn_kernel"] = self.latent_attn_kernel
         says["expert_load_mean"] = says.pop("assigned") / self.held

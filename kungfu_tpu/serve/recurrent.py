@@ -60,7 +60,7 @@ import numpy as np
 
 from kungfu_tpu.models import cohere2_moe, solar_open2 as arch
 from kungfu_tpu.ops import delta_rule
-from kungfu_tpu.serve.caches import row_windows, write_rows
+from kungfu_tpu.serve.caches import kv_rows, row_windows, write_rows
 
 F32 = jnp.float32
 #: of the KDA layers' matrices (the published layer keeps them so; the
@@ -204,16 +204,20 @@ class HybridCaches:
             cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim,
             STATE_DTYPE) is not None)
 
-    def read(self, out):
+    def read(self, out, contexts):
         """A decode step's ``out`` on the host: the slots' tokens, and
         what it says of itself as attrs of the span that waits for them
-        (docs/tracing.md)."""
+        (docs/tracing.md): of the softmax layers' slab the K/V rows its
+        ``contexts`` had to read beside the rows it did, of the KDA
+        layers' states the slots it was for beside those it moved."""
         out = np.asarray(jax.device_get(out))
         says = dict(zip(_SAYS, out[self.batch:].tolist()))
+        says.update(kv_rows(contexts, self.shapes()[:1],
+                            self.cfg.compute_dtype))
         says["experts_held"] = self.held
         says["expert_load_mean"] = says.pop("assigned") / self.held
         # ``decode`` moves every slot's state whatever is live: a step
-        # that reads fewer has to say so here
+        # that reads fewer has to say so here (serve/caches.py)
         says["state_slots_read"] = self.batch
         says["state_bytes_read"] = self.state_bytes
         says["kda_step_kernel"] = self.kda_step_kernel

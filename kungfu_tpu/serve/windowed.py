@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kungfu_tpu.models import cohere2_moe as arch
-from kungfu_tpu.serve.caches import row_windows, write_rows
+from kungfu_tpu.serve.caches import kv_rows, row_windows, write_rows
 
 
 def _slot_of(slab, li, slot):
@@ -146,16 +146,19 @@ class WindowedCaches:
     def new_out(self):
         return jnp.zeros(self.batch + 3, jnp.int32)
 
-    def read(self, out):
+    def read(self, out, contexts):
         """A decode step's ``out`` on the host: the slots' tokens, and
-        its routing as attrs of the span that waits for them
-        (docs/tracing.md)."""
+        as attrs of the span that waits for them (docs/tracing.md) its
+        routing, counted by the step, and the K/V rows its ``contexts``
+        had to read of the rings and the full slabs beside the rows it
+        did read, all of them."""
         out = np.asarray(jax.device_get(out))
         touched, load_max, assigned = out[self.batch:].tolist()
-        return out[:self.batch], {
-            "experts_touched": touched, "experts_held": self.held,
-            "expert_load_max": load_max,
-            "expert_load_mean": assigned / self.held}
+        return out[:self.batch], dict(
+            kv_rows(contexts, self.shapes(), self.cfg.compute_dtype),
+            experts_touched=touched, experts_held=self.held,
+            expert_load_max=load_max,
+            expert_load_mean=assigned / self.held)
 
     def prefill(self, params, k, v, ids, n, start, slot):
         """``ids`` ``[P]`` (the prompt past ``start`` cached positions,

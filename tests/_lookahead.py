@@ -3,7 +3,9 @@
 test_cohere2_moe.py`` for the window rings): a recorder of the engine's
 spans, the committed pages of a pool as bytes, ONE schedule of mixed
 requests, so that two engines driven through it take the same slots at
-the same calls, and the comparison of what such a run committed."""
+the same calls, the comparison of what such a run committed, and every
+``kf:serve.decode_read`` of a run beside the contexts of the tokens it
+handed out (``tests/test_serve_kv_rows.py``)."""
 
 from __future__ import annotations
 
@@ -31,6 +33,32 @@ def record_spans(monkeypatch) -> list:
     monkeypatch.setattr(timeline, "span",
                         lambda kind, name, **attrs: Span(log, name, attrs))
     return log
+
+
+def decode_reads(eng, spans, asked: dict) -> list:
+    """Drive ``eng`` through ``asked`` (rid -> (prompt, max_new)), all
+    submitted before the first step, until it is idle; ``spans`` is
+    :func:`record_spans`'s log.  Returns, for every
+    ``kf:serve.decode_read``, (its attrs, the contexts of the rows it
+    handed out): a decode step's token is its request's ``n``-th, made
+    from a row that attended over the prompt and the ``n - 1`` tokens
+    before it, its own position among them -- a plain count from the
+    requests' own lengths, which knows nothing of slots or flights.  A
+    row the engine drops (its request ended on ``eos_id`` in the step
+    before) hands out no token and is in no count."""
+    for rid, (prompt, max_new) in asked.items():
+        eng.submit(rid, prompt, max_new)
+    out = []
+    while eng.pending_count or eng.active_count or eng._flight is not None:
+        seen = len(spans)
+        events = eng.step()
+        reads = [s for s in spans[seen:] if s.name == "decode_read"]
+        assert len(reads) <= 1
+        for read in reads:
+            out.append((read.attrs, [
+                len(asked[e["rid"]][0]) + e["n"] - 1 for e in events
+                if e["kind"] == "token" and e["n"] > 1]))
+    return out
 
 
 def committed(pool) -> dict:

@@ -241,7 +241,7 @@ def test_the_cache_says_which_form_its_decode_step_took(
         model.cfg.recurrent_layers) == kernel * 3
     assert ("pallas_call" in text) == bool(kernel)
     out = np.arange(caches.batch + 4, dtype=np.int32)
-    tokens, says = caches.read(out)
+    tokens, says = caches.read(out, np.asarray([3, 5]))
     assert says["kda_step_kernel"] == kernel
     assert says["state_slots_read"] == caches.batch
     assert says["state_bytes_read"] == caches.state_bytes

@@ -189,7 +189,7 @@ def test_the_cache_says_which_form_its_decode_step_took(
     assert text.count("name=_call") == kernel * model.cfg.n_layers
     assert ("pallas_call" in text) == bool(kernel)
     out = np.arange(caches.batch + 4, dtype=np.int32)
-    tokens, says = caches.read(out)
+    tokens, says = caches.read(out, np.asarray([3, 5]))
     assert says["latent_attn_kernel"] == kernel
     assert says["latent_rows_read"] == caches.batch * caches.seq
     assert tokens.tolist() == list(range(caches.batch))

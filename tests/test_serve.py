@@ -420,9 +420,9 @@ class TestOneStepAhead:
             calls.append("decode")
             return decode_j(*a)
 
-        def counted_read(out):
+        def counted_read(out, contexts):
             calls.append("read")
-            return read(out)
+            return read(out, contexts)
 
         eng._decode_j = counted_decode
         monkeypatch.setattr(eng._caches, "read", counted_read)

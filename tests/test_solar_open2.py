@@ -370,7 +370,7 @@ def test_a_slot_that_is_not_live_keeps_its_state_across_a_step(adapter):
         now = np.asarray(now)
         assert np.array_equal(now[:, 1], was[:, 1])
         assert not np.array_equal(now[:, 0], was[:, 0])
-    toks, says = caches.read(out)
+    toks, says = caches.read(out, np.asarray([7]))
     assert toks.shape == (2,) and says["state_slots_live"] == 1
 
 
