@@ -30,7 +30,14 @@ Both bodies drive the model's ONE ``block``:
   request before -- in the chunked form, the bucket's padding past ``n``
   masked so that what is written back is the state of exactly ``n``
   tokens and the tail at ``n - taps + 1 .. n - 1``;
-* the **decode** step writes one row a slot (``caches.write_rows``) and
+* the **decode** step writes one row a slot (``caches.write_rows``),
+  attends over the slab -- on the TPU one kernel a softmax layer,
+  ``ops/pallas/decode_attention.py``, which is handed the slab whole and
+  the rows each slot may see (``pos + 1`` where it is live, else 0) and
+  walks only the key tiles below that count; off the TPU, and at the
+  tiny sizes' heads of 8, ``cohere2_moe.attention`` over every row under
+  a mask; ``kv_attn_kernel`` on ``kf:serve.decode_read`` says which, and
+  ``kv_rows_read`` the rows the step itself counted as read -- and
   updates every slot's state in place (``delta_rule.kda_update``: on
   the TPU one kernel a layer, ``ops/pallas/kda_step.py``, which reads a
   head's matrix once, takes both read-outs and the update from it and
@@ -40,7 +47,9 @@ Both bodies drive the model's ONE ``block``:
   a slot the step is not ``live`` for keeps its state and its tail
   (the loop runs one step ahead, so such a slot may hold a request
   that has just ended, or nothing).  Every slot is processed every
-  step: no operation's shape or time follows what is live
+  step and no operation's shape follows what is live; of the times,
+  the attention kernel's follows the live contexts (the tiles it
+  skips), by what PERF.md, PR 39, measured over six seeds
   (docs/serving.md).
 
 Pages: a KDA layer keeps nothing at a page's end that a later request
@@ -69,7 +78,7 @@ F32 = jnp.float32
 STATE_DTYPE = jnp.dtype("float32")
 #: what a decode step's ``out`` says behind the slots' tokens
 _SAYS = ("experts_touched", "expert_load_max", "assigned",
-         "state_slots_live")
+         "state_slots_live", "kv_rows_walked")
 
 
 def _of_slot(part, li, slot):
@@ -133,13 +142,23 @@ class HybridCaches:
         writes no row, keeps its state and tail, and is counted
         nowhere).  Returns the parts and ONE int32 vector: the ``B``
         tokens, then what the step says of itself (:data:`_SAYS`): its
-        routing over the live slots and all layers, and the slots whose
-        state it moved."""
+        routing over the live slots and all layers, the slots whose
+        state it moved, and the K/V rows its attention read."""
         cfg, model = self.cfg, self.model
         (kr, state), (vr, tails) = k, v
         state, tails = list(state), list(tails)
-        see = (jnp.arange(self.seq) <= pos[:, None])[:, None, None, None]
         at = row_windows(pos, self.seq, live)
+        tile = self.attn_tile
+        if tile:
+            from kungfu_tpu.ops.pallas import decode_attention as kernel
+
+            # what a slot may see, as the kernel takes it: its first
+            # ``pos + 1`` rows, and none where the step is not for it
+            visible = jnp.where(live, pos + 1, 0)
+            walked = kernel.rows_walked(visible, tile)
+        else:       # XLA's form reads every row of every slot under a mask
+            see = (jnp.arange(self.seq) <= pos[:, None])[:, None, None, None]
+            walked = self.batch * self.seq
 
         class Step:
             """A decode step's cache: one row a slot into the slab and
@@ -156,6 +175,9 @@ class HybridCaches:
             def attend(_, li, q, positions):
                 i = self.place[li]
                 with jax.named_scope("attn_full"):
+                    if tile:
+                        return kernel.decode_attn(q[:, 0], kr, vr, i,
+                                                  visible, tile=tile)[:, None]
                     return cohere2_moe.attention(q, kr[i], vr[i], see)
 
             def convolve(_, li, u, w):
@@ -178,20 +200,49 @@ class HybridCaches:
         h = model.embed(params, last_ids[:, None])
         counts = []
         for li in range(cfg.n_layers):
-            h, n = arch.block(cfg, params[f"layer_{li}"], li, h,
-                              pos[:, None], Step(), dense=True,
-                              live=live[:, None])
-            counts.append(n)
+            h, count = arch.block(cfg, params[f"layer_{li}"], li, h,
+                                  pos[:, None], Step(), dense=True,
+                                  live=live[:, None])
+            counts.append(count)
         tok = jnp.argmax(model.logits(params, h[:, 0]), axis=-1)
         with jax.named_scope("moe_router"):
             counts = jnp.stack(counts)
             says = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
-                              jnp.sum(counts), jnp.sum(live)])
+                              jnp.sum(counts), jnp.sum(live),
+                              len(cfg.gqa_layers) * walked])
         return ((kr, tuple(state)), (vr, tuple(tails)),
                 jnp.concatenate([tok, says]).astype(jnp.int32))
 
     def new_out(self):
         return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
+
+    @functools.cached_property
+    def attn_tile(self):
+        """The key tile with which a decode step's attention over the
+        slab is ONE kernel a softmax layer that walks only the tiles a
+        live context reaches (``ops/pallas/decode_attention.py``), or
+        None where it is XLA's two products over every row
+        (``cohere2_moe.attention``): off the TPU, and for shapes the
+        kernel does not tile.  One choice, from the platform and the
+        slab's shape, made once: the step that is traced and the span
+        that says which form ran read the same.  The kernel's package is
+        imported here and by no module's import, so a process that
+        traces no such step never pays for it (PERF.md, PR 35)."""
+        if jax.default_backend() != "tpu":
+            return None
+        from kungfu_tpu.ops.pallas import decode_attention
+
+        cfg = self.cfg
+        return decode_attention.key_tile(
+            self.seq, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
+            cfg.head_dim, cfg.compute_dtype)
+
+    @property
+    def kv_attn_kernel(self) -> int:
+        """1 where a decode step's attention over the slab is the fused
+        kernel, 0 where it is ``cohere2_moe.attention``
+        (:attr:`attn_tile`)."""
+        return int(self.attn_tile is not None)
 
     @functools.cached_property
     def kda_step_kernel(self) -> int:
@@ -214,6 +265,10 @@ class HybridCaches:
         says = dict(zip(_SAYS, out[self.batch:].tolist()))
         says.update(kv_rows(contexts, self.shapes()[:1],
                             self.cfg.compute_dtype))
+        # ... of which the rows READ are the step's own count: the tiles
+        # the kernel walked, or every row where XLA's form ran
+        says["kv_rows_read"] = says.pop("kv_rows_walked")
+        says["kv_attn_kernel"] = self.kv_attn_kernel
         says["experts_held"] = self.held
         says["expert_load_mean"] = says.pop("assigned") / self.held
         # ``decode`` moves every slot's state whatever is live: a step

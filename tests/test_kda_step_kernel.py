@@ -240,7 +240,7 @@ def test_the_cache_says_which_form_its_decode_step_took(
     assert text.count("name=_call") == kernel * len(
         model.cfg.recurrent_layers) == kernel * 3
     assert ("pallas_call" in text) == bool(kernel)
-    out = np.arange(caches.batch + 4, dtype=np.int32)
+    out = np.arange(len(caches.new_out()), dtype=np.int32)
     tokens, says = caches.read(out, np.asarray([3, 5]))
     assert says["kda_step_kernel"] == kernel
     assert says["state_slots_read"] == caches.batch
@@ -283,15 +283,20 @@ FOOTPRINT = textwrap.dedent("""
     import sys
     import kungfu_tpu.models, kungfu_tpu.serve.engine
     import kungfu_tpu.serve.recurrent, kungfu_tpu.serve.caches
+    import kungfu_tpu.serve.windowed
     heavy = ("jax.experimental.pallas", "kungfu_tpu.ops.pallas")
     before = [m for m in heavy if m in sys.modules]
     import ast, jax, jax.numpy as jnp
-    from kungfu_tpu.models import solar_open2 as arch
-    from kungfu_tpu.serve.recurrent import HybridCaches
+    from kungfu_tpu.models import cohere2_moe, solar_open2, transformer
     jax.default_backend = lambda: sys.argv[1]
-    model = arch.SolarOpen2(arch.SolarOpen2Config(
-        **ast.literal_eval(sys.argv[2])))
-    caches = HybridCaches(model, 4, 32)
+    make = {"hybrid": lambda **z: solar_open2.SolarOpen2(
+                solar_open2.SolarOpen2Config(**z)),
+            "windowed": lambda **z: cohere2_moe.Cohere2Moe(
+                cohere2_moe.Cohere2MoeConfig(**z)),
+            "dense": lambda **z: transformer.Transformer(
+                transformer.TransformerConfig(**z))}[sys.argv[3]]
+    model = make(**ast.literal_eval(sys.argv[2]))
+    caches = model.serve_caches(4, model.cfg.max_seq)
     built = [m for m in heavy if m in sys.modules]
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     k, v = jax.eval_shape(caches.new_slabs)
@@ -299,28 +304,50 @@ FOOTPRINT = textwrap.dedent("""
     jax.eval_shape(caches.decode, params, k, v, slots, slots,
                    jax.ShapeDtypeStruct((4,), bool))
     after = [m for m in heavy if m in sys.modules]
-    print("FOOTPRINT", before, built, after,
-          "kungfu_tpu.ops.pallas.kda_step" in sys.modules)
+    print("FOOTPRINT", type(caches).__name__, before, built, after,
+          [m for m in ("kda_step", "decode_attention")
+           if "kungfu_tpu.ops.pallas." + m in sys.modules])
 """)
 
+LOADED = "['jax.experimental.pallas', 'kungfu_tpu.ops.pallas']"
+#: the other two families at shapes the grouped-head kernel WOULD tile
+#: (heads of 128 in bfloat16, eight query heads a group, positions in
+#: lane tiles): on a TPU their decode steps still trace no kernel
+WINDOWED = dict(vocab_size=64, d_model=64, n_layers=4, n_heads=16,
+                n_kv_heads=2, head_dim=128, d_expert=32, n_experts=4,
+                experts_held=(0, 4), top_k=2, n_shared=1, window=128,
+                max_seq=256)
+DENSE = dict(vocab_size=64, d_model=256, n_layers=1, n_heads=2, d_ff=64,
+             max_seq=256, dropout=0.0, causal=True, pos="learned",
+             dtype="bfloat16")
 
-@pytest.mark.parametrize("backend,after", [
-    ("tpu", "['jax.experimental.pallas', 'kungfu_tpu.ops.pallas']"),
-    ("cpu", "[]")])
-def test_only_a_traced_kda_decode_step_imports_the_kernels(backend, after):
+
+@pytest.mark.parametrize("backend,family,sizes,after,kernels", [
+    ("tpu", "hybrid", {**SMALL, **TILES}, LOADED,
+     "['kda_step', 'decode_attention']"),
+    ("cpu", "hybrid", {**SMALL, **TILES}, "[]", "[]"),
+    ("tpu", "windowed", WINDOWED, "[]", "[]"),
+    ("tpu", "dense", DENSE, "[]", "[]")],
+    ids=["tpu", "cpu", "tpu_windowed", "tpu_dense"])
+def test_only_a_traced_kda_decode_step_imports_the_kernels(
+        backend, family, sizes, after, kernels):
     """Importing the models and the serving plane, and building a
     ``HybridCaches``, loads neither Pallas nor ``kungfu_tpu.ops.pallas``
     (about a second of every serving cell's set-up, were it paid at
-    import: PERF.md, PR 35).  Tracing a decode step with KDA layers where
-    the platform says TPU does; where it says CPU that does not
-    either."""
+    import: PERF.md, PR 35).  Tracing a decode step of the hybrid cache
+    where the platform says TPU does (its two choosers ask the kernels'
+    modules what tiles); where it says CPU that does not either.  A
+    ``WindowedCaches`` or a ``DenseCaches`` loads none whatever the
+    platform and the shapes, its traced decode step included."""
     done = subprocess.run(
-        [sys.executable, "-c", FOOTPRINT, backend, repr({**SMALL, **TILES})],
+        [sys.executable, "-c", FOOTPRINT, backend, repr(sizes), family],
         capture_output=True, text=True, timeout=300,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert done.returncode == 0, done.stderr[-2000:]
     line = [l for l in done.stdout.splitlines() if l.startswith("FOOTPRINT")]
-    assert line == [f"FOOTPRINT [] [] {after} {backend == 'tpu'}"], \
+    cache = {"hybrid": "HybridCaches", "windowed": "WindowedCaches",
+             "dense": "DenseCaches"}[family]
+    assert line == [f"FOOTPRINT {cache} [] [] {after} {kernels}"], \
         (done.stdout, done.stderr[-2000:])
 
 

@@ -47,7 +47,7 @@ ASKED = {rid: (np.random.default_rng(40 + i).integers(0, 96, p).tolist(), n)
 #: decode step's ``out`` holds behind the slots' tokens)
 KV = {"dense": ([(2, MAX_SEQ)], 2 * 16 * 2 * 4, 0),
       "windowed": ([(3, windowed.WINDOW), (1, MAX_SEQ)], 2 * 8 * 2 * 4, 3),
-      "hybrid": ([(1, MAX_SEQ)], 2 * 8 * 2 * 4, 4)}
+      "hybrid": ([(1, MAX_SEQ)], 2 * 8 * 2 * 4, 5)}
 
 
 @pytest.fixture(scope="module")
@@ -165,7 +165,9 @@ def test_the_hosts_contexts_sum_to_the_latent_steps_own_count(
 def test_nothing_of_it_is_in_the_step(built, family):
     """The counts are the host's: the decode program lowers to the same
     text with the cache's ``read`` and with one that states nothing, and
-    its ``out`` is as long as it was."""
+    its ``out`` is as long as it was -- but for the hybrid cache's, which
+    since PR 39 carries the one count that IS the step's: the rows its
+    attention read (``kv_rows_read``; tests/test_solar_open2.py)."""
     i32 = jnp.zeros(SLOTS, jnp.int32)
 
     def lowered(eng):

@@ -495,6 +495,189 @@ def test_decode_says_what_it_moved_and_routed(adapter, monkeypatch):
     assert last("decode_read")["state_slots_read"] == 3
 
 
+# -- the softmax layer's slab through the kernel that walks live tiles -----
+#: wide enough for ``ops/pallas/decode_attention.py``: 16 query heads
+#: over 2 key/value heads of 128, a slab of three tiles of 128 positions;
+#: the KDA layers stay at 4 heads of 8 (XLA's ``kda_step`` everywhere)
+WIDE_SEQ, WIDE_TILE = 384, 128
+
+
+def wide_model(adapter):
+    """(the program's model in bfloat16, its weights)."""
+    cfg = dict(tiny_cfg(), num_attention_heads=16, num_key_value_heads=2,
+               head_dim=128, n_positions=WIDE_SEQ)
+    model = adapter.program_model(cfg)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(5))
+
+
+def says_tpu(monkeypatch):
+    """What the code can see says TPU, so the decode step takes its
+    kernel branch, and the kernel runs in Pallas's plain interpreter
+    (JAX operations in the calling program: nothing that calls back into
+    Python from a step the engine has dispatched ahead)."""
+    import functools
+
+    from kungfu_tpu.ops.pallas import decode_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(decode_attention, "decode_attn", functools.partial(
+        decode_attention.decode_attn, interpret=True))
+
+
+def walked(n):
+    return sum(-(-x // WIDE_TILE) * WIDE_TILE for x in n)
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_read_states_the_rows_the_step_itself_counted(adapter, monkeypatch,
+                                                      backend):
+    """``kv_rows_read`` on ``kf:serve.decode_read`` is the step's own
+    count, brought back behind its tokens: where the kernel runs, every
+    live slot's tiles up to its newest row and nothing of a slot the
+    step is not for; where XLA's form runs, every row of every slot.
+    ``kv_attn_kernel`` says which, and the host's counts stand beside
+    it as they were."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    model, _ = wide_model(adapter)
+    caches = model.serve_caches(4, WIDE_SEQ)
+    kernel = int(backend == "tpu")
+    assert caches.attn_tile == (WIDE_TILE if kernel else None)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    k, v = jax.eval_shape(caches.new_slabs)
+    slots = jax.ShapeDtypeStruct((4,), jnp.int32)
+    text = str(jax.make_jaxpr(caches.decode)(
+        params, k, v, slots, slots, jax.ShapeDtypeStruct((4,), bool)))
+    assert text.count("name=decode_attn") == kernel
+    assert len(caches.new_out()) == 4 + 5
+    # what the step of slots at positions 4, 127, 128 (not live) and 300
+    # puts behind its tokens, taken apart by ``read``
+    pos, live = np.asarray([4, 127, 128, 300]), [True, True, False, True]
+    n = [p + 1 if l else 0 for p, l in zip(pos, live)]
+    count = walked(n) if kernel else 4 * WIDE_SEQ
+    assert walked(n) == 128 + 128 + 0 + 384
+    out = np.asarray([7, 8, 9, 10, 12, 1, 12, 3, count], np.int32)
+    tokens, says = caches.read(out, np.asarray([5, 128, 301]))
+    assert tokens.tolist() == [7, 8, 9, 10]
+    assert says["kv_rows_read"] == count and says["kv_attn_kernel"] == kernel
+    assert says["kv_rows_live"] == 5 + 128 + 301
+    assert says["kv_rows_written"] == 3 and says["kv_row_bytes"] == 1024
+    assert says["state_slots_live"] == 3 and "kv_rows_walked" not in says
+
+
+def test_the_step_counts_the_rows_its_kernel_walks(adapter, monkeypatch):
+    """One traced decode step, the kernel interpreted: behind the tokens
+    stands ``sum ceil(n / tile) * tile`` over the live slots, a slot the
+    step is not for counted nowhere and its rows untouched."""
+    says_tpu(monkeypatch)
+    model, params = wide_model(adapter)
+    caches = model.serve_caches(4, WIDE_SEQ)
+    k, v = caches.new_slabs()
+    fill = lambda r, a: jax.random.normal(r, a.shape, jnp.float32
+                                          ).astype(a.dtype)
+    r = jax.random.split(jax.random.PRNGKey(6), 2)
+    k, v = (fill(r[0], k[0]), k[1]), (fill(r[1], v[0]), v[1])
+    pos = jnp.asarray([4, 127, 128, 300], jnp.int32)
+    live = jnp.asarray([True, True, False, True])
+    (kr, _), _, out = jax.jit(caches.decode)(
+        params, k, v, jnp.asarray([5, 9, 11, 2], jnp.int32), pos, live)
+    _, says = caches.read(out, np.asarray([5, 128, 301]))
+    assert says["kv_rows_read"] == walked([5, 128, 0, 301]) == 640
+    assert says["kv_attn_kernel"] == 1 and says["state_slots_live"] == 3
+    assert np.array_equal(np.asarray(kr)[:, 2], np.asarray(k[0])[:, 2])
+
+
+def test_every_softmax_layer_is_handed_the_slots_visible_rows(adapter):
+    """Two periods of four layers, softmax layers 0 and 4: the second
+    one's kernel, too, gets the rows each SLOT may see (a layer's block
+    returns its experts' counts, another vector of another length: PR
+    39's review).  One decode step over a filled slab with the kernel
+    interpreted and with XLA's form: what the layers behind the second
+    softmax layer leave in their states and tails, and the counts behind
+    the tokens, are the same."""
+    def step(kernel):
+        with pytest.MonkeyPatch.context() as steer:
+            if kernel:
+                says_tpu(steer)
+            cfg = dict(tiny_cfg(), num_attention_heads=16,
+                       num_key_value_heads=2, head_dim=128,
+                       n_positions=WIDE_SEQ, num_hidden_layers=8,
+                       num_hidden_layers_published=8)
+            model = adapter.program_model(cfg)
+            params = jax.jit(model.init)(jax.random.PRNGKey(5))
+            caches = model.serve_caches(4, WIDE_SEQ)
+            assert caches.kv_attn_kernel == int(kernel)
+            k, v = caches.new_slabs()
+            r = jax.random.split(jax.random.PRNGKey(6), 2)
+            fill = lambda r, a: jax.random.normal(r, a.shape, jnp.float32
+                                                  ).astype(a.dtype)
+            k, v = (fill(r[0], k[0]), k[1]), (fill(r[1], v[0]), v[1])
+            args = (params, k, v, jnp.asarray([5, 9, 11, 2], jnp.int32),
+                    jnp.asarray([4, 127, 128, 300], jnp.int32),
+                    jnp.asarray([True, True, False, True]))
+            # two calls of ONE traced kernel: both layers hand it
+            # operands of the same shapes
+            text = str(jax.make_jaxpr(caches.decode)(*args))
+            assert text.count("jit[name=_call ") == 2 * kernel
+            assert text.count("name=decode_attn") == kernel
+            (_, state), (_, tails), out = jax.jit(caches.decode)(*args)
+        return ([np.asarray(x, np.float32) for x in state + tails],
+                np.asarray(out))
+
+    (got, out), (want, plain) = step(True), step(False)
+    assert len(got) == 2 * 6
+    # bfloat16 weights of size 0.5: the two forms round apart by 1-8 % of
+    # an array's largest entry (13), and by 33-78 % in the three layers
+    # behind a second softmax layer that is handed another vector
+    for a, b in zip(got, want):
+        assert np.abs(a - b).max() < 0.15 * np.abs(b).max()
+    # the live slots' tokens and their number; the rows read are the
+    # step's own: two layers' live tiles against two whole slabs
+    live = [0, 1, 3, 4 + 3]
+    assert out[live].tolist() == plain[live].tolist()
+    assert out[-1] == 2 * walked([5, 128, 0, 301])
+    assert plain[-1] == 2 * 4 * WIDE_SEQ
+
+
+def test_the_engine_through_the_kernel_serves_xlas_tokens(adapter):
+    """Two requests through ``InferenceEngine``, one whose context
+    crosses a tile's edge while it decodes, with the kernel interpreted
+    and with XLA's form: the same tokens, and on every
+    ``kf:serve.decode_read`` span which form ran and rows read that are
+    whole tiles under the kernel and the whole slab without it."""
+    def serve(kernel):
+        with pytest.MonkeyPatch.context() as steer:
+            if kernel:
+                says_tpu(steer)
+            model, params = wide_model(adapter)
+            eng = InferenceEngine(
+                model, params, max_batch=3, max_seq=WIDE_SEQ,
+                pool=KVCachePool(PageSpec.for_model(model.cfg,
+                                                    page_tokens=PAGE),
+                                 capacity_pages=2))
+            spans = _lookahead.record_spans(steer)
+            # (seeds under which every served token leads the runner-up
+            # by 0.85 in logits of size 10: the two forms round apart by
+            # 0.1-0.25)
+            eng.submit("a", ids_of(26, 5), 4)
+            eng.submit("b", ids_of(27, 126), 4)     # decodes rows 126 .. 128
+            done = {e["rid"]: e["tokens"] for e in eng.drain()
+                    if e["kind"] == "done"}
+        return done, [s.attrs for s in spans if s.name == "decode_read"]
+
+    (kernel, reads), (xla, plain) = serve(True), serve(False)
+    assert kernel == xla and len(kernel["a"]) == len(kernel["b"]) == 4
+    assert reads and all(r["kv_attn_kernel"] == 1 for r in reads)
+    assert all(r["kv_rows_read"] % WIDE_TILE == 0
+               and r["kv_rows_live"] <= r["kv_rows_read"] < 3 * WIDE_SEQ
+               for r in reads if r["kv_rows_live"])
+    # a alone in its first tile; a beside b, a tile each; b alone in two,
+    # its context past 128
+    pairs = [(r["kv_rows_live"], r["kv_rows_read"]) for r in reads]
+    assert pairs == [(6, 128), (7 + 127, 256), (8 + 128, 256), (129, 256)]
+    assert plain and all(r["kv_attn_kernel"] == 0
+                         and r["kv_rows_read"] == 3 * WIDE_SEQ for r in plain)
+
+
 def test_the_engine_serves_it_without_knowing_it():
     """``engine.py`` imports no model and tests for no class
     (tests/test_cohere2_moe.py reads its source); this model's answer to

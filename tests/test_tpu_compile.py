@@ -618,7 +618,7 @@ def test_latent_programs_fit_beside_the_weights(mla_programs):
 #: the vocabulary, 128 slots of 4,096 positions
 KDA_SLOTS, KDA_SEQ, KDA_LAYERS, KDA_HELD = 128, 4096, 3, 10
 KDA_PREFILL = (256, 2048)
-KDA_SAYS = 4        # what a step's ``out`` holds behind the tokens
+KDA_SAYS = 5        # what a step's ``out`` holds behind the tokens
 KDA_STATE = f"{KDA_SLOTS},64,128,128"
 KDA_ROWS = f"{KDA_SLOTS},8,{KDA_SEQ},128"
 
@@ -668,6 +668,7 @@ def kda_programs(topo):
                 params, k, v, jax.ShapeDtypeStruct((n,), i32, sharding=one),
                 i0, i0, i0)
         assert eng._caches.kda_step_kernel == 1
+        assert eng._caches.kv_attn_kernel == 1
     return {name: lo.compile() for name, lo in lowered.items()}
 
 
@@ -762,6 +763,113 @@ def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
         r"%(params__layer_\d____moe____experts____(?:gate|up|down)__[.\d]*) = "
         rf"bf16\[{KDA_HELD},(?:4096,1280|1280,4096)\]", entry)
     assert len(experts_w) == 4 * 3
+
+
+def test_hybrid_decode_attention_is_one_kernel_over_the_slab(kda_programs):
+    """The softmax layer's attention in the decode step is ONE
+    ``decode_attn`` kernel under ``attn_core/attn_full`` (the scopes
+    ``kv_attn_roofline`` and ``decode_path_ms.attn_full`` read), handed
+    the queries through a bitcast and K and V **whole, as the in-place
+    row write left them**: no slice, copy or transpose of the slab or of
+    its layer, and the scores ``[128, 64, 4096]`` in no type.  Its grid
+    is every tile of every slot, so the operations are the same whatever
+    is live; which tiles it skips follows the five vectors of scalars it
+    is handed first (layer, visible rows, and the walk)."""
+    text = kda_programs["decode"].as_text()
+    entry = text[text.index("\nENTRY"):]
+    calls = re.findall(
+        rf"^\s*%?(decode_attn[\w.]*) = bf16\[{KDA_SLOTS},8,8,128\]\S* "
+        r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        entry, re.M)
+    assert len(calls) == 1          # one softmax layer in the period
+    (name, operands), = calls
+    assert "/attn_core/attn_full/" in _op_name(text, name)
+    operands = re.findall(r"%([\w.\-]+)", operands)
+    assert len(operands) == 5 + 3
+    made_by = {n: (op, dims) for n, _, dims, op in _entry_ops(text)}
+    q, k, v = operands[5:]
+    assert made_by[q] == ("bitcast", f"{KDA_SLOTS},8,8,128")
+    written = set()
+    for part in (k, v):     # ... out of the fused in-place row write
+        assert made_by[part] == ("get-tuple-element", "1," + KDA_ROWS)
+        source = re.search(r"%" + re.escape(part) + r" = [^\n]*"
+                           r"get-tuple-element\(%([\w.\-]+)\)", entry).group(1)
+        assert "dynamic-update-slice" in source, source
+        written.add(source)
+    assert len(written) == 1
+    # the rows of K and V are read by that kernel and by nothing else
+    # after the row write
+    for part in (k, v):
+        readers = re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*? \w[\w\-]*\([^\n]*%"
+            + re.escape(part) + r"[,)]", entry, re.M)
+        assert [r for r in readers if not r.startswith("tuple")] == [name], \
+            readers
+    for scores in (rf"\[{KDA_SLOTS},64,{KDA_SEQ}\]",
+                   rf"\[{KDA_SLOTS},8,8,{KDA_SEQ}\]",
+                   rf"\[{KDA_SLOTS},8,8,1,{KDA_SEQ}\]"):
+        assert not re.search(scores, text), scores
+
+
+def test_a_stage_of_two_periods_hands_both_kernels_the_slots_rows(topo):
+    """Softmax layers 0 and 4 of eight (the cell holds one, at layer 0):
+    the decode step holds two ``decode_attn`` calls, handed layer 0 and
+    layer 1 of the slab and the SAME four vectors a slot (visible rows
+    and the walk) -- not, for the second, whatever the layers between
+    left under a name (PR 39's review: the experts' counts)."""
+    from kungfu_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
+
+    slots, seq = 8, 2048
+    cfg = SolarOpen2Config(
+        vocab_size=512, d_model=512, n_layers=8, gqa_layers=(0, 4),
+        n_heads=16, n_kv_heads=2, head_dim=128, kda_heads=4, gate_rank=16,
+        d_expert=128, n_experts=16, experts_held=(0, 16), top_k=4,
+        max_seq=seq)
+    model = SolarOpen2(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    with pytest.MonkeyPatch.context() as steer:
+        steer.setattr(jax, "default_backend", lambda: "tpu")
+        caches = model.serve_caches(slots, seq)
+        params, (k, v) = jax.tree_util.tree_map(shaped, (
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+            jax.eval_shape(caches.new_slabs)))
+        vec = jax.ShapeDtypeStruct((slots,), i32, sharding=one)
+        text = jax.jit(caches.decode).lower(
+            params, k, v, vec, vec,
+            jax.ShapeDtypeStruct((slots,), bool, sharding=one)
+        ).compile().as_text()
+        assert caches.kv_attn_kernel == 1
+    entry = text[text.index("\nENTRY"):]
+    calls = re.findall(
+        rf"^\s*%?decode_attn[\w.]* = bf16\[{slots},2,8,128\]\S* "
+        r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        entry, re.M)
+    assert len(calls) == 2
+    first, second = (re.findall(r"%([\w.\-]+)", c) for c in calls)
+    layer = lambda name: re.search(
+        r"%" + re.escape(name) + r" = s32\[1\]\S* constant\(\{(\d)\}\)",
+        text).group(1)
+    assert sorted((layer(first[0]), layer(second[0]))) == ["0", "1"]
+    assert first[1:5] == second[1:5]
+    made_by = {n: (dtype, dims) for n, dtype, dims, _ in _entry_ops(text)}
+    assert all(made_by[n] == ("s32", str(slots)) for n in first[1:5])
+    # (K and V are one array for both layers: each call reads it as its
+    # own layer's row write left it)
+    assert not set(first[6:]) & set(second[6:])
+
+
+def test_the_other_families_decode_programs_hold_no_such_kernel(
+        serve_programs, moe_programs):
+    """The dense model's slab has heads of 64 and ``WindowedCaches`` is
+    the next issue's: neither decode program holds a custom call."""
+    for text in (serve_programs["decode"],
+                 moe_programs["decode"].as_text()):
+        assert "decode_attn" not in text
+        assert "tpu_custom_call" not in text
 
 
 def test_hybrid_programs_fit_beside_the_weights(kda_programs):
