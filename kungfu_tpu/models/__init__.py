@@ -27,6 +27,10 @@ models because they are its benchmark workload:
   (gated delta-rule linear-attention layers, whose past is a matrix a
   head and not rows a position, beside a gated softmax layer in four;
   ``experts`` again), served likewise.
+* :mod:`kungfu_tpu.models.evabyte` — the ``evabyte`` decoder (a
+  byte-level model with EVA attention: exact softmax inside an aligned
+  window, every chunk of positions before it pooled into one learned
+  key/value row, one softmax over both), served likewise.
 * :mod:`kungfu_tpu.models.fake` — gradient-shaped fake models for
   collective benchmarking without real compute (parity with
   ``tests/go/fakemodel``).
@@ -34,6 +38,7 @@ models because they are its benchmark workload:
 
 from kungfu_tpu.models import nn
 from kungfu_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
+from kungfu_tpu.models.evabyte import EvaByte, EvaByteConfig
 from kungfu_tpu.models.mlp import MLP, mnist_slp
 from kungfu_tpu.models.pangu_moe import PanguMoe, PanguMoeConfig
 from kungfu_tpu.models.resnet import ResNet, resnet50
@@ -46,6 +51,8 @@ __all__ = [
     "nn",
     "Cohere2Moe",
     "Cohere2MoeConfig",
+    "EvaByte",
+    "EvaByteConfig",
     "MLP",
     "PanguMoe",
     "PanguMoeConfig",

@@ -15,7 +15,10 @@ plane:
   whatever ``model.serve_caches`` answers: :mod:`kungfu_tpu.serve.caches`
   for the dense transformer, :mod:`kungfu_tpu.serve.windowed` for a
   model that mixes window and full attention layers,
-  :mod:`kungfu_tpu.serve.latent` for latent (MLA) attention;
+  :mod:`kungfu_tpu.serve.latent` for latent (MLA) attention,
+  :mod:`kungfu_tpu.serve.recurrent` for layers that keep a state a
+  slot, :mod:`kungfu_tpu.serve.pooled` for attention over pooled chunk
+  rows;
 * :mod:`kungfu_tpu.serve.router` — request router + admission policy
   (FCFS, bounded queue, typed overload rejection) speaking over the
   existing host channel / p2p handler machinery, with SLO-gated fault

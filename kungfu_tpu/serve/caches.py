@@ -8,7 +8,7 @@ max_seq)``.  What is kept comes in TWO PARTS, called ``k`` and ``v``
 after the dense model's, each an array or a tree of arrays whose first
 two axes are ``[layers, slots]``.  The engine never looks inside a part:
 it hands the pair on, and writes into one slot of every array of it.  An
-array of a part is of one of two kinds:
+array of a part is of one of three kinds:
 
 * **rows a position** ``[layers, slots, heads, positions, width]``:
   per-head keys and values of one shape in the dense model and in
@@ -22,7 +22,7 @@ array of a part is of one of two kinds:
   (``serve/recurrent.py``: a matrix a head and a short convolution's
   last inputs).  It has one value, the newest: nothing in it is the
   state at a page's end, so no page of positions can rebuild it.  A
-  model that has such a part says so to the pool (``PageSpec.recurrent``,
+  model that has such a part says so to the pool (``PageSpec.unpaged``,
   from the config's ``recurrent_layers``); its pages are then never
   ``whole``, ``KVCachePool.reusable`` is false for them, and the engine
   looks up no prefix, reserves no page and commits none: ``rows_of_slot``
@@ -31,6 +31,18 @@ array of a part is of one of two kinds:
   nothing, whatever the slot held; a prefill of a padded bucket leaves
   the state of exactly ``n`` tokens; a decode step leaves the state of a
   slot it is not ``live`` for as it was.
+* **rows that stand for several positions** ``[layers, slots, heads,
+  rows, width]`` whose rows are no positions: ``serve/pooled.py`` keeps,
+  beside the exact rows of the open window of positions, one *chunk
+  row* for every few positions before it, MADE from those positions'
+  rows by a learned pooling when the chunk is complete and seen by no
+  query until its window has closed.  A page of positions could hold a
+  closed window's chunk rows; the rule by which a prefix would be
+  restored from them is not built, so such a model is unpaged too
+  (``cfg.pages_reusable`` False) and owes what the state a slot owes: a
+  prefill starts the slot from nothing whatever it held, a padded
+  bucket leaves the rows and chunk rows of exactly ``n`` positions, a
+  decode step leaves a slot it is not ``live`` for as it was.
 
 ``new_slabs()``
     the device cache as the pair ``(k, v)``.
@@ -73,7 +85,12 @@ array of a part is of one of two kinds:
     * ``latent_rows_live``, ``latent_rows_read`` -- compressed rows a
       position (``serve/latent.py``), a layer's;
     * ``state_slots_live``, ``state_slots_read``, ``state_bytes_read``
-      -- a state a slot (``serve/recurrent.py``).
+      -- a state a slot (``serve/recurrent.py``);
+    * ``summary_rows_live``, ``summary_rows_read``,
+      ``summary_rows_written`` -- rows that stand for several positions
+      (``serve/pooled.py``: the chunk rows of closed windows a context
+      has to read, those the step read, and the chunks it completed),
+      beside ``kv_rows_*`` for the open window's exact rows.
 
     The ``*_live`` of rows a position is the sum of ``contexts``, which
     the host has (the latent cache's step counts it itself, and the two
@@ -113,8 +130,9 @@ keep rows.  :class:`DenseCaches` is the dense ``Transformer``'s (one slab
 position); ``serve/windowed.py`` the one of a model that mixes window and
 full attention layers; ``serve/latent.py`` the one of latent attention;
 ``serve/recurrent.py`` the one of a model most of whose layers keep a
-state a slot.  :func:`row_windows` and :func:`write_rows`, the in-place
-write of one row a slot, are shared by all four, :func:`pages_in_order`
+state a slot; ``serve/pooled.py`` the one of attention over pooled chunk
+rows.  :func:`row_windows` and :func:`write_rows`, the in-place write of
+one row a slot, are shared by all five, :func:`pages_in_order`
 and :func:`slot_rows`, the host's side of a part that keeps every
 position, by the first and the third.
 """
@@ -132,7 +150,7 @@ from kungfu_tpu.models.transformer import _rope
 from kungfu_tpu.ops import costmodel
 
 
-def row_windows(pos, s_max, live):
+def row_windows(pos, s_max, live, width: int = 128):
     """Per slot ``b``: where the aligned window of ``S`` that holds
     position ``pos[b]`` starts, and which of its rows that is -- none of
     them where ``live[b]`` is false: such a slot's window is written
@@ -141,8 +159,11 @@ def row_windows(pos, s_max, live):
     scalar ``p // w * w`` on purpose: from that the compiler knows the
     window is tile-aligned and updates it in place; sliced out of a
     vector of starts it no longer does, and the write takes five times
-    as long (tests/test_tpu_compile.py)."""
-    w = math.gcd(s_max, 128)                # divides S: never clamped
+    as long (tests/test_tpu_compile.py).  ``width`` is the window's
+    rows: 128 suits every layout the compiler gives a slab; a cache
+    whose rows are a tile's sublanes may ask for fewer
+    (``serve/pooled.py``)."""
+    w = math.gcd(s_max, width)              # divides S: never clamped
     lane = jnp.arange(w)[:, None]
     return [(p // w * w, lane == jnp.where(l, p % w, -1))
             for p, l in zip(pos, live)]

@@ -59,9 +59,13 @@ for all heads and their shared rotary key (``serve/latent.py``: there
 recurrent state with a slab for the others beside a matrix and a
 convolution tail a slot (``serve/recurrent.py``: parts that are no rows
 a position at all, so nothing of a request can be put into a page; the
-pool's spec says so, ``PageSpec.recurrent``, and the engine then looks
-up no prefix, reserves no page and commits none).  Scheduler, slots,
-pool and spans are the same for all four.
+pool's spec says so, ``PageSpec.unpaged``, and the engine then looks
+up no prefix, reserves no page and commits none), a model whose
+attention reads rows pooled from other rows with the open window's exact
+rows beside one chunk row for every few positions of the closed ones
+(``serve/pooled.py``: unpaged too, until the rule by which a page could
+hold a chunk row is built).  Scheduler, slots, pool and spans are the
+same for all five.
 
 Fault surface: the engine is process-local and carries no collective
 state — worker death is handled ABOVE it by the router's replay ladder
@@ -166,9 +170,10 @@ class InferenceEngine:
         self.pool = pool if pool is not None else KVCachePool(
             PageSpec.for_model(cfg, page_tokens=page_tokens))
         self._page_tokens = self.pool.spec.page_tokens
-        # False where some layer keeps a state a slot: no page can hold a
-        # request's prefix, so none is looked up, reserved or committed
-        self._paged = not self.pool.spec.recurrent
+        # False where no page can hold a request's prefix (a layer keeps a
+        # state a slot, or rows made from other rows): none is looked up,
+        # reserved or committed
+        self._paged = not self.pool.spec.unpaged
         self._width = self.max_batch  # admitted width (policy-adjustable)
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)

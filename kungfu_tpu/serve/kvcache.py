@@ -90,12 +90,16 @@ class PageSpec:
     #: is not ``whole``, and a prefix can be reused only if the pages
     #: over its last ``window`` positions are (:meth:`KVCachePool.reusable`)
     window: int = 0
-    #: True: some layers of the model keep a state a slot and no rows a
-    #: position (``serve/recurrent.py``), and ``n_layers`` counts the
-    #: others.  Nothing such a layer holds at a page's end can be put
-    #: into a page, so no page is ever ``whole`` and no prefix reusable:
-    #: the engine looks none up and commits none
-    recurrent: bool = False
+    #: True: what the model keeps of a request cannot be handed to a later
+    #: one page by page, so no page is ever ``whole`` and no prefix
+    #: reusable: the engine looks none up and commits none.  Either some
+    #: layers keep a state a slot and no rows a position
+    #: (``serve/recurrent.py``; ``n_layers`` counts the others): nothing
+    #: such a layer holds at a page's end can be put into a page.  Or
+    #: the model says so of itself (``cfg.pages_reusable`` False;
+    #: ``serve/pooled.py``: rows made from other rows, whose page rule is
+    #: not built)
+    unpaged: bool = False
 
     @property
     def widths(self) -> Tuple[int, int]:
@@ -129,10 +133,11 @@ class PageSpec:
         heads, width, second = row or (
             getattr(cfg, "n_kv_heads", cfg.n_heads), cfg.head_dim, 0)
         stateful = len(getattr(cfg, "recurrent_layers", ()))
+        unpaged = bool(stateful) or not getattr(cfg, "pages_reusable", True)
         return cls(n_layers=cfg.n_layers - stateful, n_heads=heads,
                    head_dim=width, page_tokens=int(page_tokens),
                    dtype=dtype or cfg.dtype, window=getattr(cfg, "window", 0),
-                   v_head_dim=second, recurrent=bool(stateful))
+                   v_head_dim=second, unpaged=unpaged)
 
 
 def chain_hashes(tokens: Sequence[int], page_tokens: int) -> List[bytes]:
@@ -362,10 +367,10 @@ class KVCachePool:
         restored into a slot?  Its last ``spec.window`` positions must
         come from whole pages: a window layer attends to them next, and
         a page that is not whole no longer has them.  (The full layers'
-        rows are in every page, so earlier pages need not be whole.)  With
-        layers that keep a state a slot (``spec.recurrent``) never: no
-        page holds what such a layer was at its end."""
-        if self.spec.recurrent:
+        rows are in every page, so earlier pages need not be whole.)  Of
+        a model whose pages cannot be handed on (``spec.unpaged``)
+        never."""
+        if self.spec.unpaged:
             return False
         if not self.spec.window:
             return True

@@ -434,7 +434,7 @@ def test_the_engine_looks_up_no_prefix_and_commits_nothing(adapter,
     cfg = tiny_cfg()
     model, params = build(adapter, cfg)
     spec = PageSpec.for_model(model.cfg, page_tokens=PAGE)
-    assert spec.recurrent and (spec.n_layers, spec.n_heads, spec.head_dim) \
+    assert spec.unpaged and (spec.n_layers, spec.n_heads, spec.head_dim) \
         == (1, 2, 8)
     eng = engine(model, params, slots=2, capacity=2)
     assert not eng.pool.reusable([])
@@ -458,7 +458,7 @@ def test_the_engine_looks_up_no_prefix_and_commits_nothing(adapter,
     dense = PageSpec.for_model(TransformerConfig(
         vocab_size=97, d_model=32, n_layers=2, n_heads=4, d_ff=64,
         max_seq=32), page_tokens=PAGE)
-    assert not dense.recurrent and dense.n_layers == 2
+    assert not dense.unpaged and dense.n_layers == 2
 
 
 def test_decode_says_what_it_moved_and_routed(adapter, monkeypatch):

@@ -644,7 +644,7 @@ def kda_programs(topo):
         shaped, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     spec = PageSpec.for_model(cfg, page_tokens=256)
     # a page counts the softmax layer's rows alone, and is never whole
-    assert spec.recurrent and spec.page_bytes == 256 * 8 * 2 * 128 * 2
+    assert spec.unpaged and spec.page_bytes == 256 * 8 * 2 * 128 * 2
     eng = InferenceEngine(model, None, max_batch=KDA_SLOTS, max_seq=KDA_SEQ,
                           pool=KVCachePool(spec, capacity_pages=1))
     k, v = jax.tree_util.tree_map(shaped,
@@ -890,3 +890,106 @@ def test_hybrid_programs_fit_beside_the_weights(kda_programs):
     assert stats["decode"].temp_size_in_bytes < 0.2e9
     assert stats["prefill256"].temp_size_in_bytes < 0.3e9
     assert stats["prefill2048"].temp_size_in_bytes < 1.5e9
+
+
+# -- and for the model whose attention reads pooled chunk rows -----------------
+#: EvaByte as its cell serves it: one stage of 8 of the 32 layers, every
+#: width as published, 16 slots of 32,768 positions -- a slot and layer
+#: 2,048 exact rows and 2,048 chunk rows
+EVA_SLOTS, EVA_SEQ, EVA_LAYERS = 16, 32768, 8
+EVA_SLAB = f"{EVA_LAYERS},{EVA_SLOTS},32,4096,128"
+
+
+@pytest.fixture(scope="module")
+def eva_programs(topo):
+    """name -> compiled program of the engine serving ``evabyte`` at the
+    cell's sizes, lowered from shapes alone: the decode step and the
+    prefill of the longest bucket."""
+    from kungfu_tpu.models.evabyte import EvaByte, EvaByteConfig
+    from kungfu_tpu.serve.engine import InferenceEngine
+    from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
+
+    cfg = EvaByteConfig(n_layers=EVA_LAYERS, init_layers=32, max_seq=EVA_SEQ)
+    model = EvaByte(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        shaped, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = PageSpec.for_model(cfg, page_tokens=2048)
+    assert spec.unpaged
+    eng = InferenceEngine(model, None, max_batch=EVA_SLOTS, max_seq=EVA_SEQ,
+                          pool=KVCachePool(spec, capacity_pages=1))
+    k, v = jax.tree_util.tree_map(shaped,
+                                  jax.eval_shape(eng._caches.new_slabs))
+    assert k.shape == v.shape == (EVA_LAYERS, EVA_SLOTS, 32, 4096, 128)
+    slots = jax.ShapeDtypeStruct((EVA_SLOTS,), i32, sharding=one)
+    i0 = jax.ShapeDtypeStruct((), i32, sharding=one)
+    out = jax.ShapeDtypeStruct((EVA_SLOTS + 1,), i32, sharding=one)
+    lowered = {
+        "decode": eng._decode_j.lower(params, k, v, out, slots, slots),
+        f"prefill{EVA_SEQ}": eng._prefill_j.lower(
+            params, k, v, jax.ShapeDtypeStruct((EVA_SEQ,), i32, sharding=one),
+            i0, i0, i0)}
+    return {name: lo.compile() for name, lo in lowered.items()}
+
+
+def test_pooled_programs_fit_beside_the_weights(eva_programs):
+    """3.26 GB of weights and 8.59 GB of cache (16 slots x 8 layers x 64
+    MiB) are arguments of every program: 11.85 GB, 74 % of the chip.  A
+    decode step adds some 0.04 GB; the prefill of the 32,768 bucket
+    walks a window of 2,048 at a time, so its temporaries are a window's
+    (the FFN's three intermediates alone would be 2.2 GB for the bucket)
+    and stay under the chip's remainder."""
+    stats = {n: p.memory_analysis() for n, p in eva_programs.items()}
+    cache = 2 * EVA_LAYERS * EVA_SLOTS * 32 * 4096 * 128 * 2
+    assert cache == 8_589_934_592 == EVA_SLOTS * EVA_LAYERS * (64 << 20)
+    for name, m in stats.items():
+        assert m.alias_size_in_bytes == cache, name
+        assert 11.85e9 < m.argument_size_in_bytes < 11.86e9, name
+    args = stats["decode"].argument_size_in_bytes
+    assert 3.26e9 < args - cache < 3.27e9
+    assert stats["decode"].temp_size_in_bytes < 0.1e9
+    temps = stats[f"prefill{EVA_SEQ}"].temp_size_in_bytes
+    assert temps < 1.2e9 and args + temps < 0.85 * 16e9
+
+
+@pytest.mark.parametrize("program", ["decode", f"prefill{EVA_SEQ}"])
+def test_pooled_program_writes_its_slabs_in_place(eva_programs, program):
+    """Both slabs alias their outputs, and nothing but the in-place
+    updates produces an array the size of a slab or of one layer of one:
+    no copy of either, in the decode step or around the prefill's walk
+    over the windows."""
+    text = eva_programs[program].as_text()
+    assert len(re.findall(r"may-alias|must-alias",
+                          text.split("\n", 1)[0])) == 2
+    moved = []
+    for name, dtype, dims, op in _entry_ops(text):
+        elems = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        if elems < EVA_SLOTS * 32 * 4096 * 128 or op in (
+                "parameter", "bitcast", "get-tuple-element", "tuple"):
+            continue
+        in_place = "dynamic-update-slice" in name or "dynamic_update_slice" \
+            in name or op in ("dynamic-update-slice", "while") \
+            or _fused_root(text, name) == "dynamic-update-slice"
+        if not in_place:
+            moved.append((op, name, dtype, dims))
+    assert not moved
+
+
+def test_pooled_decode_has_the_same_operations_whatever_the_positions(
+        eva_programs):
+    """A row written, a chunk completed, a window closed: ``pos`` and
+    ``live`` reach the step as masks and as the starts of aligned
+    windows, so one compiled program serves every set of positions, and
+    in it nothing loops or branches.  Each slot and layer has four
+    in-place window updates (the new row and the chunk row, K and V)."""
+    text = eva_programs["decode"].as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert not re.findall(r"= [^\n]* (while|conditional)\(", entry)
+    assert "tpu_custom_call" not in text
+    updates = [n for n, _, dims, _ in _entry_ops(text)
+               if dims == EVA_SLAB and "dynamic-update-slice" in n]
+    assert len(updates) == 4 * EVA_LAYERS * EVA_SLOTS
