@@ -32,12 +32,23 @@ ONE ``block``:
   not: the row of an unfinished chunk belongs to the open window, so no
   query can see it, and the step that completes the chunk overwrites it
   with the pooling of all ``C`` rows, those a prefill left among them;
-  and attends over the slab under the mask.  So the three events of a
-  slot's life (a row, a chunk completed, a window closed) cost the same
-  operations every step: no branch, no shape that follows a position
-  (ROADMAP's lesson from PR 26), and every row of every slot is read
-  whatever is live, which ``kv_rows_read`` and ``summary_rows_read``
-  state.  A slot the step is not ``live`` for keeps every row as it was;
+  and attends over the slab -- on the TPU one kernel a layer,
+  ``ops/pallas/decode_attention.py``, which is handed the slab whole
+  and what each slot may see as TWO RUNS of its rows (``visible_runs``:
+  ``pos mod W + 1`` exact rows from row 0, ``(W / C) (pos div W)`` chunk
+  rows from row ``W``; none where the step is not live for the slot)
+  and walks only the key tiles of those runs, under ONE softmax; off
+  the TPU, and at sizes the kernel does not tile (the tests' heads of
+  16; a window that is not whole tiles), ``evabyte.eva_attention`` over
+  every row under ``visible_rows``; ``eva_attn_kernel`` on
+  ``kf:serve.decode_read`` says which, and ``kv_rows_read`` and
+  ``summary_rows_read`` the rows of each run the step itself counted as
+  read.  So the three events of a slot's life (a row, a chunk
+  completed, a window closed) cost the same operations every step: no
+  branch, no shape that follows a position (ROADMAP's lesson from PR
+  26); of the times, the attention kernel's follows the live contexts
+  (the tiles it skips; PERF.md, PR 41).  A slot the step is not
+  ``live`` for keeps every row as it was;
 * the **prefill** walks the prompt a window at a time inside the one
   program, so that its temporaries are a window's and not the bucket's:
   each window's rows go into the slot's exact rows, attend to themselves
@@ -59,6 +70,8 @@ engine looks up no prefix, commits nothing, never calls a prefill with
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,7 +91,7 @@ ATTN_BLOCK = 256
 #: of this cache alone on the chip (PERF.md, PR 40)
 ROW_WINDOW = 16
 #: what a decode step's ``out`` says behind the slots' tokens
-_SAYS = ("summary_rows_written",)
+_SAYS = ("summary_rows_written", "kv_rows_walked", "summary_rows_walked")
 
 
 class PooledCaches:
@@ -115,21 +128,40 @@ class PooledCaches:
             self.cfg.window_chunks * (pos // w))[..., None]
         return jnp.concatenate([exact, chunk], axis=-1)
 
+    def visible_runs(self, pos, live):
+        """:meth:`visible_rows` of a decode step's ``pos`` ``[B]`` as
+        the kernel takes it, ``[2, B]``: the rows are two runs of the
+        slab's, the open window's exact rows from row 0 and the closed
+        windows' chunk rows from row ``W``; how many of each a slot may
+        see, none where the step is not ``live`` for it."""
+        w = self.exact
+        return jnp.where(live, jnp.stack(
+            [pos % w + 1, self.cfg.window_chunks * (pos // w)]), 0)
+
     # -- the two forward passes ------------------------------------------
     def decode(self, params, k, v, last_ids, pos, live):
         """One token for every slot (``last_ids``/``pos``/``live``
         ``[B]``; a slot that is not live computes what nobody reads and
         writes nothing).  Returns the slabs and ONE int32 vector: the
-        ``B`` tokens, then the chunks the step completed, summed over
-        the layers (:data:`_SAYS`)."""
+        ``B`` tokens, then what the step says of itself, each summed
+        over the layers (:data:`_SAYS`): the chunks it completed, and
+        the exact rows and the chunk rows its attention read."""
         cfg, model = self.cfg, self.model
         w, c = self.exact, cfg.chunk_size
         rows = self.shape[3]
         row_at = row_windows(pos % w, rows, live, ROW_WINDOW)
         chunk_at = row_windows(w + pos // c, rows, live, ROW_WINDOW)
         chunk_from = pos % w // c * c       # the new row's chunk, in the slab
-        see = self.visible_rows(pos)[:, None, None, :]
         size = (1, 1, cfg.n_heads, c, cfg.head_dim)
+        tile = self.attn_tile
+        if tile:
+            from kungfu_tpu.ops.pallas import decode_attention as kernel
+
+            visible = self.visible_runs(pos, live)
+            walked = [kernel.rows_walked(n, tile) for n in visible]
+        else:       # XLA's form reads every row of every slot under a mask
+            see = self.visible_rows(pos)[:, None, None, :]
+            walked = [self.batch * w, self.batch * self.chunks]
 
         class Step:
             """A decode step's cache: one row a slot into the slab, the
@@ -154,6 +186,10 @@ class PooledCaches:
             @jax.named_scope("attn_core")
             def attend(_, li, q, positions):
                 with jax.named_scope("eva_attn"):
+                    if tile:    # one query head a key/value head: [B, H, 1, D]
+                        return kernel.decode_attn(
+                            q[:, 0, :, None], k, v, li, visible, tile=tile,
+                            starts=(0, w))[:, None, :, 0]
                     return arch.eva_attention(q, k[li], v[li], see)
 
         h = model.embed(params, last_ids[:, None])
@@ -161,9 +197,9 @@ class PooledCaches:
             h = arch.block(cfg, params[f"layer_{li}"], li, h, pos[:, None],
                            Step())
         tok = jnp.argmax(model.next_logits(params, h[:, 0]), axis=-1)
-        completed = cfg.n_layers * jnp.sum(live & (pos % c == c - 1))
-        return k, v, jnp.concatenate(
-            [tok, completed[None]]).astype(jnp.int32)
+        says = cfg.n_layers * jnp.stack(
+            [jnp.sum(live & (pos % c == c - 1)), *walked])
+        return k, v, jnp.concatenate([tok, says]).astype(jnp.int32)
 
     def new_out(self):
         return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
@@ -174,10 +210,11 @@ class PooledCaches:
         step's ``contexts`` had to read of each kind of row beside what
         it did read: a context of ``c`` positions has ``c - W floor((c -
         1) / W)`` exact rows a layer (``kv_rows_*``) and ``(W / C)
-        floor((c - 1) / W)`` chunk rows (``summary_rows_*``); the step
-        reads every row of every slot under the mask, which the cache
-        states from its shapes; the chunks it completed it counted
-        itself."""
+        floor((c - 1) / W)`` chunk rows (``summary_rows_*``); the rows
+        it read of each kind (the tiles its kernel walked, or every row
+        of every slot) and the chunks it completed the step counted
+        itself; ``eva_attn_kernel`` says which form of the attention
+        ran."""
         cfg = self.cfg
         out = np.asarray(jax.device_get(out))
         says = dict(zip(_SAYS, out[self.batch:].tolist()))
@@ -186,8 +223,39 @@ class PooledCaches:
         says.update(kv_rows(exact, ((l, slots, heads, self.exact, width),),
                             cfg.compute_dtype))
         says["summary_rows_live"] = l * int(chunk.sum())
-        says["summary_rows_read"] = l * slots * self.chunks
+        # ... of which the rows READ are the step's own counts: the tiles
+        # of each run the kernel walked, or every row where XLA's form ran
+        says["kv_rows_read"] = says.pop("kv_rows_walked")
+        says["summary_rows_read"] = says.pop("summary_rows_walked")
+        says["eva_attn_kernel"] = self.eva_attn_kernel
         return out[:self.batch], says
+
+    @functools.cached_property
+    def attn_tile(self):
+        """The key tile with which a decode step's attention over the
+        slab is ONE kernel a layer that walks only the tiles of a slot's
+        two visible runs (``ops/pallas/decode_attention.py``), or None
+        where it is ``eva_attention`` over every row under the mask: off
+        the TPU, and for shapes the kernel does not tile (a window that
+        is not whole tiles among them).  One choice, from the platform
+        and the slab's shape, made once: the step that is traced and the
+        span that says which form ran read the same.  The kernel's
+        package is imported here and by no module's import, so a process
+        that traces no such step never pays for it (PERF.md, PR 35)."""
+        if jax.default_backend() != "tpu":
+            return None
+        from kungfu_tpu.ops.pallas import decode_attention
+
+        cfg = self.cfg
+        return decode_attention.key_tile(
+            self.shape[3], cfg.n_heads, 1, cfg.head_dim, cfg.compute_dtype,
+            starts=(0, self.exact))
+
+    @property
+    def eva_attn_kernel(self) -> int:
+        """1 where a decode step's attention over the slab is the fused
+        kernel, 0 where it is ``eva_attention`` (:attr:`attn_tile`)."""
+        return int(self.attn_tile is not None)
 
     def prefill(self, params, k, v, ids, n, start, slot):
         """``ids`` ``[P]`` (the prompt, zero-padded past ``n``) into

@@ -3,9 +3,11 @@
 test_cohere2_moe.py`` for the window rings): a recorder of the engine's
 spans, the committed pages of a pool as bytes, ONE schedule of mixed
 requests, so that two engines driven through it take the same slots at
-the same calls, the comparison of what such a run committed, and every
+the same calls, the comparison of what such a run committed, every
 ``kf:serve.decode_read`` of a run beside the contexts of the tokens it
-handed out (``tests/test_serve_kv_rows.py``)."""
+handed out (``tests/test_serve_kv_rows.py``), and the steer under which
+a decode step takes its attention kernel, interpreted
+(``tests/test_solar_open2.py``, ``tests/test_evabyte.py``)."""
 
 from __future__ import annotations
 
@@ -33,6 +35,23 @@ def record_spans(monkeypatch) -> list:
     monkeypatch.setattr(timeline, "span",
                         lambda kind, name, **attrs: Span(log, name, attrs))
     return log
+
+
+def says_tpu(monkeypatch):
+    """What the code can see says TPU, so a decode step takes its
+    kernel branch, and ``ops/pallas/decode_attention.py``'s kernel runs
+    in Pallas's plain interpreter (JAX operations in the calling
+    program: nothing that calls back into Python from a step the engine
+    has dispatched ahead)."""
+    import functools
+
+    import jax
+
+    from kungfu_tpu.ops.pallas import decode_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(decode_attention, "decode_attn", functools.partial(
+        decode_attention.decode_attn, interpret=True))
 
 
 def decode_reads(eng, spans, asked: dict) -> list:

@@ -6,8 +6,10 @@ plain forward pass on all eight heads, plain causal attention up to a
 window, the engine's prefill and decode through ``PooledCaches`` across
 chunk and window edges, what ``mu`` and ``phi`` can and cannot move,
 slots reused, buckets padded and slots left out of a step, requests
-admitted steps apart, what a decode step says of the rows it read, and
-the parameter count at the cell's configuration.
+admitted steps apart, what a decode step says of the rows it read, the
+decode step through the kernel that walks only a slot's live exact rows
+and chunk rows (``ops/pallas/decode_attention.py``, interpreted) against
+XLA's form, and the parameter count at the cell's configuration.
 
 The weights are the adapter's (bfloat16 leaves from a seed), computed in
 float32 at ``highest`` on both sides, so the two agree to rounding.
@@ -398,6 +400,209 @@ def test_decode_read_counts_exact_rows_and_chunk_rows_by_the_formulas(
     assert any(a["summary_rows_written"] for a, _ in reads)
 
 
+# -- (g'): the decode attention as the kernel that walks live tiles -----------
+#: wide enough for ``ops/pallas/decode_attention.py``: 2 heads of 128 in
+#: bfloat16, windows of 128 in chunks of 4, slots of 512 positions -- a
+#: slot and layer keeps 128 exact rows and 128 chunk rows (32 a closed
+#: window), one tile of 128 each
+WIDE_W, WIDE_SEQ, WIDE_TILE = 128, 512, 128
+
+
+def wide(adapter):
+    """(the program's model in bfloat16, the adapter's weights)."""
+    cfg = tiny_cfg(hidden_size=256, num_attention_heads=2,
+                   num_key_value_heads=2, window_size=WIDE_W,
+                   init_std=0.05, n_positions=WIDE_SEQ,
+                   max_position_embeddings=WIDE_SEQ)
+    return adapter.program_model(cfg), jax.jit(
+        lambda k: adapter.init_params(cfg, k))(jax.random.PRNGKey(3))
+
+
+says_tpu = _lookahead.says_tpu
+
+
+def walked(rows):
+    return sum(-(-x // WIDE_TILE) * WIDE_TILE for x in rows)
+
+
+def test_decode_through_the_kernel_equals_xlas_form_token_and_row(adapter):
+    """Slot 0 from position 122, slot 2 from 250, slot 1 left out, twelve
+    steps: chunks complete in both, slot 0's first window closes at 128
+    (its exact run falls from 128 rows to 1 as 32 chunk rows come into
+    sight) and slot 2's second at 256.  With ``eva_attention`` under the
+    mask and then, fed the same tokens, with the kernel interpreted:
+    every step's logits to bfloat16's rounding (0.01-0.02 at logits of
+    size 3), hence the same token by a margin or not at all, the same
+    rows in both slabs, and the slot the step is not for bit for bit as
+    it was."""
+    i32 = jnp.int32
+
+    def run(kernel, fed=None):
+        with pytest.MonkeyPatch.context() as steer:
+            if kernel:
+                says_tpu(steer)
+            model, params = wide(adapter)
+            logits = recording(model)
+            caches = model.serve_caches(3, WIDE_SEQ)
+            assert caches.attn_tile == (WIDE_TILE if kernel else None)
+            assert caches.eva_attn_kernel == int(kernel)
+            k, v = caches.new_slabs()
+            prefill = jax.jit(caches.prefill)
+            last = np.zeros(3, np.int32)
+            for slot, seed, n in ((0, 41, 122), (1, 42, 40), (2, 43, 250)):
+                ids = np.zeros(-(-n // WIDE_W) * WIDE_W, np.int32)
+                ids[:n] = ids_of(seed, n)
+                k, v, tok = prefill(params, k, v, jnp.asarray(ids), i32(n),
+                                    i32(0), i32(slot))
+                last[slot] = int(tok)
+            text = str(jax.make_jaxpr(caches.decode)(
+                params, k, v, jnp.asarray(last), jnp.zeros(3, i32),
+                jnp.ones(3, bool)))
+            assert text.count("name=decode_attn") == int(kernel)
+            step = jax.jit(caches.decode)
+            pos, live = np.asarray([122, 40, 250]), [True, False, True]
+            idle = np.asarray(k)[:, 1], np.asarray(v)[:, 1]
+            del logits[:]
+            tokens, said = [], []
+            for i in range(12):
+                k, v, out = step(params, k, v, jnp.asarray(last),
+                                 jnp.asarray(pos, i32), jnp.asarray(live))
+                toks, says = caches.read(out, (pos[[0, 2]] + 1).tolist())
+                tokens.append(toks)
+                said.append(says)
+                last = np.where(live, toks if fed is None else fed[i],
+                                last).astype(np.int32)
+                pos = pos + np.asarray(live)
+            for was, now in zip(idle, (k, v)):
+                assert np.array_equal(np.asarray(now)[:, 1], was)
+        return (tokens, [row[[0, 2]] for row in logits], said,
+                np.asarray(k, np.float32), np.asarray(v, np.float32))
+
+    want, rows_x, plain, k2, v2 = run(False)
+    got, rows_k, said, k1, v1 = run(True, fed=want)
+    assert len(rows_x) == len(rows_k) == 12
+    for i, (a, b) in enumerate(zip(rows_k, rows_x)):
+        assert np.abs(b).max() > 1.5
+        np.testing.assert_allclose(a, b, atol=0.05, rtol=0,
+                                   err_msg=f"step {i}")
+        for slot, row in zip((0, 2), b):
+            assert row.max() - row[got[i][slot]] <= 0.05, (i, slot)
+    assert len({tuple(t[[0, 2]]) for t in want}) > 6
+    for a, b in ((k1, k2), (v1, v2)):
+        assert np.abs(b).max() > 1
+        np.testing.assert_allclose(a, b, atol=0.02 * np.abs(b).max(), rtol=0)
+    layers, slots = 3, 3
+    for i, (kernel, xla) in enumerate(zip(said, plain)):
+        # step ``i`` reads slot 0 at position 122 + i and slot 2 at 250 + i
+        exact = [(122 + i) % WIDE_W + 1, (250 + i) % WIDE_W + 1]
+        chunk = [32 * ((122 + i) // WIDE_W), 32 * ((250 + i) // WIDE_W)]
+        assert kernel["kv_rows_live"] == xla["kv_rows_live"] \
+            == layers * sum(exact)
+        assert kernel["summary_rows_live"] == xla["summary_rows_live"] \
+            == layers * sum(chunk)
+        assert kernel["kv_rows_read"] == layers * walked(exact) \
+            == layers * 2 * WIDE_TILE
+        assert kernel["summary_rows_read"] == layers * walked(chunk) \
+            == layers * WIDE_TILE * ((i >= 6) + 1)
+        assert xla["kv_rows_read"] == layers * slots * WIDE_W
+        assert xla["summary_rows_read"] == layers * slots * WIDE_SEQ // CHUNK
+        assert (kernel["eva_attn_kernel"], xla["eva_attn_kernel"]) == (1, 0)
+        for says in (kernel, xla):
+            assert says["summary_rows_written"] == layers * sum(
+                p % CHUNK == CHUNK - 1 for p in (122 + i, 250 + i))
+            assert "kv_rows_walked" not in says \
+                and "summary_rows_walked" not in says
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_read_states_the_rows_of_each_kind_the_step_counted(adapter,
+                                                            monkeypatch,
+                                                            backend):
+    """``kv_rows_read`` and ``summary_rows_read`` are what the step put
+    behind its tokens, whichever form ran, and ``eva_attn_kernel`` says
+    which: the choice is the cache's, made once from the platform and
+    the slab's shape (a window that is not whole tiles has XLA's form
+    on the TPU too)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    model, _ = wide(adapter)
+    caches = model.serve_caches(4, WIDE_SEQ)
+    kernel = int(backend == "tpu")
+    assert caches.attn_tile == (WIDE_TILE if kernel else None)
+    assert len(caches.new_out()) == 4 + 3
+    out = np.asarray([7, 8, 9, 10, 6, 768, 384], np.int32)
+    tokens, says = caches.read(out, np.asarray([5, 128, 301]))
+    assert tokens.tolist() == [7, 8, 9, 10]
+    assert (says["kv_rows_read"], says["summary_rows_read"]) == (768, 384)
+    assert says["eva_attn_kernel"] == kernel
+    assert says["kv_rows_live"] == 3 * (5 + 128 + 45)
+    assert says["summary_rows_live"] == 3 * (0 + 0 + 64)
+    assert says["summary_rows_written"] == 6
+    assert says["kv_rows_written"] == 9 and says["kv_row_bytes"] == 1024
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert caches.eva_attn_kernel == kernel
+    # heads of 16, float32: the tiny model has XLA's form everywhere
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tiny = adapter.program_model(tiny_cfg()).serve_caches(2, MAX_SEQ)
+    assert tiny.attn_tile is None and tiny.eva_attn_kernel == 0
+    # ... and so has a window of 96 rows, which no tile divides
+    cfg = tiny_cfg(hidden_size=256, num_attention_heads=2,
+                   num_key_value_heads=2, window_size=96, n_positions=384,
+                   max_position_embeddings=384)
+    assert adapter.program_model(cfg).serve_caches(2, 384).attn_tile is None
+
+
+def test_the_engine_through_the_kernel_serves_xlas_tokens(adapter):
+    """Three requests through ``InferenceEngine`` over two slots, one of
+    which closes a window while it decodes, with the kernel interpreted
+    and with XLA's form: on every ``kf:serve.decode_read`` the same
+    live rows, which form ran, and rows read that are
+    whole tiles of each run -- at least the live rows, at most a tile a
+    run, slot and layer more -- under the kernel and the whole slab
+    without it."""
+    asked = {"a": (ids_of(51, 120), 14), "b": (ids_of(52, 30), 6),
+             "c": (ids_of(53, 250), 9)}
+
+    def serve(kernel):
+        with pytest.MonkeyPatch.context() as steer:
+            if kernel:
+                says_tpu(steer)
+            model, params = wide(adapter)
+            eng = InferenceEngine(
+                model, params, max_batch=2, max_seq=WIDE_SEQ,
+                pool=KVCachePool(PageSpec.for_model(model.cfg,
+                                                    page_tokens=PAGE),
+                                 capacity_pages=2))
+            spans = _lookahead.record_spans(steer)
+            reads = _lookahead.decode_reads(eng, spans, asked)
+        return reads
+
+    kernel, plain = serve(True), serve(False)
+    layers, slots = 3, 2
+    assert len(kernel) == len(plain) > 12
+    assert [c for _, c in kernel] == [c for _, c in plain]
+    exactly = 0
+    for (says, contexts), (xla, _) in zip(kernel, plain):
+        assert says["eva_attn_kernel"] == 1 and xla["eva_attn_kernel"] == 0
+        assert xla["kv_rows_read"] == layers * slots * WIDE_W
+        assert xla["summary_rows_read"] == layers * slots * WIDE_SEQ // CHUNK
+        for live, read in (("kv_rows_live", "kv_rows_read"),
+                           ("summary_rows_live", "summary_rows_read")):
+            assert says[live] == xla[live]
+            assert says[read] % (layers * WIDE_TILE) == 0
+            assert says[live] <= says[read] <= says[live] \
+                + layers * slots * WIDE_TILE
+        c = np.asarray(contexts)
+        closed = (c - 1) // WIDE_W
+        exactly += (says["kv_rows_read"], says["summary_rows_read"]) == (
+            layers * walked(c - WIDE_W * closed),
+            layers * walked(32 * closed))
+    # (a step dispatched before a request's last token was read still
+    # walks that slot: one such step a request)
+    assert exactly >= len(kernel) - len(asked)
+    assert any(s["summary_rows_read"] for s, _ in kernel)
+    assert not all(s["summary_rows_read"] for s, _ in kernel)
+
+
 # -- (h): the configuration's parameter count, and the pool's spec ------------
 
 def test_the_parameter_count_is_the_trees_at_the_cells_configuration(adapter):
@@ -459,7 +664,8 @@ def test_the_engine_serves_it_without_knowing_it(adapter):
     assert k.shape == v.shape == (3, 3, 4, WINDOW + MAX_SEQ // CHUNK, 16)
     ks, vs = caches.empty_pages(64)
     assert ks.shape == vs.shape == (3, 4, WINDOW, 16)
-    assert len(caches.new_out()) == 3 + 1
+    # (the tokens, then the chunks completed and the rows of each kind read)
+    assert len(caches.new_out()) == 3 + 3
     # a context past a window reads its chunk rows, not its positions
     assert caches.decode_flops([33]) < caches.decode_flops([32])
     assert caches.decode_flops([5, 9]) > caches.decode_flops([5, 8]) > 0

@@ -510,18 +510,7 @@ def wide_model(adapter):
     return model, jax.jit(model.init)(jax.random.PRNGKey(5))
 
 
-def says_tpu(monkeypatch):
-    """What the code can see says TPU, so the decode step takes its
-    kernel branch, and the kernel runs in Pallas's plain interpreter
-    (JAX operations in the calling program: nothing that calls back into
-    Python from a step the engine has dispatched ahead)."""
-    import functools
-
-    from kungfu_tpu.ops.pallas import decode_attention
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(decode_attention, "decode_attn", functools.partial(
-        decode_attention.decode_attn, interpret=True))
+says_tpu = _lookahead.says_tpu
 
 
 def walked(n):

@@ -898,6 +898,10 @@ def test_hybrid_programs_fit_beside_the_weights(kda_programs):
 #: 2,048 exact rows and 2,048 chunk rows
 EVA_SLOTS, EVA_SEQ, EVA_LAYERS = 16, 32768, 8
 EVA_SLAB = f"{EVA_LAYERS},{EVA_SLOTS},32,4096,128"
+EVA_SAYS = 3        # what a step's ``out`` holds behind the tokens
+#: keys a grid step of the decode attention holds: 32 heads of 128, K and
+#: V, are 4 MiB at 256 (10.5 MiB by the kernel's own count; 19.75 at 512)
+EVA_TILE = 256
 
 
 @pytest.fixture(scope="module")
@@ -927,12 +931,19 @@ def eva_programs(topo):
     assert k.shape == v.shape == (EVA_LAYERS, EVA_SLOTS, 32, 4096, 128)
     slots = jax.ShapeDtypeStruct((EVA_SLOTS,), i32, sharding=one)
     i0 = jax.ShapeDtypeStruct((), i32, sharding=one)
-    out = jax.ShapeDtypeStruct((EVA_SLOTS + 1,), i32, sharding=one)
-    lowered = {
-        "decode": eng._decode_j.lower(params, k, v, out, slots, slots),
-        f"prefill{EVA_SEQ}": eng._prefill_j.lower(
-            params, k, v, jax.ShapeDtypeStruct((EVA_SEQ,), i32, sharding=one),
-            i0, i0, i0)}
+    out = jax.ShapeDtypeStruct((EVA_SLOTS + EVA_SAYS,), i32, sharding=one)
+    # the cache asks the platform which form its decode attention takes
+    # (``PooledCaches.attn_tile``); here it is told what the chip says
+    with pytest.MonkeyPatch.context() as steer:
+        steer.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = {
+            "decode": eng._decode_j.lower(params, k, v, out, slots, slots),
+            f"prefill{EVA_SEQ}": eng._prefill_j.lower(
+                params, k, v,
+                jax.ShapeDtypeStruct((EVA_SEQ,), i32, sharding=one),
+                i0, i0, i0)}
+        assert eng._caches.attn_tile == EVA_TILE
+        assert eng._caches.eva_attn_kernel == 1
     return {name: lo.compile() for name, lo in lowered.items()}
 
 
@@ -989,7 +1000,54 @@ def test_pooled_decode_has_the_same_operations_whatever_the_positions(
     text = eva_programs["decode"].as_text()
     entry = text[text.index("\nENTRY"):]
     assert not re.findall(r"= [^\n]* (while|conditional)\(", entry)
-    assert "tpu_custom_call" not in text
     updates = [n for n, _, dims, _ in _entry_ops(text)
                if dims == EVA_SLAB and "dynamic-update-slice" in n]
     assert len(updates) == 4 * EVA_LAYERS * EVA_SLOTS
+
+
+def test_pooled_decode_attention_is_one_kernel_a_layer_over_the_slab(
+        eva_programs):
+    """A layer's attention in the decode step is ONE ``decode_attn``
+    kernel under ``attn_core/eva_attn`` (the scopes ``eva_attn_roofline``
+    and ``decode_path_ms.eva_attn`` read), handed the one query row a
+    head padded to eight and K and V **whole, as the in-place row and
+    chunk-row writes left them**: no slice, copy or transpose of a slab
+    or of its layer, and the scores ``[16, 32, 4096]`` in no type.  Its
+    grid is every tile of every slot, so the operations are the same
+    whatever is live; which tiles it skips follows the seven vectors of
+    scalars it is handed first (layer; the two runs' visible rows; the
+    walk: whose block, first and last live tile, the second run's first
+    step and its jump), which all eight calls share but for the layer.
+    The prefill holds no such kernel."""
+    text = eva_programs["decode"].as_text()
+    entry = text[text.index("\nENTRY"):]
+    calls = re.findall(
+        rf"^\s*%?(decode_attn[\w.]*) = bf16\[{EVA_SLOTS},32,8,128\]\S* "
+        r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        entry, re.M)
+    assert len(calls) == EVA_LAYERS == text.count("tpu_custom_call")
+    made_by = {n: (op, dtype, dims) for n, dtype, dims, op in
+               _entry_ops(text)}
+    walks, layers = set(), set()
+    for name, operands in calls:
+        assert "/attn_core/eva_attn/" in _op_name(text, name)
+        operands = re.findall(r"%([\w.\-]+)", operands)
+        assert len(operands) == 7 + 3
+        assert [made_by[n][1:] for n in operands[:7]] == [("s32", "1")] + [
+            ("s32", str(2 * EVA_SLOTS))] + [("s32", str(EVA_SLOTS))] * 3 + [
+            ("s32", str(EVA_SLOTS))] * 2
+        layers.add(re.search(
+            r"%" + re.escape(operands[0]) + r" = s32\[1\]\S* "
+            r"constant\(\{(\d)\}\)", text).group(1))
+        walks.add(tuple(operands[1:7]))
+        q, k, v = operands[7:]
+        assert made_by[q][1:] == ("bf16", f"{EVA_SLOTS},32,8,128")
+        for part in (k, v):     # ... out of the fused in-place writes
+            assert made_by[part][1:] == ("bf16", EVA_SLAB)
+            assert "dynamic-update-slice" in part \
+                or _fused_root(text, part) == "dynamic-update-slice", part
+    assert layers == {str(i) for i in range(EVA_LAYERS)} and len(walks) == 1
+    for scores in (rf"\[{EVA_SLOTS},32,4096\]", rf"\[{EVA_SLOTS},32,1,4096\]",
+                   rf"\[{EVA_SLOTS},32,8,4096\]"):
+        assert not re.search(scores, text), scores
+    assert "tpu_custom_call" not in eva_programs[f"prefill{EVA_SEQ}"].as_text()
