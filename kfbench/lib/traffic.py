@@ -1,9 +1,14 @@
 """The one generator of traffic.  A traffic mix is a data file of
 parameters; everything here is driven by them and by ``--seed``.
 
-The work offered is fixed by the file, and the seed changes only what
-must differ between runs -- order, arrival times, token ids -- so that
-two seeds never offer different amounts of work.
+The file fixes the work offered and, for an open loop, its schedule:
+which request is due at which instant, drawn once from the file's
+``schedule_seed``.  Two runs of a cell offer the same requests at the
+same instants.  ``--seed`` draws what must differ between runs and what
+the comparison needs -- the weights, every request's token ids, the
+sample held against the reference -- and none of it changes the amount
+of work or when it arrives: a step whose time follows the contexts that
+are live together reads the same in every run.
 """
 
 from __future__ import annotations
@@ -59,18 +64,20 @@ def length_pairs(traffic: dict, n: int):
     return pairs
 
 
-def open_schedule(traffic: dict, seconds: float, seed: int):
+def open_schedule(traffic: dict, seconds: float):
     """Open loop: ``(due_s, prompt_len, output_len, in_window)`` sorted
     by due time, relative to the window's start.  The pre-roll (negative
     due times) and the window each offer a number and a multiset of
-    requests fixed by the file; the seed permutes their order and draws
-    the due times -- a Poisson process given its count, that is sorted
-    uniform draws."""
+    requests fixed by the file, in an order and at due times drawn from
+    the file's ``schedule_seed`` -- one draw of a Poisson process given
+    its count, that is sorted uniform draws.  ``--seed`` has no part in
+    it (``tools/sweep_fresh.py`` states a seed a window: a knee has to
+    hold over draws)."""
     out = []
     for stream, t0, span in ((2, -traffic["preroll_s"], traffic["preroll_s"]),
                              (3, 0.0, seconds)):
         n = max(1, round(traffic["rate_rps"] * span))
-        g = rng(seed, stream)
+        g = rng(traffic["schedule_seed"], stream)
         pairs = length_pairs(traffic, n)
         order = g.permutation(n)
         dues = np.sort(g.uniform(t0, t0 + span, n))
@@ -80,5 +87,6 @@ def open_schedule(traffic: dict, seconds: float, seed: int):
 
 
 def prompt_ids(vocab: int, seed: int, index: int, n: int):
-    """Random ids: no two requests share a prefix."""
+    """Random ids from ``--seed``: no two requests share a prefix, and
+    no two seeds a prompt."""
     return rng(seed, 5, index).integers(0, vocab, n).tolist()

@@ -2,12 +2,13 @@
 loop of requests from independent users.
 
 A generator thread submits each request when it is due, whether or not
-earlier ones have finished; traffic starts ``preroll_s`` before the
-window so that the window opens on an engine already at its steady
-number of active slots.  Requests are counted if they were due inside
-the window and are timed from when they were due.  Every token is
-stamped when the ``engine.step()`` that produced it returns.  The facts
-of every request go to the run's file.
+earlier ones have finished.  Which request is due when is the traffic
+file's (``lib/traffic.py``), the same in every run of the cell.  Traffic
+starts ``preroll_s`` before the window so that the window opens on an
+engine already at its steady number of active slots.  Requests are
+counted if they were due inside the window and are timed from when they
+were due.  Every token is stamped when the ``engine.step()`` that
+produced it returns.  The facts of every request go to the run's file.
 """
 
 from __future__ import annotations
@@ -101,16 +102,21 @@ class Serving:
         self.pages = pages
 
 
+def offered(spec: dict) -> list:
+    """The requests of one run, in the order they are due.  Which
+    lengths, and when each is due, is the traffic file's and the same in
+    every run; the ids of each prompt are ``--seed``'s."""
+    vocab, seed = spec["config"]["vocab_size"], spec["seed"]
+    return [Request(i, gen.prompt_ids(vocab, seed, i, p), o, due=due,
+                    in_window=w)
+            for i, (due, p, o, w) in enumerate(
+                gen.open_schedule(spec["traffic"], spec["seconds"]))]
+
+
 def drive(spec: dict, serving: Serving, tracer=None) -> dict:
     """Pre-roll, window and drain of one traffic mix on a warm engine."""
-    cfg, tr, seed = spec["config"], spec["traffic"], spec["seed"]
-    eng = serving.eng
-    reqs = {}
-    for i, (due, p, o, w) in enumerate(
-            gen.open_schedule(tr, spec["seconds"], seed)):
-        r = Request(i, gen.prompt_ids(cfg["vocab_size"], seed, i, p), o,
-                    due=due, in_window=w)
-        reqs[r.rid] = r
+    tr, eng = spec["traffic"], serving.eng
+    reqs = {r.rid: r for r in offered(spec)}
     tracer = tracer or harness.Tracer(dict(spec, trace=0))
 
     def all_answered():
@@ -187,7 +193,7 @@ def drive(spec: dict, serving: Serving, tracer=None) -> dict:
 def traffic_shapes(spec: dict):
     """Every (prompt, output) length this cell's traffic will send."""
     return {(p, o) for _, p, o, _ in gen.open_schedule(
-        spec["traffic"], spec["seconds"], spec["seed"])}
+        spec["traffic"], spec["seconds"])}
 
 
 def run(spec: dict) -> dict:

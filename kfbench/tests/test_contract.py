@@ -49,7 +49,13 @@ def test_configurations_state_source_and_departures(bench):
     for c in bench["configs"]:
         cfg = files.load_config(c["name"])
         assert cfg["source"].startswith(c["source"])
-        assert cfg["assumed"] and c["reduced"] == []
+        assert cfg["assumed"]
+        # every key cut from the source is listed in both places, with
+        # the published value kept beside the one that runs
+        assert sorted(cfg.get("reduced", {})) == sorted(c["reduced"])
+        for key in c["reduced"]:
+            assert cfg[key + "_published"] != cfg[key], (c["name"], key)
+            assert str(cfg[key + "_published"]) in cfg["reduced"][key]
         files.load_adapter(cfg["family"])
         files.load_reference(cfg["family"])
     at_most_a_quarter = max(1, len(bench["workloads"]) // 4)

@@ -13,8 +13,8 @@ import os
 
 import pytest
 
-from conftest import rehearse
-from kfbench.lib import decode_paths, evabyte, files, spans, traffic as gen
+from conftest import one_schedule_whatever_the_seed, rehearse
+from kfbench.lib import decode_paths, evabyte, files, spans
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CELL = "evabyte-serve-docbytes"
@@ -216,14 +216,14 @@ def test_a_program_without_the_scopes_or_attrs_gives_nothing_to_read():
     # the cell is on the judged metric's list, and on no list whose
     # reader finds nothing in it
     judged = [m for m in bench["end_to_end"] if m["name"] == "itl_p50_ms"][0]
-    assert judged["workloads"][-1] == CELL
+    assert CELL in judged["workloads"]
     lists = {m["name"]: m.get("workloads", ()) for m in bench["per_layer"]}
     for name in ("kv_attn_roofline", "admit_ms_per_req", "commit_ms_per_req",
                  "commit_mb_per_req", "prefill_ms_per_ktok.chat"):
         assert CELL not in lists[name], name
     for name in ("kv_rows_live_share", "decode_step_spread",
                  "first_token_ms_per_ktok", "scope_ms_per_step.kv_write"):
-        assert lists[name][-1] == CELL, name
+        assert CELL in lists[name], name
 
 
 def recorded():
@@ -264,19 +264,14 @@ def test_readers_on_a_recorded_excerpt_of_the_chip():
 
 
 def test_the_traffic_offers_one_multiset_whatever_the_seed():
-    tr = files.load_traffic("docbytes-open")
-    runs = [gen.open_schedule(tr, 40.0, seed) for seed in (1, 2, BIG)]
-    sets = [sorted((p, o, w) for _, p, o, w in run) for run in runs]
-    assert sets[0] == sets[1] == sets[2]
-    assert [r[:3] for r in runs[0]] != [r[:3] for r in runs[1]]
-    prompts = sorted(p for _, p, _, _ in runs[0])
-    outputs = sorted(o for _, _, o, _ in runs[0])
+    tr, schedule = one_schedule_whatever_the_seed("docbytes-open")
+    prompts = sorted(p for _, p, _, _ in schedule)
+    outputs = sorted(o for _, _, o, _ in schedule)
     assert prompts[0] >= 2048 and prompts[-1] <= 28672
     assert outputs[0] >= 256 and outputs[-1] <= 3072
     assert 9000 < prompts[len(prompts) // 2] < 16000
     assert 800 < outputs[len(outputs) // 2] < 1300
-    for _, p, o, _ in runs[0]:
-        assert p + o <= tr["max_total"] == tr["engine"]["max_seq"]
+    assert tr["max_total"] == tr["engine"]["max_seq"]
     e = tr["engine"]
     assert (e["max_batch"], e["max_seq"], e["page_tokens"]) == (
         16, 32768, 2048)

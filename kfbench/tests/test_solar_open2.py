@@ -8,12 +8,12 @@ and the cell's rehearsal."""
 
 import pytest
 
-from conftest import rehearse
-from kfbench.lib import decode_paths, files, solar_open2, spans, traffic as gen
+from conftest import (BIG, EXPERT_CELL_READERS,
+                      one_schedule_whatever_the_seed, rehearse)
+from kfbench.lib import decode_paths, files, solar_open2, spans
 
 CELL = "solar2-serve-reasoning"
 CONFIG = "Solar-Open2-250B"
-BIG = 2 ** 31 + 12345
 
 
 def test_sizes_as_published_and_as_cut():
@@ -205,29 +205,27 @@ def test_a_program_without_the_scopes_or_attrs_gives_nothing_to_read():
     assert metric("kda_state_roofline", decode_facts(attrs=False)) is None
     # ... and the cell is on the list of every reader it shares with both
     # expert cells, on ``attn_full`` and on the judged metric
-    shared = [m["name"] for m in bench["per_layer"]
-              if CELL in m.get("workloads", ()) and m["name"] not in new]
-    assert len(shared) == 17 and "decode_path_ms.attn_full" in shared
-    assert "moe_experts_roofline" not in shared
-    assert "prefill_ms_per_ktok.chat" not in shared
+    lists = {m["name"]: m.get("workloads", ()) for m in bench["per_layer"]}
+    for name in EXPERT_CELL_READERS + (
+            "decode_path_ms.attn_full", "kv_rows_live_share",
+            "kv_attn_roofline"):
+        assert CELL in lists[name], name
+    for name in ("moe_experts_roofline", "decode_path_ms.attn_window",
+                 "prefill_ms_per_ktok.chat", "latent_rows_live_share"):
+        assert CELL not in lists[name], name
     judged = [m for m in bench["end_to_end"] if m["name"] == "itl_p50_ms"][0]
-    assert judged["workloads"][-1] == CELL
+    assert CELL in judged["workloads"]
 
 
 def test_the_traffic_offers_one_multiset_whatever_the_seed():
-    tr = files.load_traffic("reasoning-open")
-    runs = [gen.open_schedule(tr, 40.0, seed) for seed in (1, 2, BIG)]
-    sets = [sorted((p, o, w) for _, p, o, w in run) for run in runs]
-    assert sets[0] == sets[1] == sets[2]
-    assert [r[:3] for r in runs[0]] != [r[:3] for r in runs[1]]
-    prompts = sorted(p for _, p, _, w in runs[0] if w)
-    outputs = sorted(o for _, _, o, w in runs[0] if w)
+    tr, schedule = one_schedule_whatever_the_seed("reasoning-open")
+    prompts = sorted(p for _, p, _, w in schedule if w)
+    outputs = sorted(o for _, _, o, w in schedule if w)
     assert prompts[0] >= 64 and prompts[-1] <= 2048
     assert outputs[0] >= 384 and outputs[-1] <= 3072
     assert 400 < prompts[len(prompts) // 2] < 650
     assert 1300 < outputs[len(outputs) // 2] < 1800
-    for _, p, o, _ in runs[0]:
-        assert p + o <= tr["max_total"] == tr["engine"]["max_seq"]
+    assert tr["max_total"] == tr["engine"]["max_seq"]
     e = tr["engine"]
     assert (e["max_batch"], e["max_seq"], e["page_tokens"], e["kv_pages"]) \
         == (128, 4096, 256, 64)

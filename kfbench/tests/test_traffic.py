@@ -1,24 +1,16 @@
-"""The generator: every seed offers the same work, in another order."""
+"""The generator: an open loop's schedule is its file's, the same for
+every seed; a train cell's rows are a function of seed and step."""
 
 import numpy as np
 
+from conftest import BIG, one_schedule_whatever_the_seed
 from kfbench.lib import files, traffic as gen
-
-BIG = 2 ** 31 + 12345  # the driver's seeds pass 32 signed bits
 
 
 def test_open_loop_offers_one_multiset_whatever_the_seed():
-    tr = files.load_traffic("chat-open")
-    runs = [gen.open_schedule(tr, 40.0, seed) for seed in (1, 2, BIG)]
-    sets = [sorted((p, o, w) for _, p, o, w in run) for run in runs]
-    assert sets[0] == sets[1] == sets[2]
-    assert [r[:3] for r in runs[0]] != [r[:3] for r in runs[1]]
-    n = round(tr["rate_rps"] * 40.0)
-    assert sum(w for *_, w in runs[0]) == n
-    for due, p, o, w in runs[0]:
-        assert p + o <= tr["max_total"] and o >= 1
-        assert (0 <= due < 40.0) if w else (-tr["preroll_s"] <= due < 0)
-    assert runs[2] == gen.open_schedule(tr, 40.0, BIG)
+    tr, schedule = one_schedule_whatever_the_seed("chat-open")
+    assert schedule == gen.open_schedule(tr, 40.0)  # a function of the file
+    assert len(gen.open_schedule(tr, 20.0)) < len(schedule)
 
 
 def test_packed_batches_are_a_function_of_seed_and_step():

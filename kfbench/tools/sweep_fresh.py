@@ -1,17 +1,21 @@
 #!/usr/bin/env python3
-"""An open-loop cell's knee from windows that share nothing: one process
-and one warm engine like ``sweep_rate.py``, but every window is driven
-with a seed of its own, so its prompts are new to the prefix cache.
+"""An open-loop cell's knee, the highest rate at which the backlog does
+not grow, from windows that share nothing: one process, one warm engine,
+and every window driven with a seed of its own.  The traffic file's
+``rate_rps`` is then written by hand at the stated share of it, with the
+sweep's lines in its ``rate_reason``.
 
-``sweep_rate.py`` drives every rate with ONE seed, and
-``traffic.prompt_ids(vocab, seed, index, n)`` gives request ``index`` the
-same first ids at every rate: from the second rate on, its requests hit
-the pages the rate before committed, prefill only a suffix, and the
-engine looks faster than it is (PERF.md, PR 27).  Here window ``j`` of
-``--seeds`` entry ``s`` draws schedule and prompts from seed ``s + j``
-(the weights stay those of the first seed: they do not move the
-timing), and each line says how many prompt tokens came out of the
-cache: it has to read 0.
+Window ``j`` of ``--seeds`` entry ``s`` takes seed ``s + j`` for both
+things a run keeps apart: the schedule, which a run takes from the
+file's ``schedule_seed`` and which is drawn anew here because a knee has
+to hold over draws and not on one; and the prompts, so that they are new
+to the prefix cache.  (``traffic.prompt_ids(vocab, seed, index, n)``
+gives request ``index`` the same first ids at every rate under one seed:
+from the second rate on its requests would hit the pages the rate before
+committed, prefill only a suffix, and the engine would look faster than
+it is; PERF.md, PR 27.)  The weights stay those of the first seed: they
+do not move the timing.  Each line says how many prompt tokens came out
+of the cache: it has to read 0.
 
     python3 kfbench/tools/sweep_fresh.py --workload cmdaplus-serve-mixedlen \
         --rates 1.5 2.0 --seeds 1100 1200 --seconds 40
@@ -26,6 +30,18 @@ import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
+
+
+def windows(spec: dict, seeds, rates):
+    """``spec`` set for each window in turn: window ``j`` of seed ``s``
+    draws its schedule and its prompts from ``s + j``.  What is yielded
+    is ``spec`` itself, the same object every time: use each window
+    before asking for the next."""
+    for seed in seeds:
+        for j, rate in enumerate(rates):
+            spec["seed"] = spec["traffic"]["schedule_seed"] = seed + j
+            spec["traffic"]["rate_rps"] = rate
+            yield spec
 
 
 def main() -> int:
@@ -48,14 +64,13 @@ def main() -> int:
     loop = files.load_module("loops", spec["traffic"]["loop"])
     serving = loop.Serving(spec)
     shapes = set()
-    for rate in a.rates:  # (the lengths follow the rate, not the seed)
+    for rate in a.rates:  # (the lengths follow the rate, not the draw)
         spec["traffic"]["rate_rps"] = rate
         shapes |= loop.traffic_shapes(spec)
     serving.warm(spec, shapes)
-    for seed, rate in [(seed + j, rate) for seed in a.seeds
-                       for j, rate in enumerate(a.rates)]:
-        spec["seed"], spec["traffic"]["rate_rps"] = seed, rate
-        d = loop.drive(spec, serving)
+    for window in windows(spec, a.seeds, a.rates):
+        seed, rate = window["seed"], window["traffic"]["rate_rps"]
+        d = loop.drive(window, serving)
         t0, t_end = d["t0"], d["t_end"]
         inside = [s for s in d["steps"] if t0 < s[0] <= t_end]
         half = len(inside) // 2
