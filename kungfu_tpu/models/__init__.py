@@ -31,6 +31,11 @@ models because they are its benchmark workload:
   byte-level model with EVA attention: exact softmax inside an aligned
   window, every chunk of positions before it pooled into one learned
   key/value row, one softmax over both), served likewise.
+* :mod:`kungfu_tpu.models.phi4flash` — the ``phi4flash`` decoder
+  (SambaY: selective state-space layers beside window and full
+  differential attention, then Gated Memory Units and cross-attention
+  layers that read the one full layer's keys and values), served
+  likewise.
 * :mod:`kungfu_tpu.models.fake` — gradient-shaped fake models for
   collective benchmarking without real compute (parity with
   ``tests/go/fakemodel``).
@@ -41,6 +46,7 @@ from kungfu_tpu.models.cohere2_moe import Cohere2Moe, Cohere2MoeConfig
 from kungfu_tpu.models.evabyte import EvaByte, EvaByteConfig
 from kungfu_tpu.models.mlp import MLP, mnist_slp
 from kungfu_tpu.models.pangu_moe import PanguMoe, PanguMoeConfig
+from kungfu_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
 from kungfu_tpu.models.resnet import ResNet, resnet50
 from kungfu_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
 from kungfu_tpu.models.transformer import Transformer, TransformerConfig, bert_base, gpt_small
@@ -56,6 +62,8 @@ __all__ = [
     "MLP",
     "PanguMoe",
     "PanguMoeConfig",
+    "Phi4Flash",
+    "Phi4FlashConfig",
     "mnist_slp",
     "ResNet",
     "resnet50",

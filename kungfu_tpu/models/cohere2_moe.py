@@ -114,20 +114,24 @@ def rope_interleaved(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
-def attention(q, k, v, mask):
+def attention(q, k, v, mask, scale=None):
     """Grouped-query attention: ``q`` ``[B, Q, G, J, D]`` (``J`` query
     heads read key/value head ``g``; the layout the projection gives,
     so no query is ever transposed), ``k``/``v`` ``[B, G, S, D]``,
     ``mask`` broadcastable to ``[B, 1, 1, Q, S]``, True = attend.
-    Float32 logits and softmax, like every attention of the tree."""
-    logits = jnp.einsum("bqgjd,bgsd->bgjqs", q, k
-                        ).astype(jnp.float32) / math.sqrt(q.shape[-1])
+    Float32 logits and softmax, like every attention of the tree.  The
+    scores are divided by ``sqrt(D)``, or multiplied by ``scale`` where
+    one is given (``models/phi4flash.py``)."""
+    logits = jnp.einsum("bqgjd,bgsd->bgjqs", q, k).astype(jnp.float32)
+    logits = logits / math.sqrt(q.shape[-1]) if scale is None \
+        else logits * scale
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bgjqs,bgsd->bqgjd", probs, v)
 
 
-def blocked_attention(q, k, v, q_pos, k_pos0, window: Optional[int]):
+def blocked_attention(q, k, v, q_pos, k_pos0, window: Optional[int],
+                      scale=None):
     """Attention of many query rows without their ``[heads, Q, S]``
     scores: :data:`ATTN_BLOCK` rows at a time, each block over the span
     of keys it can see.  ``q`` ``[1, Q, G, J, D]`` at positions
@@ -151,7 +155,7 @@ def blocked_attention(q, k, v, q_pos, k_pos0, window: Optional[int]):
         see = (kp[None, :] <= qp[:, None]) & (kp[None, :] >= 0)
         if window is not None:
             see = see & (qp[:, None] - kp[None, :] < window)
-        return attention(qb, kb, vb, see)[0]
+        return attention(qb, kb, vb, see, scale)[0]
 
     return jax.lax.map(one, jnp.arange(n_q // blk)).reshape(q.shape)
 

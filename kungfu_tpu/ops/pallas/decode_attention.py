@@ -281,8 +281,9 @@ def _tile_at(b, t, lo, hi, off=None, jump=None):
     return tile
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "starts", "interpret"))
-def _call(li, n, q, k, v, tile, starts, interpret):
+@functools.partial(jax.jit, static_argnames=("tile", "starts", "scale",
+                                             "interpret"))
+def _call(li, n, q, k, v, tile, starts, scale, interpret):
     n_b, g, j, d = q.shape
     s = k.shape[3]
     walk = _walk(n, tile, starts)
@@ -293,8 +294,7 @@ def _call(li, n, q, k, v, tile, starts, interpret):
     heads = pl.BlockSpec((None, g, j, d), lambda b, t, *_: (b, 0, 0, 0))
     tiles = pl.BlockSpec((None, None, g, tile, d), rows)
     return pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / math.sqrt(d),
-                          runs=len(starts)),
+        functools.partial(_kernel, scale=scale, runs=len(starts)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(walk),
             grid=(n_b, s // tile),
@@ -313,7 +313,8 @@ def _call(li, n, q, k, v, tile, starts, interpret):
     )(li, n.reshape(-1), *walk, q, k, v)
 
 
-def decode_attn(q, k, v, li, n, *, tile, starts=(0,), interpret=False):
+def decode_attn(q, k, v, li, n, *, tile, starts=(0,), scale=None,
+                interpret=False):
     """``softmax(mask(q K^T / sqrt(D))) V`` of layer ``li`` for every
     slot, ONE softmax over all the rows it may see: ``q`` ``[B, G, J,
     D]``, the slab's ``k`` and ``v`` ``[L, B, G, S, D]`` whole, ``n``
@@ -322,7 +323,10 @@ def decode_attn(q, k, v, li, n, *, tile, starts=(0,), interpret=False):
     starts[r] + n[r, b])``, which end before the next run starts; ``n``
     is ``[R, B]``, or ``[B]`` for the one run from row 0 (all 0: none,
     and zeros come back).  ``tile`` is :func:`key_tile`'s for the slab
-    and the starts, or another that tiles both."""
+    and the starts, or another that tiles both.  ``scale`` multiplies the
+    scores where it is not ``1 / sqrt(D)`` (``serve/sambay.py``: rows of
+    128 that hold two heads of 64, each query zero in the other's
+    half)."""
     g, j, d = q.shape[1:]
     s = k.shape[3]
     starts = tuple(int(x) for x in starts)
@@ -340,5 +344,6 @@ def decode_attn(q, k, v, li, n, *, tile, starts=(0,), interpret=False):
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
     out = _call(jnp.asarray(li, jnp.int32).reshape(1),
                 n.astype(jnp.int32), q, k, v, int(tile), starts,
+                1.0 / math.sqrt(d) if scale is None else float(scale),
                 bool(interpret))
     return out[:, :, :j] if pad else out

@@ -44,6 +44,17 @@ array of a part is of one of three kinds:
   bucket leaves the rows and chunk rows of exactly ``n`` positions, a
   decode step leaves a slot it is not ``live`` for as it was.
 
+A part may hold several kinds side by side (``serve/sambay.py``: a state
+a slot, rings and rows of every position in one slot), and **rows may be
+kept once and read by several layers**: there one layer keeps every
+position's rows and seven further layers, which keep nothing, attend
+over them.  The engine sees none of it.  What such a cache owes is in
+its ``read``: the counts below are then A READING LAYER's -- a context
+of ``c`` owes ``c`` rows for every layer that reads the slab, not for
+every layer that keeps one -- while ``kv_rows_written`` stays the
+KEEPING layers'.  (Such a family is unpaged here, so nothing reads
+how many layers its ``PageSpec`` counts a page over.)
+
 ``new_slabs()``
     the device cache as the pair ``(k, v)``.
 ``prefill(params, k, v, ids, n, start, slot)`` -> ``(k, v, token)``
@@ -85,7 +96,7 @@ array of a part is of one of three kinds:
     * ``latent_rows_live``, ``latent_rows_read`` -- compressed rows a
       position (``serve/latent.py``), a layer's;
     * ``state_slots_live``, ``state_slots_read``, ``state_bytes_read``
-      -- a state a slot (``serve/recurrent.py``);
+      -- a state a slot (``serve/recurrent.py``, ``serve/sambay.py``);
     * ``summary_rows_live``, ``summary_rows_read``,
       ``summary_rows_written`` -- rows that stand for several positions
       (``serve/pooled.py``: the chunk rows of closed windows a context
@@ -131,10 +142,14 @@ position); ``serve/windowed.py`` the one of a model that mixes window and
 full attention layers; ``serve/latent.py`` the one of latent attention;
 ``serve/recurrent.py`` the one of a model most of whose layers keep a
 state a slot; ``serve/pooled.py`` the one of attention over pooled chunk
-rows.  :func:`row_windows` and :func:`write_rows`, the in-place write of
-one row a slot, are shared by all five, :func:`pages_in_order`
-and :func:`slot_rows`, the host's side of a part that keeps every
-position, by the first and the third.
+rows; ``serve/sambay.py`` the one of three kinds of content in a slot
+whose full rows are read by more layers than keep them.
+:func:`row_windows` and :func:`write_rows`, the in-place write of
+one row a slot, are shared by all six, :func:`layer_slot` and
+:func:`put_rows` by the two that keep rings, :func:`of_slot` and
+:func:`to_slot` by the two that keep a state a slot,
+:func:`pages_in_order` and :func:`slot_rows`, the host's side of a part
+that keeps every position, by the first and the third.
 """
 
 from __future__ import annotations
@@ -190,6 +205,38 @@ def write_rows(slab, li, new, windows):
                             old), at,
             allow_negative_indices=False)
     return slab
+
+
+def layer_slot(slab, li, slot):
+    """Layer ``li``, slot ``slot`` of a slab ``[L, B, G, S, D]`` as ``[1,
+    G, S, D]``, by one dynamic slice (taking the layer first would
+    materialise its slots)."""
+    return jax.lax.dynamic_slice(
+        slab, (li, slot, 0, 0, 0), (1, 1) + slab.shape[2:],
+        allow_negative_indices=False)[0]
+
+
+def put_rows(slab, rows, at):
+    """``rows`` ``[1, G, n, D]`` of one layer and slot into ``slab`` at
+    ``at``, in place."""
+    return jax.lax.dynamic_update_slice(slab, rows[None], at,
+                                        allow_negative_indices=False)
+
+
+def of_slot(part, li, slot):
+    """Layer ``li``, slot ``slot`` of a part that keeps a state a slot,
+    without those two axes, by one dynamic slice (taking the layer first
+    would materialise its slots)."""
+    return jax.lax.dynamic_slice(
+        part, (li, slot) + (0,) * (part.ndim - 2), (1, 1) + part.shape[2:],
+        allow_negative_indices=False)[0, 0]
+
+
+def to_slot(part, li, slot, new):
+    """``new`` as layer ``li``, slot ``slot`` of such a part, in place."""
+    return jax.lax.dynamic_update_slice(
+        part, new[None, None].astype(part.dtype),
+        (li, slot) + (0,) * (part.ndim - 2), allow_negative_indices=False)
 
 
 def pages_in_order(data, rows: int, page_tokens: int):

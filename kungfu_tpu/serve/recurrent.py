@@ -69,7 +69,8 @@ import numpy as np
 
 from kungfu_tpu.models import cohere2_moe, solar_open2 as arch
 from kungfu_tpu.ops import delta_rule
-from kungfu_tpu.serve.caches import kv_rows, row_windows, write_rows
+from kungfu_tpu.serve.caches import (kv_rows, of_slot, row_windows, to_slot,
+                                     write_rows)
 
 F32 = jnp.float32
 #: of the KDA layers' matrices (the published layer keeps them so; the
@@ -79,21 +80,6 @@ STATE_DTYPE = jnp.dtype("float32")
 #: what a decode step's ``out`` says behind the slots' tokens
 _SAYS = ("experts_touched", "expert_load_max", "assigned",
          "state_slots_live", "kv_rows_walked")
-
-
-def _of_slot(part, li, slot):
-    """Layer ``li``, slot ``slot`` of a part, without those two axes, by
-    one dynamic slice (taking the layer first would materialise its
-    slots)."""
-    return jax.lax.dynamic_slice(
-        part, (li, slot) + (0,) * (part.ndim - 2), (1, 1) + part.shape[2:],
-        allow_negative_indices=False)[0, 0]
-
-
-def _to_slot(part, li, slot, new):
-    return jax.lax.dynamic_update_slice(
-        part, new[None, None].astype(part.dtype),
-        (li, slot) + (0,) * (part.ndim - 2), allow_negative_indices=False)
 
 
 class HybridCaches:
@@ -307,8 +293,8 @@ class HybridCaches:
                     vr = jax.lax.dynamic_update_slice(
                         vr, vn[None], (i, slot, 0, start, 0),
                         allow_negative_indices=False)
-                me.keys = (_of_slot(kr, i, slot)[None],
-                           _of_slot(vr, i, slot)[None])
+                me.keys = (of_slot(kr, i, slot)[None],
+                           of_slot(vr, i, slot)[None])
 
             @jax.named_scope("attn_core")
             def attend(me, li, q, positions):
@@ -318,22 +304,22 @@ class HybridCaches:
 
             def convolve(_, li, u, w):
                 i = self.place[li]
-                tail = jnp.where(goes_on, _of_slot(tails[i], 0, slot), 0)
+                tail = jnp.where(goes_on, of_slot(tails[i], 0, slot), 0)
                 y, tail = delta_rule.causal_conv(u[0], w, tail, n)
                 with jax.named_scope("kv_write"):
-                    tails[i] = _to_slot(tails[i], 0, slot, tail)
+                    tails[i] = to_slot(tails[i], 0, slot, tail)
                 return y[None]
 
             @jax.named_scope("attn_core")
             def recur(_, li, q, k, v, g, b):
                 i = self.place[li]
                 with jax.named_scope("kda_chunk"):
-                    s0 = jnp.where(goes_on, _of_slot(state[i], 0, slot
-                                                     ).astype(F32), 0.0)
+                    s0 = jnp.where(goes_on, of_slot(state[i], 0, slot
+                                                    ).astype(F32), 0.0)
                     o, s_n = delta_rule.kda_chunked(
                         q[0], k[0], v[0], g[0], b[0], s0, n)
                 with jax.named_scope("kv_write"):
-                    state[i] = _to_slot(state[i], 0, slot, s_n)
+                    state[i] = to_slot(state[i], 0, slot, s_n)
                 return o[None]
 
         h = model.embed(params, ids[None])

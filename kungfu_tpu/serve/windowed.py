@@ -39,20 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from kungfu_tpu.models import cohere2_moe as arch
-from kungfu_tpu.serve.caches import kv_rows, row_windows, write_rows
-
-
-def _slot_of(slab, li, slot):
-    """Layer ``li``, slot ``slot`` of a slab as ``[1, G, S, D]``, by one
-    dynamic slice (taking the layer first would materialise its slots)."""
-    return jax.lax.dynamic_slice(
-        slab, (li, slot, 0, 0, 0), (1, 1) + slab.shape[2:],
-        allow_negative_indices=False)[0]
-
-
-def _put(slab, rows, at):
-    return jax.lax.dynamic_update_slice(slab, rows[None], at,
-                                        allow_negative_indices=False)
+from kungfu_tpu.serve.caches import (kv_rows, layer_slot, put_rows,
+                                     row_windows, write_rows)
 
 
 class WindowedCaches:
@@ -194,24 +182,26 @@ class WindowedCaches:
                 i = self.place[li]
                 if not cfg.is_window(li):
                     with jax.named_scope("kv_write"):
-                        kf = _put(kf, kn, (i, slot, 0, start, 0))
-                        vf = _put(vf, vn, (i, slot, 0, start, 0))
-                    me.keys = (_slot_of(kf, i, slot), _slot_of(vf, i, slot),
-                               0, None)
+                        kf = put_rows(kf, kn, (i, slot, 0, start, 0))
+                        vf = put_rows(vf, vn, (i, slot, 0, start, 0))
+                    me.keys = (layer_slot(kf, i, slot),
+                               layer_slot(vf, i, slot), 0, None)
                     return
                 # (the barrier: the slot's old rows are taken out before
                 # the ring is written, or the compiler, reading them where
                 # they are used, copies the whole slab to keep them)
                 old_k, old_v = jax.lax.optimization_barrier(
-                    (_slot_of(kw, i, slot), _slot_of(vw, i, slot)))
+                    (layer_slot(kw, i, slot), layer_slot(vw, i, slot)))
                 me.keys = (jnp.concatenate([old_k[:, :, unroll], kn], axis=2),
                            jnp.concatenate([old_v[:, :, unroll], vn], axis=2),
                            start - ring, cfg.window)
                 with jax.named_scope("kv_write"):
-                    kw = _put(kw, jnp.where(fresh, kn[:, :, source], old_k),
-                              (i, slot, 0, 0, 0))
-                    vw = _put(vw, jnp.where(fresh, vn[:, :, source], old_v),
-                              (i, slot, 0, 0, 0))
+                    kw = put_rows(
+                        kw, jnp.where(fresh, kn[:, :, source], old_k),
+                        (i, slot, 0, 0, 0))
+                    vw = put_rows(
+                        vw, jnp.where(fresh, vn[:, :, source], old_v),
+                        (i, slot, 0, 0, 0))
 
             @jax.named_scope("attn_core")
             def attend(me, li, q, positions):

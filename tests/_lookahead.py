@@ -39,19 +39,22 @@ def record_spans(monkeypatch) -> list:
 
 def says_tpu(monkeypatch):
     """What the code can see says TPU, so a decode step takes its
-    kernel branch, and ``ops/pallas/decode_attention.py``'s kernel runs
-    in Pallas's plain interpreter (JAX operations in the calling
-    program: nothing that calls back into Python from a step the engine
-    has dispatched ahead)."""
+    kernel branch, and ``ops/pallas/decode_attention.py``'s kernel (and
+    ``row_write.py``'s, where a cache writes its rows by it) runs in
+    Pallas's plain interpreter (JAX operations in the calling program:
+    nothing that calls back into Python from a step the engine has
+    dispatched ahead)."""
     import functools
 
     import jax
 
-    from kungfu_tpu.ops.pallas import decode_attention
+    from kungfu_tpu.ops.pallas import decode_attention, row_write
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(decode_attention, "decode_attn", functools.partial(
         decode_attention.decode_attn, interpret=True))
+    monkeypatch.setattr(row_write, "write_rows", functools.partial(
+        row_write.write_rows, interpret=True))
 
 
 def decode_reads(eng, spans, asked: dict) -> list:

@@ -1051,3 +1051,200 @@ def test_pooled_decode_attention_is_one_kernel_a_layer_over_the_slab(
                    rf"\[{EVA_SLOTS},32,8,4096\]"):
         assert not re.search(scores, text), scores
     assert "tpu_custom_call" not in eva_programs[f"prefill{EVA_SEQ}"].as_text()
+
+
+# -- and for the model with three kinds of content in a slot -----------------
+#: Phi-4-mini-flash-reasoning as its cell serves it: all 32 layers and all
+#: 200,064 ids, 128 slots of 4,096 positions -- a slot nine Mamba states
+#: and tails, eight rings of 512 rows and the one full layer's 4,096
+SY_SLOTS, SY_SEQ, SY_PREFILL = 128, 4096, 1024
+SY_SAYS = 2         # what a step's ``out`` holds behind the tokens
+SY_STATE = f"1,{SY_SLOTS},16,5120"
+SY_RING = f"8,{SY_SLOTS},10,512,128"
+SY_SLAB = f"1,{SY_SLOTS},10,{SY_SEQ},128"
+
+
+@pytest.fixture(scope="module")
+def sambay_programs(topo):
+    """name -> compiled program of the engine serving ``phi4flash`` whole
+    at the cell's sizes, lowered from shapes alone: the decode step and
+    the prefill of the longest bucket the cell's prompts reach."""
+    from kungfu_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
+    from kungfu_tpu.serve.engine import InferenceEngine
+    from kungfu_tpu.serve.kvcache import KVCachePool, PageSpec
+
+    cfg = Phi4FlashConfig(max_seq=SY_SEQ)
+    model = Phi4Flash(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        shaped, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = PageSpec.for_model(cfg, page_tokens=256)
+    assert spec.unpaged  # (a page of this family is never whole)
+    eng = InferenceEngine(model, None, max_batch=SY_SLOTS, max_seq=SY_SEQ,
+                          pool=KVCachePool(spec, capacity_pages=1))
+    k, v = jax.tree_util.tree_map(shaped,
+                                  jax.eval_shape(eng._caches.new_slabs))
+    assert [x.shape for x in jax.tree_util.tree_leaves(k)] == [
+        (8, SY_SLOTS, 10, 512, 128), (1, SY_SLOTS, 10, SY_SEQ, 128)] \
+        + [(1, SY_SLOTS, 16, 5120)] * 9
+    assert [x.shape for x in jax.tree_util.tree_leaves(v)][2:] \
+        == [(1, SY_SLOTS, 3, 5120)] * 9
+    slots = jax.ShapeDtypeStruct((SY_SLOTS,), i32, sharding=one)
+    i0 = jax.ShapeDtypeStruct((), i32, sharding=one)
+    out = jax.ShapeDtypeStruct((SY_SLOTS + SY_SAYS,), i32, sharding=one)
+    # the cache asks the platform which form its decode attention takes
+    # (``SambaYCaches.attn_tiles``); here it is told what the chip says
+    with pytest.MonkeyPatch.context() as steer:
+        steer.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = {
+            "decode": eng._decode_j.lower(params, k, v, out, slots, slots),
+            f"prefill{SY_PREFILL}": eng._prefill_j.lower(
+                params, k, v,
+                jax.ShapeDtypeStruct((SY_PREFILL,), i32, sharding=one),
+                i0, i0, i0)}
+        assert eng._caches.attn_tiles == (512, 512)
+        assert eng._caches.kv_attn_kernel == 1
+    return {name: lo.compile() for name, lo in lowered.items()}
+
+
+def test_sambay_programs_fit_beside_the_weights(sambay_programs):
+    """7.71 GB of weights (3,852,562,944 parameters in bfloat16) and
+    5.78 GB of cache are arguments of every program: 13.49 GB, 84 % of
+    the chip's 16 GB.  The cache: eight rings (2 x 128 x 8 x 512 rows of
+    2,560 B = 2.68 GB), the one slab (2.68 GB), nine states (0.38 GB in
+    float32) and tails.  A decode step adds some 0.14 GB; the prefill of
+    1,024 tokens 0.43 GB -- the pairs of the chunked scan exist for one
+    chunk of 64 at a time (ops/selective_scan.py), and the cross-decoder
+    runs over one row."""
+    stats = {n: p.memory_analysis() for n, p in sambay_programs.items()}
+    cache = 2 * SY_SLOTS * 10 * 128 * 2 * (8 * 512 + SY_SEQ) \
+        + 9 * SY_SLOTS * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert cache == 5_781_585_920
+    for name, m in stats.items():
+        assert m.alias_size_in_bytes == cache, name
+        assert 13.48e9 < m.argument_size_in_bytes < 13.50e9, name
+    args = stats["decode"].argument_size_in_bytes
+    assert 7.70e9 < args - cache < 7.72e9
+    assert stats["decode"].temp_size_in_bytes < 0.25e9
+    temps = stats[f"prefill{SY_PREFILL}"].temp_size_in_bytes
+    assert temps < 0.7e9 and args + temps < 0.9 * 16e9
+
+
+@pytest.mark.parametrize("program", ["decode", f"prefill{SY_PREFILL}"])
+def test_sambay_program_writes_state_rings_and_slab_in_place(sambay_programs,
+                                                             program):
+    """All 22 arrays (rings and slab of K and of V, nine layers' states
+    and nine layers' tails) alias their outputs; nothing but an in-place
+    update produces an array the size of the rings or the slab, and
+    nothing COPIES a state in HBM (the decode step's update of a state is
+    an elementwise fusion over it, written where it lay; what the
+    compiler moves ahead into its fast memory, ``S(1)``, is a prefetch of
+    an operand and no second home)."""
+    text = sambay_programs[program].as_text()
+    assert len(re.findall(r"may-alias|must-alias",
+                          text.split("\n", 1)[0])) == 22
+    moved = []
+    for name, dtype, dims, op in _entry_ops(text):
+        if op in ("parameter", "bitcast", "get-tuple-element", "tuple"):
+            continue
+        if dims in (SY_RING, SY_SLAB):
+            # (the decode step's row write is ``row_write.py``'s kernel,
+            # whose results are its operands' buffers; the prefill's an
+            # update in place)
+            in_place = "dynamic-update-slice" in name \
+                or "dynamic_update_slice" in name \
+                or op in ("dynamic-update-slice", "while") \
+                or _fused_root(text, name) == "dynamic-update-slice" \
+                or (op == "custom-call" and name.startswith("row_write"))
+            if not in_place:
+                moved.append((op, name, dtype, dims))
+        elif dims == SY_STATE and op == "copy":
+            moved.append((op, name, dtype, dims))
+    assert not moved
+    entry = text[text.index("\nENTRY"):]
+    homes = re.findall(r"copy-start[\w.]* = \(f32\[" + SY_STATE
+                       + r"\](\S*), f32\[" + SY_STATE + r"\](\S*),", entry)
+    assert all("S(1)" in a or "S(1)" in b for a, b in homes), homes
+
+
+def test_sambay_decode_has_the_same_operations_whatever_is_live(
+        sambay_programs):
+    """``live`` and ``pos`` reach the step as masks and as the kernels'
+    scalars: one compiled program serves every set of live slots, and in
+    it nothing loops or branches.  A keeping layer has ONE ``row_write``
+    call, K and V together, its two results the slabs it was handed
+    (eight rings and the slab: nine calls where ``caches.write_rows``
+    would be 1,152 window updates and the scalars that find them), and
+    the step some 2,300 operations in all."""
+    text = sambay_programs["decode"].as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert not re.findall(r"= [^\n]* (while|conditional)\(", entry)
+    assert "dynamic-update-slice" not in entry
+    for dims, layers in ((SY_RING, 8), (SY_SLAB, 1)):
+        part = r"bf16\[" + dims + r"\]\S*"
+        writes = re.findall(
+            r"^\s*%?row_write[\w.\-]* = \(" + part + ", " + part
+            + r"\) custom-call\(", entry, re.M)
+        assert len(writes) == layers, dims
+    executed = [op for _, _, _, op in _entry_ops(text) if op not in (
+        "parameter", "bitcast", "get-tuple-element", "tuple", "constant")]
+    assert len(executed) < 2600
+    states = re.findall(
+        rf"^\s*%?([\w.\-]+) = f32\[{SY_STATE}\]\S* parameter\(", entry, re.M)
+    assert len(states) == 9
+
+
+def test_sambay_decode_attention_is_one_kernel_a_reading_layer(
+        sambay_programs):
+    """Sixteen ``decode_attn`` calls and no other kernel that attends
+    (nine ``row_write`` calls beside them): eight under
+    ``attn_core/attn_window`` over the rings, one under ``attn_full`` and
+    seven under ``attn_cross`` over the ONE slab -- all eight handed the
+    same K and V, whole, as the full layer's in-place row write left
+    them (the scalars of the walk are vectors a slot, which the compiler
+    may keep in two places); queries ``[128, 10, 4, 128]`` padded to
+    eight rows a pair; the scores ``[128, 40, 4096]`` in no type.  The
+    prefill holds no kernel."""
+    text = sambay_programs["decode"].as_text()
+    entry = text[text.index("\nENTRY"):]
+    calls = re.findall(
+        rf"^\s*%?(decode_attn[\w.]*) = bf16\[{SY_SLOTS},10,8,128\]\S* "
+        r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        entry, re.M)
+    assert len(calls) == 16 == text.count("tpu_custom_call") - 9
+    made_by = {n: (op, dtype, dims) for n, dtype, dims, op in
+               _entry_ops(text)}
+    by_scope = {}
+    for name, operands in calls:
+        scope = re.search(r"/attn_core/(attn_\w+)/", _op_name(text, name))
+        operands = re.findall(r"%([\w.\-]+)", operands)
+        assert len(operands) == 5 + 3
+        by_scope.setdefault(scope.group(1), []).append(operands)
+    assert {k: len(v) for k, v in by_scope.items()} == {
+        "attn_window": 8, "attn_full": 1, "attn_cross": 7}
+    full = by_scope["attn_full"] + by_scope["attn_cross"]
+    for o in full + by_scope["attn_window"]:
+        assert [made_by[n][1:] for n in o[:5]] == [("s32", "1")] + [
+            ("s32", str(SY_SLOTS))] * 4
+    assert len({tuple(o[6:]) for o in full}) == 1       # one K, one V
+    for part in full[0][6:]:
+        assert made_by[part][1:] == ("bf16", SY_SLAB)
+    rings = by_scope["attn_window"]
+    for o in rings:
+        assert all(made_by[part][1:] == ("bf16", SY_RING) for part in o[6:])
+    layer = lambda name: re.search(
+        r"%" + re.escape(name) + r" = s32\[1\]\S* constant\(\{(\d)\}\)",
+        text).group(1)
+    assert sorted(layer(o[0]) for o in rings) == [str(i) for i in range(8)]
+    assert {layer(o[0]) for o in full} == {"0"}
+    for scores in (rf"\[{SY_SLOTS},40,{SY_SEQ}\]",
+                   rf"\[{SY_SLOTS},10,4,{SY_SEQ}\]",
+                   rf"\[{SY_SLOTS},10,4,1,{SY_SEQ}\]",
+                   rf"\[{SY_SLOTS},10,8,{SY_SEQ}\]"):
+        assert not re.search(scores, text), scores
+    assert "tpu_custom_call" not in \
+        sambay_programs[f"prefill{SY_PREFILL}"].as_text()
