@@ -214,32 +214,36 @@ def absorbed_tile(heads: int, seq: int, r: int, rope: int, dtype):
     return latent_attention.key_tile(seq, heads, r, rope, dtype)
 
 
-def absorbed_products(q_lat, q_rope, c, k_r, li, pos, scale):
+def absorbed_products(q_lat, q_rope, c, k_r, li, n, scale):
     """Scores, softmax and weighted sum of the absorbed order as XLA's two
     products, the ``[B, H, S]`` float32 scores between them (arguments
-    and result as ``latent_attention.latent_attn``'s): what runs where
-    the kernel does not, and what the tests hold the kernel to."""
+    and result as ``latent_attention.latent_attn``'s, zeros for a slot
+    that sees no row among them): what runs where the kernel does not,
+    every row read under the mask, and what the tests hold the kernel
+    to."""
     c_kv, k_rope = c[li][:, 0], k_r[li][:, 0]
-    see = (jnp.arange(c.shape[3]) <= pos[:, None])[:, None]     # [B, 1, S]
+    see = (jnp.arange(c.shape[3]) < n[:, None])[:, None]        # [B, 1, S]
     scores = (jnp.einsum("bhc,bsc->bhs", q_lat, c_kv,
                          preferred_element_type=F32)
               + jnp.einsum("bhr,bsr->bhs", q_rope, k_rope,
                            preferred_element_type=F32)) * scale
-    probs = jax.nn.softmax(jnp.where(see, scores, -1e30), axis=-1)
+    probs = jnp.where(see, jax.nn.softmax(
+        jnp.where(see, scores, -1e30), axis=-1), 0.0)
     return jnp.einsum("bhs,bsc->bhc", probs.astype(c_kv.dtype), c_kv)
 
 
-def absorbed_attention(ap, q_nope, q_rope, c, k_r, li, pos, scale):
+def absorbed_attention(ap, q_nope, q_rope, c, k_r, li, n, scale):
     """The absorbed order, one query row a sequence: ``q_nope`` ``[B, H,
     nope]``, ``q_rope`` ``[B, H, rope]`` over layer ``li`` of the latent
     rows themselves, the slab's parts ``c`` ``[L, B, 1, S, r]`` and
-    ``k_r`` ``[L, B, 1, S, rope]`` handed over whole, each sequence up to
-    its position ``pos`` ``[B]`` -> ``[B, H, v]``.  Scores, softmax and
-    weighted sum are one kernel over the slab where
-    :func:`absorbed_tile` gives a tile (the scores never reach memory and
-    a latent row is read once), else :func:`absorbed_products`.  Either
-    has the slab's whole shape whatever is live; the mask alone follows
-    the data."""
+    ``k_r`` ``[L, B, 1, S, rope]`` handed over whole, each sequence over
+    its first ``n`` ``[B]`` rows (0: a slot the step is not for, which
+    gets zeros) -> ``[B, H, v]``.  Scores, softmax and weighted sum are
+    one kernel over the slab where :func:`absorbed_tile` gives a tile
+    (the scores never reach memory, a latent row is read once and a tile
+    no sequence sees is not read at all), else
+    :func:`absorbed_products`.  Either has the slab's whole shape
+    whatever is live; the kernel's time follows ``n``."""
     with jax.named_scope("attn_proj"), jax.named_scope("mla_proj"):
         q_lat = jnp.einsum("bhn,hnc->bhc", q_nope, ap["w_uk"])
     tile = absorbed_tile(q_lat.shape[1], c.shape[3], c.shape[-1],
@@ -248,10 +252,10 @@ def absorbed_attention(ap, q_nope, q_rope, c, k_r, li, pos, scale):
         if tile:
             from kungfu_tpu.ops.pallas.latent_attention import latent_attn
 
-            o_lat = latent_attn(q_lat, q_rope, c, k_r, li, pos, scale,
+            o_lat = latent_attn(q_lat, q_rope, c, k_r, li, n, scale,
                                 tile=tile)
         else:
-            o_lat = absorbed_products(q_lat, q_rope, c, k_r, li, pos, scale)
+            o_lat = absorbed_products(q_lat, q_rope, c, k_r, li, n, scale)
     with jax.named_scope("attn_proj"), jax.named_scope("mla_proj"):
         return jnp.einsum("bhc,hcv->bhv", o_lat, ap["w_uv"])
 

@@ -29,9 +29,13 @@ ONE ``block``; what differs is the order the attention is computed in:
   On the TPU that is one fused kernel a layer
   (``ops/pallas/latent_attention.py``, imported when the step is traced
   and not before), elsewhere XLA's two products; ``read`` says which as
-  ``latent_attn_kernel``.  Every slot's every position is read under a
-  mask, so no operation's shape or time follows what is live
-  (docs/serving.md).
+  ``latent_attn_kernel``.  The step hands either the rows each slot may
+  see -- ``pos + 1`` of a live slot, none of another -- and the kernel
+  walks only the key tiles those reach: no operation's SHAPE follows
+  what is live, the kernel's time does (docs/serving.md), and the rows
+  it walked are counted by the step and stated as ``latent_rows_read``
+  (XLA's form reads every position of every slot under its mask, and
+  says so).
 """
 
 from __future__ import annotations
@@ -48,7 +52,7 @@ from kungfu_tpu.serve.caches import (pages_in_order, row_windows, slot_rows,
 
 #: what a decode step's ``out`` says behind the slots' tokens
 _SAYS = ("experts_touched", "expert_load_max", "assigned",
-         "latent_rows_live")
+         "latent_rows_live", "latent_rows_walked")
 
 
 def _slot_of(slab, li, slot):
@@ -86,9 +90,20 @@ class LatentCaches:
         writes no row and is counted nowhere).  Returns the slab and ONE
         int32 vector: the ``B`` tokens, then what the step says of itself
         (:data:`_SAYS`): its routing over the live slots and the expert
-        layers, and the latent rows of live contexts, a layer."""
+        layers, and the latent rows of live contexts beside those its
+        attention read, a layer."""
         cfg, model = self.cfg, self.model
         at = row_windows(pos, self.seq, live)
+        # what a slot may see: its first ``pos + 1`` rows, and none where
+        # the step is not for it
+        visible = jnp.where(live, pos + 1, 0)
+        tile = self.attn_tile()
+        if tile:
+            from kungfu_tpu.ops.pallas.latent_attention import rows_walked
+
+            walked = rows_walked(visible, tile)
+        else:       # XLA's form reads every row of every slot under a mask
+            walked = self.batch * self.seq
 
         class Step:
             """A decode step's cache: one row a slot into the slab,
@@ -102,7 +117,7 @@ class LatentCaches:
 
             def attend(_, li, ap, q_nope, q_rope, positions):
                 return arch.absorbed_attention(
-                    ap, q_nope[:, 0], q_rope[:, 0], c, k_r, li, pos,
+                    ap, q_nope[:, 0], q_rope[:, 0], c, k_r, li, visible,
                     cfg.score_scale)[:, None]
 
         h = model.embed(params, last_ids[:, None])
@@ -118,37 +133,41 @@ class LatentCaches:
             counts = jnp.stack(counts)
             says = jnp.stack([
                 jnp.sum(counts > 0), jnp.max(counts), jnp.sum(counts),
-                jnp.sum(jnp.where(live, pos + 1, 0))])
+                jnp.sum(visible), walked])
         return c, k_r, jnp.concatenate([tok, says]).astype(jnp.int32)
 
     def new_out(self):
         return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
 
-    @functools.cached_property
-    def latent_attn_kernel(self) -> int:
-        """1 where a decode step's attention is the fused kernel, 0
+    def attn_tile(self):
+        """The key tile of a decode step's fused attention kernel, None
         where it is XLA's two products: ``absorbed_attention``'s own
         choice, made from the platform and the slab's shape when the
         step is traced."""
         cfg = self.cfg
-        return int(arch.absorbed_tile(
+        return arch.absorbed_tile(
             cfg.n_heads, self.seq, cfg.kv_lora_rank, cfg.qk_rope_dim,
-            cfg.compute_dtype) is not None)
+            cfg.compute_dtype)
+
+    @functools.cached_property
+    def latent_attn_kernel(self) -> int:
+        """1 where a decode step's attention is the fused kernel, 0
+        where it is XLA's two products."""
+        return int(self.attn_tile() is not None)
 
     def read(self, out, contexts):
         """A decode step's ``out`` on the host: the slots' tokens, and
         what it says of itself as attrs of the span that waits for them
         (docs/tracing.md).  A latent row is no K/V row: this cache
         states ``latent_rows_*`` and no ``kv_*``, and its step counts
-        the live rows itself (the sum of ``contexts``, which is not
-        needed here)."""
+        both itself: the live rows (the sum of ``contexts``, which is
+        not needed here) and the rows its attention read."""
         out = np.asarray(jax.device_get(out))
         says = dict(zip(_SAYS, out[self.batch:].tolist()))
         says["experts_held"] = self.held
-        # ``decode`` attends over every position of every slot under a
-        # mask: a step that reads fewer rows has to say so here
-        # (serve/caches.py)
-        says["latent_rows_read"] = self.batch * self.seq
+        # the rows READ are the step's own count: the tiles the kernel
+        # walked, or every row where XLA's form ran
+        says["latent_rows_read"] = says.pop("latent_rows_walked")
         says["latent_attn_kernel"] = self.latent_attn_kernel
         says["expert_load_mean"] = says.pop("assigned") / self.held
         return out[:self.batch], says

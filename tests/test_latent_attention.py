@@ -1,9 +1,11 @@
 """The fused latent decode attention (``ops/pallas/latent_attention.py``)
 against the two products it replaces (``models/pangu_moe.py::
 absorbed_attention``'s XLA form) and against the expanded order, in
-interpret mode on the CPU; which of the two a shape and a platform take,
-what the cache says of it, and what importing the serving plane costs a
-process that traces no latent decode step.
+interpret mode on the CPU; that it walks only the tiles a slot's visible
+rows reach, to the same bits as the whole walk; which of the two a shape
+and a platform take, what the cache says of it and of the rows it read,
+and what importing the serving plane costs a process that traces no
+latent decode step.
 
 Nothing here times anything: ``tests/test_tpu_compile.py`` compiles the
 cell's decode program for a described v5e, the chip measures it.
@@ -30,14 +32,17 @@ L, B, H, S, R, ROPE, NOPE, V = 2, 4, 16, 512, 128, 64, 32, 32
 TILE = 128                      # four tiles a slot
 SCALE = (NOPE + ROPE) ** -0.5
 
-#: name -> the slots' last positions (``pos``), at four tiles of 128
+#: name -> the rows each slot may see (``n``: ``pos + 1`` of a live slot,
+#: 0 of one the step is not for), at four tiles of 128
 CONTEXTS = {
-    "ends_on_a_tiles_edge": (127, 255, 383, 511),
-    "one_row_into_a_tile": (128, 256, 384, 1),
-    "position_0": (0, 0, 0, 0),
-    "last_position": (S - 1, S - 1, S - 1, S - 1),
-    "differ_across_slots": (0, 130, 317, S - 1),
-    "a_slot_not_live": (200, 0, 47, 0),
+    "ends_on_a_tiles_edge": (TILE, 2 * TILE, 3 * TILE, S),
+    "one_row_into_a_tile": (TILE + 1, 2 * TILE + 1, 3 * TILE + 1, 2),
+    "one_row": (1, 1, 1, 1),
+    "every_row": (S, S, S, S),
+    "differ_across_slots": (1, 131, 318, S),
+    "a_dead_slot_between_two_live": (201, 0, 48, 0),
+    "a_dead_slot_first": (0, TILE + 1, 0, S),
+    "nothing_live": (0, 0, 0, 0),
 }
 
 
@@ -77,18 +82,82 @@ def test_kernel_equals_xlas_two_products(rows, name, chunk):
     within bfloat16's rounding of an output of order one -- wherever a
     context ends."""
     ap, q_nope, q_rope, c, k_r = rows
-    pos = jnp.asarray(CONTEXTS[name], jnp.int32)
+    n = jnp.asarray(CONTEXTS[name], jnp.int32)
     q_lat = jnp.einsum("bhn,hnc->bhc", q_nope, ap["w_uk"])
-    got = la.latent_attn(q_lat, q_rope, c, k_r, 1, pos, SCALE, tile=TILE,
+    got = la.latent_attn(q_lat, q_rope, c, k_r, 1, n, SCALE, tile=TILE,
                          chunk=chunk, interpret=True)
-    want = arch.absorbed_products(q_lat, q_rope, c, k_r, 1, pos, SCALE)
+    want = arch.absorbed_products(q_lat, q_rope, c, k_r, 1, n, SCALE)
     assert got.shape == (B, H, R) and got.dtype == BF16
     assert np.isfinite(_f32(got)).all()
     np.testing.assert_allclose(_f32(got), _f32(want), atol=2e-2, rtol=2e-2)
-    # a slot at position 0 attends to its one row and nothing else
-    for slot in np.flatnonzero(np.asarray(pos) == 0):
+    # a slot that sees one row attends to it and nothing else; one that
+    # sees none gets zeros, from either form, and not 0/0
+    for slot in np.flatnonzero(np.asarray(n) == 1):
         np.testing.assert_allclose(_f32(got[slot]), np.broadcast_to(
             _f32(c[1, slot, 0, 0]), (H, R)), atol=1e-6)
+    dead = np.asarray(n) == 0
+    assert not _f32(got)[dead].any() and not _f32(want)[dead].any()
+
+
+@pytest.fixture(scope="module")
+def dense_rows(rows):
+    """``rows``' queries over a slab with no slot of zeros: every slot
+    holds rows whether the step is for it or not."""
+    ap, q_nope, q_rope, c, k_r = rows
+    ks = jax.random.split(jax.random.PRNGKey(45), 2)
+    draw = lambda k, like: jax.random.normal(k, like.shape, F32).astype(BF16)
+    return (jnp.einsum("bhn,hnc->bhc", q_nope, ap["w_uk"]), q_rope,
+            draw(ks[0], c), draw(ks[1], k_r))
+
+
+@pytest.mark.parametrize("name", list(CONTEXTS))
+def test_skipping_a_tile_changes_no_bit_of_a_live_slots_output(dense_rows,
+                                                               name):
+    """A tile wholly past ``n[b]`` added ``exp(-1e30 - m) = 0`` to the
+    running sum and kept the accumulator: walking four tiles of 128 and
+    skipping those, the kernel gives every slot that sees a row the very
+    bits of the WHOLE walk -- one tile of all 512 positions, every chunk
+    of 64 computed under the mask, in the same order."""
+    q_lat, q_rope, c, k_r = dense_rows
+    n = jnp.asarray(CONTEXTS[name], jnp.int32)
+    skipped, whole = (la.latent_attn(
+        q_lat, q_rope, c, k_r, 1, n, SCALE, tile=tile, chunk=64,
+        interpret=True) for tile in (TILE, S))
+    np.testing.assert_array_equal(_f32(skipped), _f32(whole))
+    assert _f32(skipped)[np.asarray(n) > 0].any(axis=(1, 2)).all()
+
+
+@pytest.mark.parametrize("name", list(CONTEXTS))
+def test_a_tile_no_slot_sees_is_not_read(dense_rows, name):
+    """NaNs in every tile wholly past ``n[b]``, in every row of a slot
+    that sees none, and in the layer the call is not for: the output is
+    what it was without them.  (Under the mask alone a NaN row would
+    reach it through ``0 x NaN`` in the weighted sum.)"""
+    q_lat, q_rope, c, k_r = dense_rows
+    n = np.asarray(CONTEXTS[name])
+    # the first row no walked tile holds, a slot
+    past = (-(-n // TILE) * TILE)[None, :, None, None, None]
+    unseen = jnp.arange(S)[None, None, None, :, None] >= past
+    unseen = unseen | (jnp.arange(L) != 1)[:, None, None, None, None]
+    assert unseen[1].sum() == B * S - la.rows_walked(jnp.asarray(n), TILE)
+    run = lambda c, k_r: la.latent_attn(
+        q_lat, q_rope, c, k_r, 1, jnp.asarray(n, jnp.int32), SCALE,
+        tile=TILE, chunk=64, interpret=True)
+    got = run(jnp.where(unseen, jnp.nan, c), jnp.where(unseen, jnp.nan, k_r))
+    assert np.isfinite(_f32(got)).all()
+    np.testing.assert_array_equal(_f32(got), _f32(run(c, k_r)))
+
+
+@pytest.mark.parametrize("tile", [TILE, 2 * TILE])
+@pytest.mark.parametrize("name", list(CONTEXTS))
+def test_the_rows_walked_are_the_live_tiles_whole(name, tile):
+    """What a step that calls the kernel states as read: every tile a
+    visible row falls in, whole -- under a tile more than the live rows
+    a live slot, and nothing for a slot that sees none."""
+    n = np.asarray(CONTEXTS[name])
+    walked = int(la.rows_walked(jnp.asarray(n, jnp.int32), tile))
+    assert walked == sum(-(-int(x) // tile) * tile for x in n)
+    assert 0 <= walked - n.sum() < tile * max((n > 0).sum(), 1)
 
 
 @pytest.mark.parametrize("name", list(CONTEXTS))
@@ -99,17 +168,17 @@ def test_absorbed_attention_takes_the_kernel_and_equals_both_orders(
     and, like it, the expanded order's over the same rows (keys and
     values of every row formed first)."""
     ap, q_nope, q_rope, c, k_r = rows
-    pos = jnp.asarray(CONTEXTS[name], jnp.int32)
+    n = jnp.asarray(CONTEXTS[name], jnp.int32)
     assert arch.absorbed_tile(H, S, R, ROPE, BF16) == 512
     calls = []
     plain = la.latent_attn
     monkeypatch.setattr(la, "latent_attn", lambda *a, **k: calls.append(
         k["tile"]) or plain(*a, **{**k, "tile": TILE}))
-    got = arch.absorbed_attention(ap, q_nope, q_rope, c, k_r, 1, pos, SCALE)
+    got = arch.absorbed_attention(ap, q_nope, q_rope, c, k_r, 1, n, SCALE)
     assert calls == [512]
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert arch.absorbed_tile(H, S, R, ROPE, BF16) is None
-    xla = arch.absorbed_attention(ap, q_nope, q_rope, c, k_r, 1, pos, SCALE)
+    xla = arch.absorbed_attention(ap, q_nope, q_rope, c, k_r, 1, n, SCALE)
     assert calls == [512]                       # the einsums, this time
     np.testing.assert_allclose(_f32(got), _f32(xla), atol=3e-2, rtol=3e-2)
     wide = jax.tree_util.tree_map(lambda a: a.astype(F32),
@@ -117,9 +186,12 @@ def test_absorbed_attention_takes_the_kernel_and_equals_both_orders(
                                    k_r[1][:, 0]))
     k_nope, v = arch.expand(wide[0], wide[3])
     for i in range(B):
+        if not n[i]:            # no row: zeros, which W_uv leaves zeros
+            assert not _f32(got[i]).any() and not _f32(xla[i]).any()
+            continue
         want = arch.expanded_attention(
             wide[1][i][None], wide[2][i][None], k_nope[i], wide[4][i], v[i],
-            pos[i][None], SCALE)[0]
+            n[i][None] - 1, SCALE)[0]
         np.testing.assert_allclose(_f32(got[i]), _f32(want), atol=6e-2,
                                    rtol=3e-2)
 
@@ -156,13 +228,14 @@ def test_the_cells_slab_takes_the_largest_tile_that_fits():
 SMALL = dict(vocab_size=64, d_model=64, n_layers=2, n_dense=1, n_heads=8,
              qk_nope_dim=16, qk_rope_dim=16, v_head_dim=16, q_lora_rank=32,
              d_ff=64, d_expert=32, n_experts=4, experts_held=(0, 4), top_k=2,
-             max_seq=256)
+             max_seq=384)
 
 
-def _small(kv_rank):
-    """(the model, the cache of four slots that serves it)."""
+def _small(kv_rank, seq=256):
+    """(the model, the cache of four slots of ``seq`` positions that
+    serves it: 256 is one key tile a slot, 384 three of 128)."""
     model = arch.PanguMoe(arch.PanguMoeConfig(**SMALL, kv_lora_rank=kv_rank))
-    return model, LatentCaches(model, 4, 256)
+    return model, LatentCaches(model, 4, seq)
 
 
 def _decode_jaxpr(model, caches):
@@ -188,11 +261,39 @@ def test_the_cache_says_which_form_its_decode_step_took(
     # (the jitted call is printed once and named where it is called)
     assert text.count("name=_call") == kernel * model.cfg.n_layers
     assert ("pallas_call" in text) == bool(kernel)
-    out = np.arange(caches.batch + 4, dtype=np.int32)
+    out = np.arange(caches.batch + 5, dtype=np.int32)
     tokens, says = caches.read(out, np.asarray([3, 5]))
     assert says["latent_attn_kernel"] == kernel
-    assert says["latent_rows_read"] == caches.batch * caches.seq
+    # the rows read are the step's own count, the last thing it says
+    assert says["latent_rows_read"] == caches.batch + 4
+    assert says["latent_rows_live"] == caches.batch + 3
+    assert "latent_rows_walked" not in says
     assert tokens.tolist() == list(range(caches.batch))
+
+
+@pytest.mark.parametrize("backend,read", [("tpu", 128 + 128 + 384),
+                                          ("cpu", 4 * 384)])
+def test_the_cache_states_the_rows_its_step_read(on_tpu, monkeypatch,
+                                                 backend, read):
+    """``latent_rows_read`` on ``kf:serve.decode_read`` is counted IN the
+    step: through the kernel the tiles it walked a layer -- one of three
+    for the contexts of 1 and of 128 rows, all three for the one of 384,
+    none for the slot that is not live -- and ``batch x seq`` through
+    XLA's two products, which read every row under their mask; the live
+    rows beside it are the same count either way."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    model, caches = _small(128, seq=384)
+    assert caches.attn_tile() == (128 if backend == "tpu" else None)
+    params = model.init(jax.random.PRNGKey(3))
+    out = caches.decode(
+        params, *caches.new_slabs(), jnp.asarray([5, 9, 11, 2], jnp.int32),
+        jnp.asarray([0, 127, 200, 383], jnp.int32),
+        jnp.asarray([True, True, False, True]))[2]
+    assert out.shape == caches.new_out().shape
+    _, says = caches.read(out, np.asarray([1, 128, 384]))
+    assert says["latent_rows_live"] == 1 + 128 + 384
+    assert says["latent_rows_read"] == read
+    assert says["latent_attn_kernel"] == (backend == "tpu")
 
 
 def test_a_decode_step_through_the_kernel_decodes_what_xlas_form_decodes(

@@ -178,7 +178,7 @@ def test_the_absorbed_order_equals_the_expanded_one(adapter, contexts):
     # two, the other one zeros)
     slab = lambda rows: jnp.stack([jnp.zeros_like(rows), rows])[:, :, None]
     got = arch.absorbed_attention(ap, q_nope, q_rope, slab(c_kv), slab(k_r),
-                                  1, pos, scale)
+                                  1, pos + 1, scale)
     k_nope, v = arch.expand(ap, c_kv)
     assert k_nope.shape == v.shape == (b, heads, 8, MAX_SEQ)
     for i in range(b):

@@ -165,9 +165,11 @@ def test_the_hosts_contexts_sum_to_the_latent_steps_own_count(
 def test_nothing_of_it_is_in_the_step(built, family):
     """The counts are the host's: the decode program lowers to the same
     text with the cache's ``read`` and with one that states nothing, and
-    its ``out`` is as long as it was -- but for the hybrid cache's, which
-    since PR 39 carries the one count that IS the step's: the rows its
-    attention read (``kv_rows_read``; tests/test_solar_open2.py)."""
+    its ``out`` is as long as it was -- but for the hybrid cache's and,
+    since PR 45, the latent cache's, which carry the one count that IS
+    the step's: the rows its attention read (``kv_rows_read``,
+    tests/test_solar_open2.py; ``latent_rows_read``,
+    tests/test_latent_attention.py)."""
     i32 = jnp.zeros(SLOTS, jnp.int32)
 
     def lowered(eng):
@@ -177,5 +179,5 @@ def test_nothing_of_it_is_in_the_step(built, family):
     eng, bare = engine(built, family), engine(built, family)
     bare._caches.read = lambda out, contexts=None: (np.asarray(out), {})
     assert lowered(eng) == lowered(bare)
-    says = {"latent": 4}.get(family) or KV[family][2]
+    says = {"latent": 5}.get(family) or KV[family][2]
     assert eng._caches.new_out().shape == (SLOTS + says,)
