@@ -19,9 +19,10 @@ chunked form needs quotients of such products.
   trips over the state (it cannot hold a head's matrix across the
   reduction, so the update reads the state again); :func:`kda_update`,
   which a served layer calls, takes the same rule as one kernel on the
-  TPU -- each head's matrix read once and written once, where it lies
-  -- and this form everywhere else.  Either is an elementwise loop
-  whose shape is the slab's whatever is live.
+  TPU -- each head's matrix read once and written once, where it lies,
+  and only the LIVE slots' (``ops/pallas/kda_step.py``) -- and this form
+  everywhere else.  Either has the slab's shapes whatever is live; this
+  form moves every slot's state under a select.
 * :func:`kda_chunked` -- a whole prompt, ``CHUNK`` positions at a time.
   Within a chunk the corrections ``w_s = b_s (v_s - S'_s^T k_s)`` solve a
   unit lower-triangular system that does not involve the state the chunk
@@ -82,19 +83,38 @@ def kda_update_heads(heads: int, dk: int, dv: int, dtype):
     return kernel.head_block(heads, dk, dv, dtype)
 
 
-def kda_update(state, q, k, v, g, b, live):
+def kda_moves(heads: int, dk: int, dv: int, dtype, live):
+    """What the KDA layers of ONE decode step share, from the slots it is
+    ``live`` for (``[B]`` bool): (``walk``, ``moved``).  Where
+    :func:`kda_update_heads` gives a head block, ``walk`` is the kernel's
+    plan of the blocks its grid steps hold (``kda_step.plan``: a few
+    scalar operations, made once here and not once a layer) and
+    ``moved`` its own count of the slots whose matrices a call reads and
+    writes back -- the live ones; else ``walk`` is None and ``moved`` is
+    ``B``: :func:`kda_step` moves every slot under its select."""
+    block = kda_update_heads(heads, dk, dv, dtype)
+    if not block:
+        return None, live.shape[0]
+    from kungfu_tpu.ops.pallas import kda_step as kernel
+
+    return kernel.plan(live, heads // block), kernel.slots_walked(live)
+
+
+def kda_update(state, q, k, v, g, b, live, walk=None):
     """:func:`kda_step` for one layer's state as the serving cache holds
     it, ``[1, B, H, K, V]`` -> (the new state, shaped like ``state``;
-    ``o`` ``[B, H, V]`` float32): the kernel, which reads and writes
-    every head's matrix once and in place, where
-    :func:`kda_update_heads` gives a head block, else :func:`kda_step`.
-    Either moves every slot's state whatever is live; ``live`` alone
-    follows the data, and only selects what is written."""
+    ``o`` ``[B, H, V]`` float32): the kernel, which reads and writes a
+    live slot's matrices once and in place and touches no other slot's
+    (their ``o`` is zeros), where :func:`kda_update_heads` gives a head
+    block -- ``walk`` is then :func:`kda_moves`' for ``live``, or made
+    here -- else :func:`kda_step`, which moves every slot's whatever is
+    live.  The shapes and the operations are the same whatever is live;
+    the slots the kernel skips follow the data, and so does its time."""
     heads = kda_update_heads(*state.shape[2:], state.dtype)
     if heads:
         from kungfu_tpu.ops.pallas.kda_step import kda_step as kernel
 
-        return kernel(state, q, k, v, g, b, live, heads=heads)
+        return kernel(state, q, k, v, g, b, live, heads=heads, walk=walk)
     new, o = kda_step(state[0], q, k, v, g, b, live)
     return new[None], o
 

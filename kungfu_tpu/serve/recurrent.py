@@ -38,18 +38,23 @@ Both bodies drive the model's ONE ``block``:
   tiny sizes' heads of 8, ``cohere2_moe.attention`` over every row under
   a mask; ``kv_attn_kernel`` on ``kf:serve.decode_read`` says which, and
   ``kv_rows_read`` the rows the step itself counted as read -- and
-  updates every slot's state in place (``delta_rule.kda_update``: on
-  the TPU one kernel a layer, ``ops/pallas/kda_step.py``, which reads a
-  head's matrix once, takes both read-outs and the update from it and
-  writes it back where it lay; off the TPU, and at the tiny sizes'
-  heads of 8, XLA's ``delta_rule.kda_step``, three trips over the
-  state; ``kda_step_kernel`` on ``kf:serve.decode_read`` says which);
-  a slot the step is not ``live`` for keeps its state and its tail
-  (the loop runs one step ahead, so such a slot may hold a request
-  that has just ended, or nothing).  Every slot is processed every
-  step and no operation's shape follows what is live; of the times,
-  the attention kernel's follows the live contexts (the tiles it
-  skips), by what PERF.md, PR 39, measured over six seeds
+  updates the live slots' states in place (``delta_rule.kda_update``:
+  on the TPU one kernel a layer, ``ops/pallas/kda_step.py``, which
+  reads a head's matrix once, takes both read-outs and the update from
+  it and writes it back where it lay, and neither copies nor computes a
+  slot the step is not ``live`` for -- its grid steps follow a plan of
+  a few scalars, ``delta_rule.kda_moves``, made once a step for the
+  three layers' calls; off the TPU, and at the tiny sizes' heads of 8,
+  XLA's ``delta_rule.kda_step``, three trips over every slot's state
+  under a select; ``kda_step_kernel`` on ``kf:serve.decode_read`` says
+  which, and ``state_slots_read`` the slots whose matrices the step
+  itself counted as moved); a slot the step is not ``live`` for keeps
+  its state and its tail (the loop runs one step ahead, so such a slot
+  may hold a request that has just ended, or nothing).  No operation's
+  shape follows what is live; of the times, both kernels' do -- the
+  attention's follows the live contexts (the tiles it skips: PERF.md,
+  PR 39), the update's the live slots (PERF.md, PR 46) -- and the
+  tails' shift, XLA's select, still moves every slot's
   (docs/serving.md).
 
 Pages: a KDA layer keeps nothing at a page's end that a later request
@@ -79,7 +84,7 @@ F32 = jnp.float32
 STATE_DTYPE = jnp.dtype("float32")
 #: what a decode step's ``out`` says behind the slots' tokens
 _SAYS = ("experts_touched", "expert_load_max", "assigned",
-         "state_slots_live", "kv_rows_walked")
+         "state_slots_live", "state_slots_moved", "kv_rows_walked")
 
 
 class HybridCaches:
@@ -95,12 +100,14 @@ class HybridCaches:
         self.held = cfg.n_layers * cfg.experts_held[1]
         self.prefill_flops = model.prefill_flops
         self.decode_flops = model.decode_flops
-        #: what the KDA layers keep for all the slots: the bytes a decode
-        #: step reads (and writes back) whatever is live
+        #: what the KDA layers keep: a slot's matrices, which a decode
+        #: step reads (and writes back) for the slots it moves, and every
+        #: slot's convolution tails, which it moves whatever is live
         _, state, tails = self.shapes()
-        self.state_bytes = len(cfg.recurrent_layers) * int(
-            np.prod(state) * STATE_DTYPE.itemsize
-            + np.prod(tails) * cfg.compute_dtype.itemsize)
+        self.slot_state_bytes = len(cfg.recurrent_layers) * int(
+            np.prod(state[2:]) * STATE_DTYPE.itemsize)
+        self.tail_bytes = len(cfg.recurrent_layers) * int(
+            np.prod(tails) * cfg.compute_dtype.itemsize)
 
     # -- the parts ---------------------------------------------------------
     def shapes(self):
@@ -128,8 +135,9 @@ class HybridCaches:
         writes no row, keeps its state and tail, and is counted
         nowhere).  Returns the parts and ONE int32 vector: the ``B``
         tokens, then what the step says of itself (:data:`_SAYS`): its
-        routing over the live slots and all layers, the slots whose
-        state it moved, and the K/V rows its attention read."""
+        routing over the live slots and all layers, the slots it was
+        for, the slots whose matrices it moved, and the K/V rows its
+        attention read."""
         cfg, model = self.cfg, self.model
         (kr, state), (vr, tails) = k, v
         state, tails = list(state), list(tails)
@@ -145,11 +153,18 @@ class HybridCaches:
         else:       # XLA's form reads every row of every slot under a mask
             see = (jnp.arange(self.seq) <= pos[:, None])[:, None, None, None]
             walked = self.batch * self.seq
+        # the blocks the KDA kernel's grid steps hold, once for the three
+        # layers' calls, and the slots whose matrices a call moves: the
+        # live ones, or all of them where XLA's form runs
+        with jax.named_scope("attn_core"), jax.named_scope("kda_state"):
+            hd = cfg.kda_head_dim
+            kda_walk, moved = delta_rule.kda_moves(
+                cfg.kda_heads, hd, hd, STATE_DTYPE, live)
 
         class Step:
             """A decode step's cache: one row a slot into the slab and
-            attention over the slab itself; one token into every slot's
-            state."""
+            attention over the slab itself; one token into every live
+            slot's state."""
 
             def write(_, li, kn, vn):
                 nonlocal kr, vr
@@ -180,7 +195,7 @@ class HybridCaches:
                 with jax.named_scope("kda_state"):
                     state[i], o = delta_rule.kda_update(
                         state[i], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                        b[:, 0], live)
+                        b[:, 0], live, kda_walk)
                 return o[:, None]
 
         h = model.embed(params, last_ids[:, None])
@@ -194,7 +209,7 @@ class HybridCaches:
         with jax.named_scope("moe_router"):
             counts = jnp.stack(counts)
             says = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
-                              jnp.sum(counts), jnp.sum(live),
+                              jnp.sum(counts), jnp.sum(live), moved,
                               len(cfg.gqa_layers) * walked])
         return ((kr, tuple(state)), (vr, tuple(tails)),
                 jnp.concatenate([tok, says]).astype(jnp.int32))
@@ -257,10 +272,12 @@ class HybridCaches:
         says["kv_attn_kernel"] = self.kv_attn_kernel
         says["experts_held"] = self.held
         says["expert_load_mean"] = says.pop("assigned") / self.held
-        # ``decode`` moves every slot's state whatever is live: a step
-        # that reads fewer has to say so here (serve/caches.py)
-        says["state_slots_read"] = self.batch
-        says["state_bytes_read"] = self.state_bytes
+        # ... and of the states the slots whose matrices the step moved:
+        # the live ones as the kernel counted them, or every slot where
+        # XLA's form ran; the tails are moved for every slot by either
+        moved = says["state_slots_read"] = says.pop("state_slots_moved")
+        says["state_bytes_read"] = (moved * self.slot_state_bytes
+                                    + self.tail_bytes)
         says["kda_step_kernel"] = self.kda_step_kernel
         return out[:self.batch], says
 

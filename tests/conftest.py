@@ -18,6 +18,16 @@ os.environ["XLA_FLAGS"] = (
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+# The TPU interpreter (``pltpu.force_tpu_interpret_mode``, ``interpret=``
+# with ``InterpretParams``) runs a kernel's copies as callbacks that
+# dispatch JAX operations of their own.  Under the CPU's asynchronous
+# dispatch a test that issues its next operation while an interpreted
+# kernel is in flight queues it AHEAD of the callbacks' and behind the
+# kernel: each waits for the other, and the worker sits in a futex until
+# the run is cut (seen three times on six and on three workers, PR 46:
+# PERF.md section 7, item 0j iv; ROADMAP D12 b).  Computations run inline
+# instead; read when the backend starts, which has not happened yet.
+jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 import pytest
 

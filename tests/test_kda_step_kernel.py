@@ -29,8 +29,15 @@ F32 = jnp.float32
 #: a shape that tiles: four slots, 16 heads of 128 x 128
 B, H, K, V = 4, 16, 128, 128
 
-#: name -> which of the four slots a step is live for
-LIVE = {"all": (1, 1, 1, 1), "none": (0, 0, 0, 0), "mixed": (1, 0, 0, 1)}
+#: name -> the slots of six a step is live for
+LIVE = {"all": (1, 1, 1, 1, 1, 1), "none": (0, 0, 0, 0, 0, 0),
+        "mixed": (1, 0, 0, 1, 0, 0), "first_alone": (1, 0, 0, 0, 0, 0),
+        "last_alone": (0, 0, 0, 0, 0, 1),
+        "dead_before_the_first_live": (0, 0, 1, 1, 0, 1),
+        "a_dead_one_between_two_live": (0, 1, 0, 1, 1, 0),
+        "a_random_half": tuple(int(x) for x in np.random.default_rng(
+            46).permutation([1, 1, 1, 0, 0, 0]))}
+SLOTS = 6
 
 
 def draw(seed, b=B, h=H, k=K, v=V):
@@ -47,6 +54,18 @@ def draw(seed, b=B, h=H, k=K, v=V):
             2.0 * jax.nn.sigmoid(jax.random.normal(r[5], (b, h), F32)))
 
 
+def bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def interpreted(*args, **kw):
+    """``kda_step`` in the TPU interpreter, waited for.  The interpreter's
+    callbacks run JAX operations of their own, and the CPU's dispatch is
+    asynchronous: a test that dispatched its next operation while they
+    ran has waited for them, and they for it, until the run was cut."""
+    return jax.block_until_ready(ks.kda_step(*args, interpret=True, **kw))
+
+
 @pytest.fixture
 def on_tpu(monkeypatch):
     """What the code can see says TPU, and every Pallas kernel runs in
@@ -56,26 +75,116 @@ def on_tpu(monkeypatch):
         yield
 
 
+@pytest.fixture(scope="module")
+def whole_walk():
+    """heads -> (state, the vectors, the new state and ``o``) of the
+    kernel with every slot live: what a live slot's share of any other
+    step has to equal bit for bit."""
+    state, *x = draw(37, SLOTS)
+    return {heads: (state, x) + tuple(interpreted(
+        state, *x, jnp.ones(SLOTS, bool), heads=heads))
+        for heads in (8, 16)}
+
+
 @pytest.mark.parametrize("heads", [8, 16])
 @pytest.mark.parametrize("name", list(LIVE))
-def test_kernel_equals_xlas_kda_step(name, heads):
+def test_kernel_equals_xlas_kda_step(name, heads, whole_walk):
     """One load of a head's matrix gives both read-outs and the update:
-    the new state and ``o`` are ``kda_step``'s to float32's rounding,
-    whatever is live and at either head block, and a slot that is not
-    live has the very bits it had."""
-    state, *x = draw(37)
+    a live slot's new state and ``o`` are ``kda_step``'s to float32's
+    rounding and the whole walk's to the bit, whatever else is live and
+    at either head block; a slot that is not live has the very bits it
+    had, and zeros for ``o``."""
+    state, x, all_new, all_o = whole_walk[heads]
     live = jnp.asarray(LIVE[name], bool)
-    new, o = ks.kda_step(state, *x, live, heads=heads, interpret=True)
+    new, o = interpreted(state, *x, live, heads=heads)
     want, want_o = delta_rule.kda_step(state[0], *x, live)
     assert new.shape == state.shape and new.dtype == F32
-    assert o.shape == (B, H, V) and o.dtype == F32
+    assert o.shape == (SLOTS, H, V) and o.dtype == F32
     np.testing.assert_allclose(new[0], want, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(o, want_o, rtol=1e-5, atol=1e-6)
-    dead = np.flatnonzero(~np.asarray(live))
-    np.testing.assert_array_equal(np.asarray(new)[0, dead].view(np.uint32),
-                                  np.asarray(state)[0, dead].view(np.uint32))
-    for slot in np.flatnonzero(np.asarray(live)):   # ... a live one moved
+    alive, dead = (np.flatnonzero(np.asarray(live) == x) for x in (1, 0))
+    np.testing.assert_allclose(np.asarray(o)[alive],
+                               np.asarray(want_o)[alive], rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_array_equal(bits(new)[0, alive], bits(all_new)[0, alive])
+    np.testing.assert_array_equal(bits(o)[alive], bits(all_o)[alive])
+    np.testing.assert_array_equal(bits(new)[0, dead], bits(state)[0, dead])
+    assert not np.asarray(o)[dead].any()
+    for slot in alive:                              # ... a live one moved
         assert np.abs(np.asarray(new - state)[0, slot]).max() > 0.1
+
+
+@pytest.mark.parametrize("heads", [8, 16])
+@pytest.mark.parametrize("name", list(LIVE))
+def test_a_dead_slots_state_is_neither_read_nor_written(name, heads,
+                                                        whole_walk):
+    """NaNs planted all through every dead slot's state reach no ``o``,
+    and those states come out bit-equal, NaNs and all; the live slots'
+    are the whole walk's to the bit.  The interpreter keeps one buffer a
+    block, NaNs where nothing has written, copies a block in when the
+    block named changes and writes one back when the next step names
+    another: a dead step that named another block than the step before
+    would write that step's bytes over a state (a); dead slots before
+    the first live one that named a block nobody fills would write NaNs
+    (b); and so would a step for nothing at all (c: ``none``)."""
+    state, x, all_new, all_o = whole_walk[heads]
+    live = np.asarray(LIVE[name], bool)
+    state = jnp.where(live[None, :, None, None, None], state, jnp.nan)
+    new, o = interpreted(state, *x, jnp.asarray(live), heads=heads)
+    assert not np.isnan(np.asarray(o)).any()
+    assert not np.asarray(o)[~live].any()
+    np.testing.assert_array_equal(bits(new)[0, ~live], bits(state)[0, ~live])
+    assert np.isnan(np.asarray(new)[0, ~live]).all()
+    np.testing.assert_array_equal(bits(new)[0, live], bits(all_new)[0, live])
+    np.testing.assert_array_equal(bits(o)[live], bits(all_o)[live])
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+@pytest.mark.parametrize("name", list(LIVE))
+def test_a_dead_step_names_the_block_the_step_before_held(name, blocks):
+    """The plan, step by step as the pipeline reads it: a live step
+    names its own block; a dead step the block of the step before it;
+    the first step of all, where it is dead, the first live slot's first
+    block (which is then named without a break until that slot's step
+    fills it) or, with nothing live, the one block every step names."""
+    live = np.asarray(LIVE[name], bool)
+    at, lo, hi = (np.asarray(x) for x in ks.plan(jnp.asarray(live), blocks))
+    steps = [(b, j) for b in range(SLOTS) for j in range(blocks)]
+    named = [(int(at[b]), int(np.clip(j, lo[b], hi[b]))) for b, j in steps]
+    for step, (b, j) in enumerate(steps):
+        if live[b]:
+            assert named[step] == (b, j)
+        elif step:
+            assert named[step] == named[step - 1]
+    first = int(np.argmax(live)) if live.any() else 0
+    assert named[0] == (first, 0)
+    assert int(ks.slots_walked(jnp.asarray(live))) == live.sum()
+    # bools and the int32 the kernel prefetches plan alike
+    for a, b in zip(ks.plan(jnp.asarray(live, jnp.int32), blocks),
+                    (at, lo, hi)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+
+
+def _own_blocks(live, blocks):
+    """A plan that breaks the rule above: a dead step names its own
+    slot's blocks, as a live one does."""
+    slots = jnp.arange(live.shape[0], dtype=jnp.int32)
+    return slots, jnp.zeros_like(slots), jnp.full_like(slots, blocks - 1)
+
+
+@pytest.mark.parametrize("name", ["a_dead_one_between_two_live",
+                                  "dead_before_the_first_live"])
+def test_the_interpreter_shows_what_a_wrong_plan_does(name):
+    """The hazard is the interpreter's too: under a plan whose dead steps
+    name their own blocks, a dead slot's state comes out holding what the
+    buffer held -- the new state of the live slot before it (a), or what
+    the first step copied through (b).  So the cases above fail where
+    the plan names a wrong block."""
+    state, *x = draw(43, SLOTS)
+    live = jnp.asarray(LIVE[name], bool)
+    new, _ = interpreted(state, *x, live, heads=8,
+                         walk=_own_blocks(live, 2))
+    dead = np.flatnonzero(~np.asarray(live))
+    assert (bits(new)[0, dead] != bits(state)[0, dead]).any()
 
 
 @pytest.mark.parametrize("shape", [(2, 8, 256, 128), (2, 8, 128, 256),
@@ -88,28 +197,30 @@ def test_kernel_at_other_widths_and_the_widest_block(shape):
     state, *x = draw(38, b, h, k, v)
     live = jnp.asarray([True, False][:b])
     heads = 32 if h == 32 else None
-    new, o = ks.kda_step(state, *x, live, heads=heads, interpret=True)
+    new, o = interpreted(state, *x, live, heads=heads)
     want, want_o = delta_rule.kda_step(state[0], *x, live)
     np.testing.assert_allclose(new[0], want, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(o, want_o, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(o[0], want_o[0], rtol=1e-5, atol=1e-6)
+    assert not np.asarray(o)[1:].any()
 
 
 @pytest.mark.parametrize("heads", [8, 16])
 def test_a_run_of_steps_leaves_a_dead_slots_state_bit_equal(heads):
-    """Five steps, another set of live slots each: after every step a
-    slot the step was not live for holds the bits it held before it, the
-    others ``kda_step``'s; slot 2 is live for none and ends as it
-    began."""
+    """Seven steps, another set of live slots each (the first slot dead,
+    the last, a dead one between two live, none, all): after every step
+    a slot the step was not live for holds the bits it held before it
+    and has zeros for ``o``, the others ``kda_step``'s; slot 2 is live
+    for one step and ends as that step left it."""
     state, *_ = draw(39)
     began = np.asarray(state).copy()
     mine = plain = state
     masks = [(1, 1, 0, 1), (0, 1, 0, 1), (1, 0, 0, 0), (0, 0, 0, 0),
-             (1, 1, 0, 1)]
+             (1, 1, 1, 1), (0, 0, 0, 1), (1, 0, 0, 1)]
     for t, mask in enumerate(masks):
         _, *x = draw(100 + t)
         live = jnp.asarray(mask, bool)
         before = np.asarray(mine).copy()
-        mine, o = ks.kda_step(mine, *x, live, heads=heads, interpret=True)
+        mine, o = interpreted(mine, *x, live, heads=heads)
         want, want_o = delta_rule.kda_step(plain[0], *x, live)
         plain = want[None]
         dead = np.flatnonzero(~np.asarray(live))
@@ -121,8 +232,12 @@ def test_a_run_of_steps_leaves_a_dead_slots_state_bit_equal(heads):
         np.testing.assert_allclose(np.asarray(o)[alive],
                                    np.asarray(want_o)[alive], rtol=1e-5,
                                    atol=1e-5)
+        assert not np.asarray(o)[dead].any()
+        if t == 4:
+            after_its_step = np.asarray(mine)[0, 2].copy()
     np.testing.assert_array_equal(np.asarray(mine)[0, 2].view(np.uint32),
-                                  began[0, 2].view(np.uint32))
+                                  after_its_step.view(np.uint32))
+    assert np.abs(after_its_step - began[0, 2]).max() > 0.1
     assert np.abs(np.asarray(mine)[0, 0] - began[0, 0]).max() > 0.1
 
 
@@ -177,24 +292,39 @@ def test_kda_update_takes_the_kernel_on_a_tpu_and_xlas_form_off_it(
         on_tpu, monkeypatch):
     """``delta_rule.kda_update`` where the platform says TPU and the
     shapes tile: one call of the kernel at the chooser's head block,
-    whose state and ``o`` are the XLA form's; where it says CPU, and at
-    the rehearsal's heads of 8, no call."""
+    whose state and live slots' ``o`` are the XLA form's, handed the
+    plan ``kda_moves`` made for the step or making its own; where it
+    says CPU, and at the rehearsal's heads of 8, no call, no plan, and
+    every slot counted as moved."""
     state, *x = draw(41)
-    live = jnp.asarray(LIVE["mixed"], bool)
+    live = jnp.asarray((1, 0, 0, 1), bool)
     calls = []
     plain = ks.kda_step
     monkeypatch.setattr(ks, "kda_step", lambda *a, **k: calls.append(
-        k["heads"]) or plain(*a, **k))
-    new, o = delta_rule.kda_update(state, *x, live)
-    assert calls == [16]
+        (k["heads"], k["walk"])) or plain(*a, **k))
+    walk, moved = delta_rule.kda_moves(H, K, V, F32, live)
+    for mine, theirs in zip(walk, ks.plan(live, 1)):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+    assert int(moved) == 2
+    new, o = jax.block_until_ready(
+        delta_rule.kda_update(state, *x, live, walk))
+    own, own_o = jax.block_until_ready(
+        delta_rule.kda_update(state, *x, live))
+    assert calls == [(16, walk), (16, None)]
+    np.testing.assert_array_equal(bits(new), bits(own))
+    np.testing.assert_array_equal(bits(o), bits(own_o))
     small = draw(42, B, 4, 8, 8)
+    assert delta_rule.kda_moves(4, 8, 8, F32, live) == (None, B)
     tiny, tiny_o = delta_rule.kda_update(*small, live)
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert delta_rule.kda_moves(H, K, V, F32, live) == (None, B)
     xla, xla_o = delta_rule.kda_update(state, *x, live)
-    assert calls == [16]                    # XLA's form, both times
+    assert len(calls) == 2                  # XLA's form, both times
     assert xla.shape == new.shape == state.shape
     np.testing.assert_allclose(new, xla, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(o, xla_o, rtol=1e-5, atol=1e-6)
+    alive = np.flatnonzero(np.asarray(live))
+    np.testing.assert_allclose(np.asarray(o)[alive], np.asarray(xla_o)[alive],
+                               rtol=1e-5, atol=1e-6)
     want, want_o = delta_rule.kda_step(small[0][0], *small[1:], live)
     np.testing.assert_array_equal(np.asarray(tiny[0]), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(tiny_o), np.asarray(want_o))
@@ -232,7 +362,10 @@ def test_the_cache_says_which_form_its_decode_step_took(
     """``kda_step_kernel`` on ``kf:serve.decode_read`` is the choice
     ``kda_update`` made when the step was traced: the kernel on a TPU at
     a shape that tiles, one a KDA layer; XLA's form on the CPU, and on a
-    TPU at the tiny models' 4 heads of 8."""
+    TPU at the tiny models' 4 heads of 8.  ``state_slots_read`` is what
+    the step itself says it moved -- the kernel's count of the live
+    slots, every slot through XLA's form -- and ``state_bytes_read``
+    those slots' matrices and every slot's tails."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     model, caches = _small(kda)
     text = str(jax.make_jaxpr(caches.decode)(*_shapes(model, caches)))
@@ -240,19 +373,32 @@ def test_the_cache_says_which_form_its_decode_step_took(
     assert text.count("name=_call") == kernel * len(
         model.cfg.recurrent_layers) == kernel * 3
     assert ("pallas_call" in text) == bool(kernel)
-    out = np.arange(len(caches.new_out()), dtype=np.int32)
-    tokens, says = caches.read(out, np.asarray([3, 5]))
+    params = model.init(jax.random.PRNGKey(5))
+    k, v = caches.new_slabs()
+    live = jnp.asarray([True, False, True, True])
+    with pltpu.force_tpu_interpret_mode():
+        *_, out = jax.block_until_ready(caches.decode(
+            params, k, v, jnp.asarray([5, 9, 11, 2]),
+            jnp.asarray([3, 17, 8, 30]), live))
+    tokens, says = caches.read(out, np.asarray([4, 9, 31]))
     assert says["kda_step_kernel"] == kernel
-    assert says["state_slots_read"] == caches.batch
-    assert says["state_bytes_read"] == caches.state_bytes
-    assert tokens.tolist() == list(range(caches.batch))
+    assert says["state_slots_live"] == 3
+    moved = says["state_slots_read"]
+    assert moved == (3 if kernel else caches.batch) and "state_slots_moved" \
+        not in says
+    cfg = model.cfg
+    matrices = cfg.kda_heads * cfg.kda_head_dim ** 2 * 4
+    tails = caches.batch * (cfg.conv_kernel - 1) * 3 * cfg.kda_width \
+        * cfg.compute_dtype.itemsize
+    assert says["state_bytes_read"] == 3 * (moved * matrices + tails)
+    assert tokens.shape == (caches.batch,)
 
 
 def test_a_decode_step_through_the_kernel_decodes_what_xlas_form_decodes(
         on_tpu, monkeypatch):
     """One whole decode step of a small model at a tiling shape, the
     kernel interpreted, against the same step through XLA's form: the
-    same tokens and routing, states apart by float32's rounding, a dead
+    same live tokens and routing, states apart by float32's rounding, a dead
     slot's state and tail untouched by either."""
     model, caches = _small(TILES)
     params = model.init(jax.random.PRNGKey(3))
@@ -267,10 +413,18 @@ def test_a_decode_step_through_the_kernel_decodes_what_xlas_form_decodes(
     ids = jnp.asarray([5, 9, 11, 2], jnp.int32)
     pos = jnp.asarray([3, 17, 8, 31], jnp.int32)
     live = jnp.asarray([True, True, False, True])
-    kernel = caches.decode(params, k, v, ids, pos, live)
+    kernel = jax.block_until_ready(
+        caches.decode(params, k, v, ids, pos, live))
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     xla = caches.decode(params, k, v, ids, pos, live)
-    np.testing.assert_array_equal(np.asarray(kernel[2]), np.asarray(xla[2]))
+    # the live slots' tokens and the routing are the same (the dead
+    # slot's token is nobody's: its ``o`` is zeros from the kernel); of
+    # the four slots the kernel moved the three live ones and XLA's form
+    # all (``state_slots_moved``, behind ``state_slots_live``)
+    said = np.asarray([0, 1, 3, 4, 5, 6, 7, 9])
+    np.testing.assert_array_equal(np.asarray(kernel[2])[said],
+                                  np.asarray(xla[2])[said])
+    assert [int(out[2][8]) for out in (kernel, xla)] == [3, 4]
     for got, want, was in zip(kernel[0][1], xla[0][1], k[1]):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(got)[0, 2],

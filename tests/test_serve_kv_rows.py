@@ -47,7 +47,7 @@ ASKED = {rid: (np.random.default_rng(40 + i).integers(0, 96, p).tolist(), n)
 #: decode step's ``out`` holds behind the slots' tokens)
 KV = {"dense": ([(2, MAX_SEQ)], 2 * 16 * 2 * 4, 0),
       "windowed": ([(3, windowed.WINDOW), (1, MAX_SEQ)], 2 * 8 * 2 * 4, 3),
-      "hybrid": ([(1, MAX_SEQ)], 2 * 8 * 2 * 4, 5)}
+      "hybrid": ([(1, MAX_SEQ)], 2 * 8 * 2 * 4, 6)}
 
 
 @pytest.fixture(scope="module")
@@ -166,10 +166,12 @@ def test_nothing_of_it_is_in_the_step(built, family):
     """The counts are the host's: the decode program lowers to the same
     text with the cache's ``read`` and with one that states nothing, and
     its ``out`` is as long as it was -- but for the hybrid cache's and,
-    since PR 45, the latent cache's, which carry the one count that IS
+    since PR 45, the latent cache's, which carry the counts that ARE
     the step's: the rows its attention read (``kv_rows_read``,
     tests/test_solar_open2.py; ``latent_rows_read``,
-    tests/test_latent_attention.py)."""
+    tests/test_latent_attention.py) and, the hybrid cache's since PR 46,
+    the slots whose matrices it moved (``state_slots_read``,
+    tests/test_kda_step_kernel.py)."""
     i32 = jnp.zeros(SLOTS, jnp.int32)
 
     def lowered(eng):

@@ -537,20 +537,22 @@ def test_read_states_the_rows_the_step_itself_counted(adapter, monkeypatch,
     text = str(jax.make_jaxpr(caches.decode)(
         params, k, v, slots, slots, jax.ShapeDtypeStruct((4,), bool)))
     assert text.count("name=decode_attn") == kernel
-    assert len(caches.new_out()) == 4 + 5
+    assert len(caches.new_out()) == 4 + 6
     # what the step of slots at positions 4, 127, 128 (not live) and 300
     # puts behind its tokens, taken apart by ``read``
     pos, live = np.asarray([4, 127, 128, 300]), [True, True, False, True]
     n = [p + 1 if l else 0 for p, l in zip(pos, live)]
     count = walked(n) if kernel else 4 * WIDE_SEQ
     assert walked(n) == 128 + 128 + 0 + 384
-    out = np.asarray([7, 8, 9, 10, 12, 1, 12, 3, count], np.int32)
+    out = np.asarray([7, 8, 9, 10, 12, 1, 12, 3, 3, count], np.int32)
     tokens, says = caches.read(out, np.asarray([5, 128, 301]))
     assert tokens.tolist() == [7, 8, 9, 10]
     assert says["kv_rows_read"] == count and says["kv_attn_kernel"] == kernel
     assert says["kv_rows_live"] == 5 + 128 + 301
     assert says["kv_rows_written"] == 3 and says["kv_row_bytes"] == 1024
     assert says["state_slots_live"] == 3 and "kv_rows_walked" not in says
+    # ... and the slots whose matrices it says it moved (PR 46)
+    assert says["state_slots_read"] == 3 and "state_slots_moved" not in says
 
 
 def test_the_step_counts_the_rows_its_kernel_walks(adapter, monkeypatch):

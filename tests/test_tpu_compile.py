@@ -620,7 +620,7 @@ def test_latent_programs_fit_beside_the_weights(mla_programs):
 #: the vocabulary, 128 slots of 4,096 positions
 KDA_SLOTS, KDA_SEQ, KDA_LAYERS, KDA_HELD = 128, 4096, 3, 10
 KDA_PREFILL = (256, 2048)
-KDA_SAYS = 5        # what a step's ``out`` holds behind the tokens
+KDA_SAYS = 6        # what a step's ``out`` holds behind the tokens
 KDA_STATE = f"{KDA_SLOTS},64,128,128"
 KDA_ROWS = f"{KDA_SLOTS},8,{KDA_SEQ},128"
 
@@ -726,15 +726,18 @@ def test_hybrid_program_updates_state_and_slab_in_place(kda_programs,
 
 
 def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
-    """No operation of the decode step follows the data (PERF.md, PR 26):
-    ``live`` reaches the step as a mask, so one compiled program serves
-    every set of live slots, and in it nothing loops or branches, no
-    product is grouped by the routing, and every KDA layer's state --
-    all 128 slots of it -- is read by exactly ONE operation: the
-    ``kda_step`` kernel under ``attn_core/kda_state``, whose grid is
-    every slot of every head and whose first output aliases the state.
-    Two trips over 1.61 GB, whatever is live (three before the kernel:
-    one reduction and one update fusion a state)."""
+    """No operation's SHAPE follows the data (PERF.md, PR 26): ``live``
+    reaches the step as a mask, so one compiled program serves every set
+    of live slots, and in it nothing loops or branches, no product is
+    grouped by the routing, and every KDA layer's state -- all 128 slots
+    of it -- is handed to exactly ONE operation: the ``kda_step`` kernel
+    under ``attn_core/kda_state``, whose grid is every slot of every
+    head block and whose first output aliases the state.  The shapes are
+    what stays: the bytes and the time are the live slots' since PR 46
+    (a dead slot's grid step names the block the step before it held,
+    from the four vectors of scalars the three calls share, and is
+    neither copied nor computed), two trips over 1.61 GB only where all
+    128 are live."""
     text = kda_programs["decode"].as_text()
     assert "ragged" not in text
     entry = text[text.index("\nENTRY"):]
@@ -758,8 +761,29 @@ def test_hybrid_decode_has_the_same_operations_whatever_is_live(kda_programs):
         operands, aliased, op_name = kernels[name]
         assert aliased == state and operands.count(state) == 1
         assert "/attn_core/kda_state/" in op_name and "kda_step" in op_name
+        # ``live`` and the plan, the five vectors, the state
+        assert len(operands) == 4 + 5 + 1
         seen.add(name)
     assert len(seen) == KDA_LAYERS
+    # the plan is made once a step, under the kernel's scope: the three
+    # calls are handed the same four vectors of scalars (the later calls
+    # through the compiler's own copies between memories)
+    def made_by(name):
+        while True:
+            moved = re.search(r"%?" + re.escape(name) + r" = [^\n]*? "
+                              r"copy-(?:done|start)\(%?([\w.\-]+)\)", entry)
+            if not moved:
+                return name
+            name = moved.group(1)
+
+    plans = {tuple(made_by(x) for x in kernels[name][0][:4])
+             for name in seen}
+    assert len(plans) == 1
+    for scalars in plans.pop():
+        made, = re.findall(r"%?" + re.escape(scalars) + r" = (\S+) [^\n]*",
+                           entry)
+        assert made.startswith(f"s32[{KDA_SLOTS}]"), (scalars, made)
+    assert "/attn_core/kda_state/" in _op_name(text, scalars)
     # the routed product: one batched product over the ten held experts
     experts_w = re.findall(
         r"%(params__layer_\d____moe____experts____(?:gate|up|down)__[.\d]*) = "
