@@ -23,8 +23,8 @@ new window fills.  At ``max_seq`` 32,768, ``W`` 2,048 and ``C`` 16 a slot
 and layer is 4,096 rows where full attention keeps 32,768.
 
 :class:`PooledCaches` is what ``InferenceEngine`` asks of such a model
-(the interface is in ``serve/caches.py``).  Both bodies drive the model's
-ONE ``block``:
+(``serve/caches.py``: the body's decode frame and ``out``; the slab, what
+a step does to it and the prefill's walk are its own):
 
 * the **decode** step, for every slot: writes the new row
   (``caches.write_rows``); pools the chunk the new row lies in from the
@@ -74,31 +74,101 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from kungfu_tpu.models import evabyte as arch
-from kungfu_tpu.serve.caches import kv_rows, row_windows, write_rows
+from kungfu_tpu.serve.caches import (ROW_WINDOW, Caches, FullRows, kv_rows,
+                                     row_windows, write_rows)
 
 #: query rows a prefill attends at once (the scores of one block,
 #: ``[heads, ATTN_BLOCK, rows]`` float32, are its largest temporary:
 #: 134 MB at 32 heads over 4,096 rows)
 ATTN_BLOCK = 256
-#: rows of the aligned window a decode step's row write reads and writes
-#: back (``caches.write_rows``).  Heads of 128 lie along the lanes, so a
-#: slab's rows are its tiles' sublanes, 16 bfloat16 rows a tile: a window
-#: of one tile (128 KB of a 32-head row) where ``row_windows``' default of
-#: 128 rows moves 1 MiB each way -- 18.86 against 20.31-21.88 ms a step
-#: of this cache alone on the chip (PERF.md, PR 40)
-ROW_WINDOW = 16
-#: what a decode step's ``out`` says behind the slots' tokens
-_SAYS = ("summary_rows_written", "kv_rows_walked", "summary_rows_walked")
 
 
-class PooledCaches:
+class _Slab(FullRows):
+    """K's slab and V's, ``W`` exact rows and ``max_seq / C`` chunk rows
+    a slot and layer."""
+
+    def __init__(self, layers, batch, heads, exact, chunks, width, dtype):
+        super().__init__(layers, batch, heads, exact + chunks, width, dtype)
+        self.exact = exact
+
+    def restored(self, rows: int) -> int:
+        """Zero exact rows, as many as the open window of ``rows``
+        positions could hold (the engine's warm-up asks)."""
+        return min(rows, self.exact)
+
+
+class _Step:
+    """A decode step's cache: one row a slot into the slab, the chunk it
+    lies in pooled again from the slab, attention over the slab
+    itself."""
+
+    def __init__(self, caches, k, v, pos, live):
+        cfg = caches.cfg
+        self.caches, self.k, self.v, self.pos, self.live = (caches, k, v, pos,
+                                                            live)
+        w, c = caches.exact, cfg.chunk_size
+        rows = caches.shape[3]
+        self.row_at = row_windows(pos % w, rows, live, ROW_WINDOW)
+        self.chunk_at = row_windows(w + pos // c, rows, live, ROW_WINDOW)
+        self.chunk_from = pos % w // c * c  # the new row's chunk, in the slab
+        self.tile = caches.attn_tile
+        if self.tile:
+            from kungfu_tpu.ops.pallas import decode_attention as kernel
+
+            self.kernel = kernel
+            self.visible = caches.visible_runs(pos, live)
+            self.walked = [kernel.rows_walked(n, self.tile)
+                           for n in self.visible]
+        else:       # XLA's form reads every row of every slot under a mask
+            self.see = caches.visible_rows(pos)[:, None, None, :]
+            self.walked = [caches.batch * w, caches.batch * caches.chunks]
+
+    def write(self, li, kn, vn, mu, phi):
+        cfg = self.caches.cfg
+        size = (1, 1, cfg.n_heads, cfg.chunk_size, cfg.head_dim)
+        with jax.named_scope("kv_write"):
+            self.k = write_rows(self.k, li, kn, self.row_at)
+            self.v = write_rows(self.v, li, vn, self.row_at)
+            with jax.named_scope("eva_pool"):
+                chunk = lambda slab: jnp.concatenate([
+                    jax.lax.dynamic_slice(
+                        slab, (li, b, 0, self.chunk_from[b], 0), size,
+                        allow_negative_indices=False)[0]
+                    for b in range(self.caches.batch)])[:, :, None]
+                kp, vp = arch.pool_chunks(chunk(self.k), chunk(self.v), mu,
+                                          phi)
+                self.k = write_rows(self.k, li, kp, self.chunk_at)
+                self.v = write_rows(self.v, li, vp, self.chunk_at)
+
+    @jax.named_scope("attn_core")
+    def attend(self, li, q, positions):
+        with jax.named_scope("eva_attn"):
+            if self.tile:   # one query head a key/value head: [B, H, 1, D]
+                return self.kernel.decode_attn(
+                    q[:, 0, :, None], self.k, self.v, li, self.visible,
+                    tile=self.tile, starts=(0, self.caches.exact)
+                )[:, None, :, 0]
+            return arch.eva_attention(q, self.k[li], self.v[li], self.see)
+
+    def parts(self):
+        return self.k, self.v
+
+    def says(self, counts):
+        """Each summed over the layers: the chunks the step completed,
+        and the exact rows and the chunk rows its attention read."""
+        c = self.caches.cfg.chunk_size
+        return self.caches.cfg.n_layers * jnp.stack(
+            [jnp.sum(self.live & (self.pos % c == c - 1)), *self.walked])
+
+
+class PooledCaches(Caches):
+    says = ("summary_rows_written", "kv_rows_walked", "summary_rows_walked")
+
     def __init__(self, model: arch.EvaByte, max_batch: int, max_seq: int):
-        self.model = model
-        cfg = self.cfg = model.cfg
-        self.batch, self.seq = int(max_batch), int(max_seq)
+        super().__init__(model, max_batch, max_seq)
+        cfg = self.cfg
         if self.seq % cfg.chunk_size or (
                 self.seq > cfg.window_size and self.seq % cfg.window_size):
             raise ValueError(
@@ -107,15 +177,15 @@ class PooledCaches:
                 f"{cfg.chunk_size})")
         #: exact rows and chunk rows of a slot and layer
         self.exact, self.chunks = cfg.window_size, self.seq // cfg.chunk_size
+        self.stores = (_Slab(cfg.n_layers, self.batch, cfg.n_heads,
+                             self.exact, self.chunks, cfg.head_dim,
+                             cfg.compute_dtype),)
         #: of K's slab, and of V's
-        self.shape = (cfg.n_layers, self.batch, cfg.n_heads,
-                      self.exact + self.chunks, cfg.head_dim)
-        self.prefill_flops = model.prefill_flops
-        self.decode_flops = model.decode_flops
-
-    def new_slabs(self):
-        dt = self.cfg.compute_dtype
-        return jnp.zeros(self.shape, dt), jnp.zeros(self.shape, dt)
+        self.shape = self.stores[0].shapes[0]
+        #: the open window's exact rows as ``read`` counts them: rows
+        #: ``[0, W)`` of the slab, no array of their own
+        self.open = FullRows(cfg.n_layers, self.batch, cfg.n_heads,
+                             self.exact, cfg.head_dim, cfg.compute_dtype)
 
     def visible_rows(self, pos, n_chunks=None):
         """Which rows of a slot's slab a query at each of ``pos``
@@ -139,96 +209,35 @@ class PooledCaches:
             [pos % w + 1, self.cfg.window_chunks * (pos // w)]), 0)
 
     # -- the two forward passes ------------------------------------------
-    def decode(self, params, k, v, last_ids, pos, live):
-        """One token for every slot (``last_ids``/``pos``/``live``
-        ``[B]``; a slot that is not live computes what nobody reads and
-        writes nothing).  Returns the slabs and ONE int32 vector: the
-        ``B`` tokens, then what the step says of itself, each summed
-        over the layers (:data:`_SAYS`): the chunks it completed, and
-        the exact rows and the chunk rows its attention read."""
-        cfg, model = self.cfg, self.model
-        w, c = self.exact, cfg.chunk_size
-        rows = self.shape[3]
-        row_at = row_windows(pos % w, rows, live, ROW_WINDOW)
-        chunk_at = row_windows(w + pos // c, rows, live, ROW_WINDOW)
-        chunk_from = pos % w // c * c       # the new row's chunk, in the slab
-        size = (1, 1, cfg.n_heads, c, cfg.head_dim)
-        tile = self.attn_tile
-        if tile:
-            from kungfu_tpu.ops.pallas import decode_attention as kernel
+    def logits(self, params, h):
+        return self.model.next_logits(params, h)
 
-            visible = self.visible_runs(pos, live)
-            walked = [kernel.rows_walked(n, tile) for n in visible]
-        else:       # XLA's form reads every row of every slot under a mask
-            see = self.visible_rows(pos)[:, None, None, :]
-            walked = [self.batch * w, self.batch * self.chunks]
+    def step(self, k, v, pos, live):
+        return _Step(self, k, v, pos, live)
 
-        class Step:
-            """A decode step's cache: one row a slot into the slab, the
-            chunk it lies in pooled again from the slab, attention over
-            the slab itself."""
-
-            def write(_, li, kn, vn, mu, phi):
-                nonlocal k, v
-                with jax.named_scope("kv_write"):
-                    k = write_rows(k, li, kn, row_at)
-                    v = write_rows(v, li, vn, row_at)
-                    with jax.named_scope("eva_pool"):
-                        chunk = lambda slab: jnp.concatenate([
-                            jax.lax.dynamic_slice(
-                                slab, (li, b, 0, chunk_from[b], 0), size,
-                                allow_negative_indices=False)[0]
-                            for b in range(self.batch)])[:, :, None]
-                        kp, vp = arch.pool_chunks(chunk(k), chunk(v), mu, phi)
-                        k = write_rows(k, li, kp, chunk_at)
-                        v = write_rows(v, li, vp, chunk_at)
-
-            @jax.named_scope("attn_core")
-            def attend(_, li, q, positions):
-                with jax.named_scope("eva_attn"):
-                    if tile:    # one query head a key/value head: [B, H, 1, D]
-                        return kernel.decode_attn(
-                            q[:, 0, :, None], k, v, li, visible, tile=tile,
-                            starts=(0, w))[:, None, :, 0]
-                    return arch.eva_attention(q, k[li], v[li], see)
-
-        h = model.embed(params, last_ids[:, None])
-        for li in range(cfg.n_layers):
-            h = arch.block(cfg, params[f"layer_{li}"], li, h, pos[:, None],
-                           Step())
-        tok = jnp.argmax(model.next_logits(params, h[:, 0]), axis=-1)
-        says = cfg.n_layers * jnp.stack(
-            [jnp.sum(live & (pos % c == c - 1)), *walked])
-        return k, v, jnp.concatenate([tok, says]).astype(jnp.int32)
-
-    def new_out(self):
-        return jnp.zeros(self.batch + len(_SAYS), jnp.int32)
-
-    def read(self, out, contexts):
-        """A decode step's ``out`` on the host: the slots' tokens, and as
-        attrs of the span that waits for them (docs/tracing.md) what the
-        step's ``contexts`` had to read of each kind of row beside what
-        it did read: a context of ``c`` positions has ``c - W floor((c -
-        1) / W)`` exact rows a layer (``kv_rows_*``) and ``(W / C)
-        floor((c - 1) / W)`` chunk rows (``summary_rows_*``); the rows
-        it read of each kind (the tiles its kernel walked, or every row
-        of every slot) and the chunks it completed the step counted
-        itself; ``eva_attn_kernel`` says which form of the attention
-        ran."""
+    def layers(self, params, h, positions, step, live):
         cfg = self.cfg
-        out = np.asarray(jax.device_get(out))
-        says = dict(zip(_SAYS, out[self.batch:].tolist()))
+        for li in range(cfg.n_layers):
+            h = arch.block(cfg, params[f"layer_{li}"], li, h,
+                           positions[:, None], step)
+        return h, ()
+
+    def attrs(self, says, contexts):
+        """What the step's ``contexts`` had to read of each kind of row
+        beside what it did read: a context of ``c`` positions has ``c -
+        W floor((c - 1) / W)`` exact rows a layer (``kv_rows_*``) and
+        ``(W / C) floor((c - 1) / W)`` chunk rows (``summary_rows_*``);
+        the rows it read of each kind (the tiles of each run its kernel
+        walked, or every row of every slot) and the chunks it completed
+        the step counted itself; ``eva_attn_kernel`` says which form of
+        the attention ran."""
         exact, chunk = self.model.rows_seen(contexts)
-        l, slots, heads, _, width = self.shape
-        says.update(kv_rows(exact, ((l, slots, heads, self.exact, width),),
-                            cfg.compute_dtype))
-        says["summary_rows_live"] = l * int(chunk.sum())
-        # ... of which the rows READ are the step's own counts: the tiles
-        # of each run the kernel walked, or every row where XLA's form ran
+        says.update(kv_rows(exact, (self.open,)))
+        says["summary_rows_live"] = self.cfg.n_layers * int(chunk.sum())
         says["kv_rows_read"] = says.pop("kv_rows_walked")
         says["summary_rows_read"] = says.pop("summary_rows_walked")
         says["eva_attn_kernel"] = self.eva_attn_kernel
-        return out[:self.batch], says
+        return says
 
     @functools.cached_property
     def attn_tile(self):
@@ -237,11 +246,8 @@ class PooledCaches:
         two visible runs (``ops/pallas/decode_attention.py``), or None
         where it is ``eva_attention`` over every row under the mask: off
         the TPU, and for shapes the kernel does not tile (a window that
-        is not whole tiles among them).  One choice, from the platform
-        and the slab's shape, made once: the step that is traced and the
-        span that says which form ran read the same.  The kernel's
-        package is imported here and by no module's import, so a process
-        that traces no such step never pays for it (PERF.md, PR 35)."""
+        is not whole tiles among them).  Chosen and imported as
+        ``caches.FullRows.tile`` is, for two runs."""
         if jax.default_backend() != "tpu":
             return None
         from kungfu_tpu.ops.pallas import decode_attention
@@ -335,16 +341,5 @@ class PooledCaches:
         k, v, row = jax.lax.fori_loop(
             0, (n + span - 1) // span, window,
             (k, v, jnp.zeros((1, cfg.d_model), arch.F32)))
-        tok = jnp.argmax(model.next_logits(params, row)[0], axis=-1)
-        return k, v, tok.astype(jnp.int32)
+        return k, v, self.greedy(params, row)
 
-    # -- the host's side of a page ---------------------------------------
-    def empty_pages(self, rows: int):
-        """What the restore program writes into a slot for ``rows``
-        positions that hold nothing (the engine's warm-up asks): zero
-        exact rows, as many as the open window of ``rows`` positions
-        could hold."""
-        _, _, heads, _, width = self.shape
-        part = np.zeros((self.cfg.n_layers, heads, min(rows, self.exact),
-                         width), self.cfg.compute_dtype)
-        return part, part

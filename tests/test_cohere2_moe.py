@@ -474,7 +474,7 @@ def test_one_initialisation_scaled_by_the_whole_models_depth(adapter):
         == jnp.float32 and lp["wq"]["w"].dtype == jnp.bfloat16
 
 
-# -- (e) the dense GPT-2 programs are what they were ------------------------
+# -- (e) the engine's programs are what they were ---------------------------
 
 #: sha256 of the lowered text of the engine's three programs at the size
 #: below, as the commit before this model lowered them (PR 25's tree).
@@ -484,26 +484,86 @@ GPT2_PROGRAMS = {
     "prefill": "3213586b14c887b542fe403c8f4bab439f9c5f541bf8942be4b754ed27095e31",
     "restore": "98d26d159b3a0ab4227b24739a9cdf57bb6888c63efda7b1929ca9f30b72aa69",
 }
+#: the same of every other family's, at the tiny sizes of
+#: tests/test_serve_kv_rows.py, as PR 46's tree lowered them: a change
+#: that only moves the cache managers' code between functions
+#: (``serve/caches.py`` and the five beside it) makes the same operations
+#: in the same order, and this is where that shows.  A PR that changes a
+#: family's programs on purpose records its own and says so.
+FAMILY_PROGRAMS = {
+    "windowed": {
+        "decode": "04933d22f01ac79b52bcfc6b33264f89609b4e6b1e05fb5f8e07fa56b1530201",
+        "prefill": "2a04752cd01b4ebab0996b5491f37cc716535db5d4feae02e1dd93933ad69167",
+        "restore": "3e35572dd5a0f4dd08662aafedddd7b6d123a804e0b7b4c28855f9575ea2157e"},
+    "latent": {
+        "decode": "fc9130c744f27df5414433d8cf815e3fc7709edff9dc063284e86f0d740d141f",
+        "prefill": "0d30be405e8e5aa65b1843ec40ba8fb062b7f00eb3e5fb4c7a4104d43ecb9ca9",
+        "restore": "2e93b82f1dd2edad2b03cb44be9bdaf7dffc2fd46d731fc40f112b5cdeac8071"},
+    "hybrid": {
+        "decode": "b0e86192dddffa9dcf6be767b4c2e69e0e78a0dff91a40898430459b359db560",
+        "prefill": "9405c7a260b7c7347597084353c57cfb662c06c0fb5bd92f3b8c6b3a46b65c48",
+        "restore": "4ee3607d4cd1bc7d13b92c292eb1ae2df1b5a5f5651d4d36fc70ec862b2065e5"},
+    "pooled": {
+        "decode": "ff320ef623366bdd9b9aa3dc02639b257cdd79a72c0a250a3c3ed439f32ed5d2",
+        "prefill": "fa41561ba9719189be36dbeff261b4fca2f7885696bc190e1c156b1683309347",
+        "restore": "8fd5d357aa58959f3b66b135260d867767ff076fd3f62ac4b232e99dab257735"},
+    "sambay": {
+        "decode": "7d0c3356bfadf794cb3ec52876acec632c8173747c5a06458437b7211d7ac549",
+        "prefill": "4a3eeaef26c7d7835b8a1a9ac95b9ae4363d158c008617744091232b6da8f9bb",
+        "restore": "7891542302e4843bf799bd0b7f31d2e5ab4e6af11697493c0afb10f74d52b5f5"},
+}
+#: family -> tokens of the prefill bucket lowered (the pooled cache's
+#: two windows of 32; a page's worth twice for the others)
+BUCKET = {"pooled": 64}
 
 
-@pytest.mark.parametrize("program", sorted(GPT2_PROGRAMS))
-def test_gpt2_programs_lower_bitwise_as_before(program):
+def gpt2_engine():
+    cfg = TransformerConfig(vocab_size=97, d_model=32, n_layers=2,
+                            n_heads=4, d_ff=64, max_seq=32, pos="learned",
+                            dtype="bfloat16")
+    m = Transformer(cfg)
+    return InferenceEngine(
+        m, m.init(jax.random.PRNGKey(0)), max_batch=3, max_seq=32,
+        pool=KVCachePool(PageSpec.for_model(cfg, page_tokens=4),
+                         capacity_pages=16))
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """family -> its engine, built when first asked for (``gpt2`` at the
+    size ``GPT2_PROGRAMS`` was recorded at)."""
+    import functools
+
+    from tests import test_serve_kv_rows as tiny
+
+    built = functools.lru_cache(None)(tiny.build_all)
+
+    @functools.lru_cache(None)
+    def of(family):
+        with jax.default_matmul_precision("default"):
+            return gpt2_engine() if family == "gpt2" else tiny.engine(
+                built(), family)
+
+    return of
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "restore"])
+@pytest.mark.parametrize("family", ["gpt2", "windowed", "latent", "hybrid",
+                                    "pooled", "sambay"])
+def test_programs_lower_bitwise_as_before(engines, family, program):
+    eng = engines(family)
+    slots = jnp.zeros(eng.max_batch, jnp.int32)
+    i0, bucket = jnp.int32(0), BUCKET.get(family, 8)
     with jax.default_matmul_precision("default"):
-        cfg = TransformerConfig(vocab_size=97, d_model=32, n_layers=2,
-                                n_heads=4, d_ff=64, max_seq=32, pos="learned",
-                                dtype="bfloat16")
-        m = Transformer(cfg)
-        p = m.init(jax.random.PRNGKey(0))
-        eng = InferenceEngine(m, p, max_batch=3, max_seq=32, pool=KVCachePool(
-            PageSpec.for_model(cfg, page_tokens=4), capacity_pages=16))
-        z, i0 = jnp.zeros(3, jnp.int32), jnp.int32(0)
-        pages = jnp.zeros((2, 4, 8, 8), jnp.bfloat16)
         lowered = {
-            "decode": lambda: eng._decode_j.lower(p, eng._k, eng._v, z, z, z),
+            "decode": lambda: eng._decode_j.lower(
+                eng.params, eng._k, eng._v, eng._out, slots, slots),
             "prefill": lambda: eng._prefill_j.lower(
-                p, eng._k, eng._v, jnp.zeros(8, jnp.int32), i0, i0, i0),
-            "restore": lambda: eng._restore_j.lower(eng._k, eng._v, pages,
-                                                    pages, i0),
+                eng.params, eng._k, eng._v, jnp.zeros(bucket, jnp.int32),
+                i0, i0, i0),
+            "restore": lambda: eng._restore_j.lower(
+                eng._k, eng._v, *eng._caches.empty_pages(bucket), i0),
         }[program]()
-    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() \
-        == GPT2_PROGRAMS[program]
+    want = GPT2_PROGRAMS if family == "gpt2" else FAMILY_PROGRAMS[family]
+    got = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    assert got == want[program], got

@@ -24,7 +24,9 @@ sys.path.insert(0, ROOT)
 
 from tests import _lookahead  # noqa: E402
 from tests import test_cohere2_moe as windowed  # noqa: E402
+from tests import test_evabyte as pooled  # noqa: E402
 from tests import test_pangu_moe as latent  # noqa: E402
+from tests import test_phi4flash as sambay  # noqa: E402
 from tests import test_solar_open2 as hybrid  # noqa: E402
 
 from kfbench.lib import files  # noqa: E402
@@ -50,16 +52,24 @@ KV = {"dense": ([(2, MAX_SEQ)], 2 * 16 * 2 * 4, 0),
       "hybrid": ([(1, MAX_SEQ)], 2 * 8 * 2 * 4, 6)}
 
 
-@pytest.fixture(scope="module")
-def built():
+def build_all():
     """family -> (model, params), each at its own tests' tiny size."""
     out = {"dense": (Transformer(DENSE),
                      Transformer(DENSE).init(jax.random.PRNGKey(0)))}
     for name, mod, family in (("windowed", windowed, "cohere2_moe"),
                               ("hybrid", hybrid, "solar_open2"),
-                              ("latent", latent, "pangu_moe")):
+                              ("latent", latent, "pangu_moe"),
+                              ("sambay", sambay, "phi4flash")):
         out[name] = mod.build(files.load_adapter(family), mod.tiny_cfg())
+    adapter, cfg = files.load_adapter("evabyte"), pooled.tiny_cfg()
+    out["pooled"] = (pooled.fresh(adapter, cfg), jax.jit(
+        lambda k: adapter.init_params(cfg, k))(jax.random.PRNGKey(0)))
     return out
+
+
+@pytest.fixture(scope="module")
+def built():
+    return build_all()
 
 
 @pytest.fixture(autouse=True)
@@ -69,7 +79,11 @@ def highest():
 
 
 def engine(built, family, eos_id=None):
+    """(The pooled cache's at its own tests' size: its windows of 32
+    want more than ``MAX_SEQ`` positions to be several.)"""
     model, params = built[family]
+    if family == "pooled":
+        return pooled.engine(model, params, slots=SLOTS, eos_id=eos_id)
     return InferenceEngine(
         model, params, max_batch=SLOTS, max_seq=MAX_SEQ, eos_id=eos_id,
         pool=KVCachePool(PageSpec.for_model(model.cfg, page_tokens=4),
@@ -183,3 +197,40 @@ def test_nothing_of_it_is_in_the_step(built, family):
     assert lowered(eng) == lowered(bare)
     says = {"latent": 5}.get(family) or KV[family][2]
     assert eng._caches.new_out().shape == (SLOTS + says,)
+
+
+# -- the one body holds every family to the contract ------------------
+_KV = {"kv_rows_live", "kv_rows_read", "kv_rows_written", "kv_row_bytes"}
+_ROUTING = {"experts_touched", "experts_held", "expert_load_max",
+            "expert_load_mean"}
+_STATE = {"state_slots_live", "state_slots_read", "state_bytes_read"}
+#: family -> the attrs its cache states on ``kf:serve.decode_read``, as
+#: docs/tracing.md's table lists them (``discarded`` is the engine's)
+READS = {
+    "dense": _KV,
+    "windowed": _KV | _ROUTING,
+    "hybrid": _KV | _ROUTING | _STATE | {"kv_attn_kernel", "kda_step_kernel"},
+    "latent": _ROUTING | {"latent_rows_live", "latent_rows_read",
+                          "latent_attn_kernel"},
+    "pooled": _KV | {"summary_rows_live", "summary_rows_read",
+                     "summary_rows_written", "eva_attn_kernel"},
+    "sambay": _KV | _STATE | {"kv_rows_live_full", "kv_attn_kernel"},
+}
+
+
+@pytest.mark.parametrize("family", list(READS))
+def test_the_body_holds_every_family_to_the_contract(built, family):
+    """``serve/caches.py::Caches`` makes ``out`` and takes it apart for
+    every family: as long as the tokens and what the family's ``says``
+    names, read back as the slots' tokens and exactly the attrs the
+    docs' table gives that family -- before any step ran, and for no
+    live context."""
+    caches = engine(built, family)._caches
+    out = caches.new_out()
+    assert out.shape == (caches.batch + len(caches.says),)
+    assert out.dtype == jnp.int32
+    tokens, attrs = caches.read(out, [])
+    assert tokens.shape == (caches.batch,) and not tokens.any()
+    assert set(attrs) == READS[family]
+    table = open(os.path.join(ROOT, "docs", "tracing.md")).read()
+    assert all(f"`{name}`" in table for name in attrs)

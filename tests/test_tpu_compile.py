@@ -13,6 +13,8 @@ Shapes are GPT-small's on one chip at batch 4 x sequence 2048
 (``tile_plan`` streams it), and ZeRO's 4 MiB bucket on the four devices.
 """
 
+import base64
+import hashlib
 import os
 import re
 
@@ -293,6 +295,95 @@ def test_decode_row_write_is_one_fused_window_update(serve_programs):
     assert len(fused) == SERVE_LAYERS * SERVE_SLOTS
 
 
+# -- the serving families' programs are what they were ---------------------------
+#: family -> program -> sha256 of the text its fixture below lowers for
+#: the described chip, as PR 46's tree lowered it (the kernels' forms;
+#: tests/test_cohere2_moe.py holds XLA's, at the tiny sizes), each
+#: kernel in it without its source locations (:func:`_located_nowhere`).
+#: A change that moves the cache managers' code between functions leaves
+#: every one as it is: it makes the same operations in the same order.
+#: A PR that changes a family's programs on purpose records its own.
+AS_BEFORE = {
+    "moe": {
+        "decode":
+            "5482f0d6bcd37cc473ae111ee2efc9f3fe9098bdebbc5696ad8ed10f1fe0e88d",
+        "restore":
+            "2c2b58ffd437ade8d80bf44d1c58ee846b565ceb226095b797106197447b3433",
+        "prefill256":
+            "890347cc18586c8bfa86df15745d2fab30eee06fd0a78aa884016470625c35a1",
+        "prefill8192":
+            "0568e02c678eca3c7f5626a7aa18d41a3c3caac1e39fa949d923a18adb87f0a6",
+    },
+    "mla": {
+        "decode":
+            "95694174fb111e6bd0b2e1199af6003315051818b2aed7b458630b89d9d09230",
+        "restore":
+            "6effc29371aeccfcb3411476c7257260aa3761e1ade07cd9dd2781f4e3bd8f64",
+        "prefill256":
+            "861463a6135baca324141f1af0647032c1d55a9f914c427753767fa4c5c55978",
+        "prefill16384":
+            "7542230a0cfe8cb0d1a318c521f71f2da223cca6147afd5f32b82b5deec40a70",
+    },
+    "kda": {
+        "decode":
+            "fcc83f47411a7ec3e8ab89d1918c7670cf357e06117feaee3bf6e2bc3a5824e3",
+        "restore":
+            "65ba70c415841a86d0aead0a496289c396a151eff35873f59f6cf2f63697670b",
+        "prefill256":
+            "457a4cc12084bbf9204ce60409340ede6ada099e50fab322af2d890cfb4ceade",
+        "prefill2048":
+            "29fdb8aa937f720a615c3589fafa46445348b6b2feb3a5d26b0396e3f00471a3",
+    },
+    "eva": {
+        "decode":
+            "97d5e5bf47383b6c472d8a2cfbff0d29eedecaf3cd26e612d47b5b28d165e809",
+        "prefill32768":
+            "cdf5c747b9cbd71a82792f79b7520fb0a1662939ff534f4e458cc211c642e924",
+    },
+    "sambay": {
+        "decode":
+            "fc35d7038ac6e1c76848c20d69753e78b289b29c258f0e1a21cb465f2f5b8d8e",
+        "prefill1024":
+            "b2d616e86c716546c66cefff9166489cdece949f73d775bd54562554f0f261d6",
+    },
+}
+_LOWERED = {}
+
+
+def _located_nowhere(text):
+    """``text`` with the body of every Mosaic kernel in it -- MLIR
+    bytecode in base64, serialized WITH its debug information: the
+    file, line and function of the ten Python frames above every
+    operation of the kernel, the checkout's path among them -- replaced
+    by that kernel's assembly without locations.  (The persistent
+    compile cache's key keeps them: a program that calls a kernel is
+    compiled anew after any edit that moves a line of a frame above the
+    call, PERF.md PR 47.)"""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.JaxIrContext()
+    ctx.allow_unregistered_dialects = True      # ``stable_mosaic``
+
+    def bare(found):
+        with ctx:
+            return ir.Module.parse(base64.b64decode(found[1])
+                                   ).operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', bare, text)
+
+
+def _compiled(family, lowered):
+    """name -> compiled program of ``lowered``, each one's text kept as
+    its sha256 under ``_LOWERED[family]`` for the test that holds it to
+    ``AS_BEFORE``."""
+    _LOWERED[family] = {
+        name: hashlib.sha256(_located_nowhere(lo.as_text()).encode()
+                             ).hexdigest()
+        for name, lo in lowered.items()}
+    return {name: lo.compile() for name, lo in lowered.items()}
+
+
 # -- the same guarantee for the model with two caches -----------------------------
 #: command-a-plus-05-2026 as its cell serves it: one period of layers,
 #: 16 of 128 experts, 32 slots of 8192 positions, rings of 4096
@@ -335,7 +426,7 @@ def moe_programs(topo):
     for n in MOE_PREFILL:
         lowered[f"prefill{n}"] = eng._prefill_j.lower(
             params, slab, slab, shaped((n,), i32), i0, i0, i0)
-    return {name: lo.compile() for name, lo in lowered.items()}
+    return _compiled("moe", lowered)
 
 
 def _entry_ops(text):
@@ -355,6 +446,7 @@ def test_two_cache_program_writes_its_slabs_in_place(moe_programs, program):
     array the size of a slab, of one layer of one, or of the weights of a
     layer's experts or query projection: no copy of a slab, no
     materialised layer, no weights laid out anew every step."""
+    assert _LOWERED["moe"][program] == AS_BEFORE["moe"][program]
     text = moe_programs[program].as_text()
     assert len(re.findall(r"may-alias|must-alias",
                           text.split("\n", 1)[0])) == 4
@@ -467,7 +559,7 @@ def mla_programs(topo):
             lowered[f"prefill{n}"] = eng._prefill_j.lower(
                 params, c, k_r, shaped((n,), i32), i0, i0, i0)
         assert eng._caches.latent_attn_kernel == 1
-    return {name: lo.compile() for name, lo in lowered.items()}
+    return _compiled("mla", lowered)
 
 
 @pytest.mark.parametrize("program", ["decode", "restore"]
@@ -478,6 +570,7 @@ def test_latent_program_writes_its_slab_in_place(mla_programs, program):
     layer of one: no copy of the slab, no materialised layer, and in the
     decode step no per-head key or value of a cached row -- ``[B, 128, S,
     128]`` would be 17 GB a layer (the prefill forms them for ONE slot)."""
+    assert _LOWERED["mla"][program] == AS_BEFORE["mla"][program]
     text = mla_programs[program].as_text()
     assert len(re.findall(r"may-alias|must-alias",
                           text.split("\n", 1)[0])) == 2
@@ -671,7 +764,7 @@ def kda_programs(topo):
                 i0, i0, i0)
         assert eng._caches.kda_step_kernel == 1
         assert eng._caches.kv_attn_kernel == 1
-    return {name: lo.compile() for name, lo in lowered.items()}
+    return _compiled("kda", lowered)
 
 
 def _state_kernels(text):
@@ -704,6 +797,7 @@ def test_hybrid_program_updates_state_and_slab_in_place(kda_programs,
     state's size.  (With ONE state array for the three layers the decode
     step copied the layer it was about to update, 537 MB each:
     serve/recurrent.py.)"""
+    assert _LOWERED["kda"][program] == AS_BEFORE["kda"][program]
     text = kda_programs[program].as_text()
     assert len(re.findall(r"may-alias|must-alias",
                           text.split("\n", 1)[0])) == 8
@@ -970,7 +1064,7 @@ def eva_programs(topo):
                 i0, i0, i0)}
         assert eng._caches.attn_tile == EVA_TILE
         assert eng._caches.eva_attn_kernel == 1
-    return {name: lo.compile() for name, lo in lowered.items()}
+    return _compiled("eva", lowered)
 
 
 def test_pooled_programs_fit_beside_the_weights(eva_programs):
@@ -999,6 +1093,7 @@ def test_pooled_program_writes_its_slabs_in_place(eva_programs, program):
     updates produces an array the size of a slab or of one layer of one:
     no copy of either, in the decode step or around the prefill's walk
     over the windows."""
+    assert _LOWERED["eva"][program] == AS_BEFORE["eva"][program]
     text = eva_programs[program].as_text()
     assert len(re.findall(r"may-alias|must-alias",
                           text.split("\n", 1)[0])) == 2
@@ -1134,7 +1229,7 @@ def sambay_programs(topo):
                 i0, i0, i0)}
         assert eng._caches.attn_tiles == (512, 512)
         assert eng._caches.kv_attn_kernel == 1
-    return {name: lo.compile() for name, lo in lowered.items()}
+    return _compiled("sambay", lowered)
 
 
 def test_sambay_programs_fit_beside_the_weights(sambay_programs):
@@ -1170,6 +1265,7 @@ def test_sambay_program_writes_state_rings_and_slab_in_place(sambay_programs,
     an elementwise fusion over it, written where it lay; what the
     compiler moves ahead into its fast memory, ``S(1)``, is a prefetch of
     an operand and no second home)."""
+    assert _LOWERED["sambay"][program] == AS_BEFORE["sambay"][program]
     text = sambay_programs[program].as_text()
     assert len(re.findall(r"may-alias|must-alias",
                           text.split("\n", 1)[0])) == 22
